@@ -13,35 +13,39 @@ import numpy as np
 
 from skellysim_tpu.config import Config, Fiber
 
-config_file = sys.argv[1] if len(sys.argv) > 1 else "skelly_config.toml"
-rng = np.random.default_rng(100)
 
-n_fibers = 10_000
-box = 20.0
+def build_config(n_fibers: int = 10_000, box: float = 20.0, seed: int = 100):
+    """The scene at ``n_fibers`` fibers (`chip_smoke.py` runs a cut of it)."""
+    rng = np.random.default_rng(seed)
+    config = Config()
+    config.params.dt_write = 0.05
+    config.params.dt_initial = 5e-3
+    config.params.dt_max = 5e-3
+    config.params.gmres_tol = 1e-8
+    config.params.pair_evaluator = "ring"
+    # f32 hot-loop flows through the fused Pallas VMEM tiles (single-chip
+    # AND each ring shard). solver_precision="auto" keeps the hot loop f32
+    # even under x64 (the pallas tier is f32-only; f64 operands fall back
+    # to the exact tile, and the log says so). Alternative at scale:
+    # pair_evaluator = "ewald".
+    config.params.kernel_impl = "pallas"
+    config.params.solver_precision = "auto"
 
-config = Config()
-config.params.dt_write = 0.05
-config.params.dt_initial = 5e-3
-config.params.dt_max = 5e-3
-config.params.gmres_tol = 1e-8
-config.params.pair_evaluator = "ring"
-# f32 hot-loop flows through the fused Pallas VMEM tiles (single-chip AND
-# each ring shard): 5.1 s/matvec at 640k nodes on one v5e vs ~28 s XLA.
-# solver_precision="auto" keeps the hot loop f32 even under x64 (the
-# pallas tier is f32-only; f64 operands would silently fall back to the
-# exact tile). Alternative at scale: pair_evaluator = "ewald" (~1 s).
-config.params.kernel_impl = "pallas"
-config.params.solver_precision = "auto"
+    config.fibers = []
+    for _ in range(n_fibers):
+        fib = Fiber(length=1.0, bending_rigidity=2.5e-3, force_scale=-0.05,
+                    n_nodes=64)
+        origin = rng.uniform(-box / 2, box / 2, 3)
+        direction = rng.normal(size=3)
+        direction /= np.linalg.norm(direction)
+        fib.fill_node_positions(origin, direction)
+        config.fibers.append(fib)
+    return config
 
-config.fibers = []
-for _ in range(n_fibers):
-    fib = Fiber(length=1.0, bending_rigidity=2.5e-3, force_scale=-0.05,
-                n_nodes=64)
-    origin = rng.uniform(-box / 2, box / 2, 3)
-    direction = rng.normal(size=3)
-    direction /= np.linalg.norm(direction)
-    fib.fill_node_positions(origin, direction)
-    config.fibers.append(fib)
 
-config.save(config_file)
-print(f"wrote {config_file} ({n_fibers} fibers); run: python -m skellysim_tpu")
+if __name__ == "__main__":
+    config_file = sys.argv[1] if len(sys.argv) > 1 else "skelly_config.toml"
+    config = build_config()
+    config.save(config_file)
+    print(f"wrote {config_file} ({len(config.fibers)} fibers); "
+          "run: python -m skellysim_tpu")

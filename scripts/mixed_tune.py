@@ -49,13 +49,9 @@ def main():
     import jax
 
     jax.config.update("jax_enable_x64", True)
-    try:
-        here = os.path.dirname(os.path.abspath(__file__))
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(here, "..", ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass
+    from skellysim_tpu.utils.bootstrap import enable_compilation_cache
+
+    enable_compilation_cache("auto")
     import jax.numpy as jnp
     import numpy as np
 

@@ -440,7 +440,7 @@ def check_fft_inventory(name, built, contract, probe):
     return out
 
 
-def check_replication(name, built, contract, probe):
+def check_shard_replication(name, built, contract, probe):
     """Replication-flow analysis (`audit.repflow`, docs/parallel.md):
     statically prove the program's `shard_map` regions cannot deadlock —
     no varying `while_loop`/`cond` predicates, no collectives under
@@ -570,7 +570,7 @@ CHECKS = (
           "replication-flow analysis over shard_map regions: no varying "
           "while/cond predicates (the manual-SPMD deadlock), no collectives "
           "under divergence, replicated outputs provably replicated",
-          check_replication),
+          check_shard_replication),
     Check("dma",
           "skelly-fence static DMA verifier over the Pallas kernel "
           "registry: read-before-arrival, overwrite-in-flight (barrier "

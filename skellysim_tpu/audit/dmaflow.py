@@ -76,19 +76,41 @@ from dataclasses import dataclass
 #: 512x2048-class tiles; bigger compiles fail on VMEM).
 VMEM_PAIR_BUDGET = 512 * 2048
 
+#: per-axis caps on the fused ring's whole-block pair tile (padded target
+#: rows, padded source lanes). The element budget alone is not sound: the
+#: whole tile is straight-line vector code, and compiling for a v5e (PR 22)
+#: a 2048x512 stresslet tile overran the 16 MB scoped VMEM stack, 64x16384
+#: and 4096x256 (22.12M, refused after 182 s) did too, and 4096x128 took
+#: 164 s to compile — while 1024x1024, 512x2048, 256x4096 and 1024x256
+#: compile in seconds. A cap, not a repair: gridding the kernel over target
+#: tiles would lift it (ROADMAP "Open items").
+VMEM_MAX_TILE_T = 1024
+VMEM_MAX_TILE_S = 4096
+
 #: cap on the n_dev-slot ring comm buffer (floats): 4 MB of f32 leaves the
 #: pair tile its VMEM headroom on a v5-lite-class core.
 VMEM_COMM_BUDGET = 1 << 20
+
+
+#: f32 sublanes of one VMEM tile: the chip's compiler refuses a comm-slot
+#: slice whose row count is not a multiple of this
+SUBLANES = 8
+
+
+def comm_slot_rows(payload_rows: int) -> int:
+    """Rows of one comm slot: the ``3 + payload_rows`` live rows padded up
+    to whole sublane tiles (8 for the stokeslet, 16 for the stresslet)."""
+    return -(-(3 + payload_rows) // SUBLANES) * SUBLANES
 
 
 def fused_ring_footprint(payload_rows: int, n_dev: int, nt: int,
                          ns: int) -> dict:
     """Closed-form worst-case VMEM terms (floats) of the fused ring kernel
     for padded shapes: the [nt, ns] pair-tile intermediates and the
-    ``n_dev`` rotating comm slots of ``3 + payload_rows`` rows."""
+    ``n_dev`` rotating comm slots of `comm_slot_rows` rows each."""
     return {
         "pair_elems": nt * ns,
-        "comm_floats": n_dev * (3 + payload_rows) * ns,
+        "comm_floats": n_dev * comm_slot_rows(payload_rows) * ns,
     }
 
 
@@ -99,6 +121,7 @@ def fused_ring_within_budget(payload_rows: int, n_dev: int, nt: int,
     verify time, from this one definition."""
     fp = fused_ring_footprint(payload_rows, n_dev, nt, ns)
     return (fp["pair_elems"] <= VMEM_PAIR_BUDGET
+            and nt <= VMEM_MAX_TILE_T and ns <= VMEM_MAX_TILE_S
             and fp["comm_floats"] <= VMEM_COMM_BUDGET)
 
 
@@ -157,6 +180,17 @@ def pallas_calls(jaxpr):
         for sub in _sub_jaxprs(eqn.params):
             out.extend(pallas_calls(sub))
     return out
+
+
+def _last_block_dim(block_mapping) -> int:
+    """Last block dimension of one Pallas block mapping as an int: jax 0.9
+    wraps each entry as ``Blocked(block_size)``. Anything else is an error,
+    not a guess — the VMEM accounting below is built on this number."""
+    dim = block_mapping.block_shape[-1]
+    dim = getattr(dim, "block_size", dim)
+    if not isinstance(dim, int):
+        raise TypeError(f"unrecognised Pallas block dimension {dim!r}")
+    return dim
 
 
 def _as_int(x):
@@ -354,8 +388,12 @@ def _extract(kernel_jaxpr, n_dev):
                 offset=(None if dev is None
                         else _device_offset(dev, defs, n_dev))))
         elif name == "semaphore_wait":
-            sem, _tr, value = eqn.params["args_tree"].unflatten(
+            sem, _tr, value, decrement = eqn.params["args_tree"].unflatten(
                 list(eqn.invars))
+            if not getattr(decrement, "val", decrement):
+                raise ValueError(
+                    f"semaphore_wait at eqn {pos} does not decrement: the "
+                    "credit ledger assumes every wait consumes its credits")
             events.append(_SemWait(pos, sem, _as_int(value)))
         elif name == "get_barrier_semaphore":
             barrier_refs.add(id(eqn.outvars[0]))
@@ -762,8 +800,7 @@ def analyze(built) -> DmaReport:
         cshape = getattr(getattr(comm.aval, "inner_aval", comm.aval),
                          "shape", ())
         slots, rows, ns = (cshape + (0, 0, 0))[:3]
-        out_bm = gm.block_mappings[n_in]
-        nt = out_bm.block_shape[-1]
+        nt = _last_block_dim(gm.block_mappings[n_in])
         payload_rows = rows - 3
         fp = fused_ring_footprint(payload_rows, n_dev, nt, ns)
         if slots != n_dev:
@@ -789,8 +826,8 @@ def analyze(built) -> DmaReport:
         if skew_bound is not None:
             observed["phase_skew_bound"] = skew_bound
     else:
-        tile_t = gm.block_mappings[n_in].block_shape[-1]
-        tile_s = max((bm.block_shape[-1]
+        tile_t = _last_block_dim(gm.block_mappings[n_in])
+        tile_s = max((_last_block_dim(bm)
                       for bm in gm.block_mappings[:n_in]), default=0)
         fp = gridded_footprint(tile_t, tile_s)
         if not gridded_within_budget(tile_t, tile_s):

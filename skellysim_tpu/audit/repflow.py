@@ -234,9 +234,16 @@ def _sub_jaxpr(obj):
     return None
 
 
-def _names_axes(names) -> frozenset:
-    """Axis names mentioned in one shard_map in_names/out_names dict."""
-    return frozenset(str(a) for dims in names.values() for a in dims)
+def _spec_axes(spec) -> frozenset:
+    """Mesh axis names one shard_map in_specs/out_specs `PartitionSpec`
+    shards over (an entry is None, an axis name, or a tuple of them)."""
+    axes = set()
+    for entry in spec:
+        if entry is None:
+            continue
+        axes.update(str(a) for a in
+                    (entry if isinstance(entry, tuple) else (entry,)))
+    return frozenset(axes)
 
 
 def _int_value(x):
@@ -455,15 +462,20 @@ class _Analyzer:
         params = eqn.params
         mesh = params.get("mesh")
         axis_names = tuple(str(a) for a in getattr(mesh, "axis_names", ()))
-        in_names = params.get("in_names", ())
-        out_names = params.get("out_names", ())
-        inner_in = [varying(_names_axes(n)) for n in in_names]
+        if "in_specs" not in params or "out_specs" not in params:
+            # an empty default would see zero outputs and pass vacuously
+            raise KeyError(
+                f"shard_map equation at {path} carries no in_specs/"
+                f"out_specs (params: {sorted(params)}): this jax spells "
+                "them differently and the replication check cannot run")
+        in_specs, out_specs = params["in_specs"], params["out_specs"]
+        inner_in = [varying(_spec_axes(n)) for n in in_specs]
         spath = f"{path}/shard_map"
         outs = self.run_jaxpr(_sub_jaxpr(params["jaxpr"]), inner_in, spath,
                               guard, record)
         n_rep = n_var = 0
-        for i, (names, s) in enumerate(zip(out_names, outs)):
-            declared = _names_axes(names)
+        for i, (spec, s) in enumerate(zip(out_specs, outs)):
+            declared = _spec_axes(spec)
             if declared:
                 n_var += 1
             else:

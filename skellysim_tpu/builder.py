@@ -13,6 +13,7 @@ Restrictions vs the reference (deliberate, batched-tensor design):
 
 from __future__ import annotations
 
+import logging
 import os
 
 import numpy as np
@@ -25,6 +26,8 @@ from .fibers import container as fc
 from .periphery import periphery as peri
 from .system import BackgroundFlow, PointSources, System
 from .utils.rng import SimRNG
+
+logger = logging.getLogger("skellysim_tpu")
 
 
 def _load_npz(path: str, what: str) -> dict:
@@ -235,10 +238,11 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
 
     params = schema.to_runtime_params(config.params)
     if params.pair_evaluator == "ring" and mesh is None:
-        import warnings
-
-        warnings.warn("config selects pair_evaluator='ring' but no mesh was "
-                      "given to build_simulation; using the direct evaluator")
+        # through the logger, not `warnings`: the CLIs build without a mesh
+        # and must show that the ring a config asked for did not run
+        logger.warning("config selects pair_evaluator='ring' but no mesh "
+                       "was given to build_simulation; using the direct "
+                       "evaluator on one device")
     shell, shape = (None, None)
     if getattr(config, "periphery", None) is not None:
         # mixed mode gets an f32 M_inv, halving the shell preconditioner's

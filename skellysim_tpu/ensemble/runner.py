@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..guard import verdict as _verdict
-from ..system.system import SimState, System
+from ..system.system import SimState, System, reached_t_final
 
 
 class EnsembleState(NamedTuple):
@@ -336,7 +336,8 @@ class EnsembleRunner:
         """
         p = self.system.params
         states = ens.states
-        running = states.time.astype(jnp.float64) < ens.t_final
+        running = ~reached_t_final(states.time.astype(jnp.float64),
+                                   ens.t_final)
 
         if self.batch_impl == "vmap":
             args = (states, ens.di_rng) if self.di_enabled else (states,)

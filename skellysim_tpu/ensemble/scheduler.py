@@ -29,7 +29,8 @@ from ..guard import verdict as _verdict
 from ..obs import flight as flight_mod
 from ..obs import tracer as obs_tracer
 from ..solver.gmres import history_rows
-from ..system.system import SimState, crossed_write_boundary
+from ..system.system import (SimState, crossed_write_boundary,
+                             reached_t_final)
 from ..utils.rng import SimRNG
 from .runner import EnsembleRunner, lane_state, rng_carry, set_lane
 
@@ -503,7 +504,7 @@ class EnsembleScheduler:
                             lane_state(self.ens.states, lane),
                             rng_state=self._rng_state(ln.spec))
                 ln.frames += 1
-            if t_new >= ln.spec.t_final:
+            if reached_t_final(t_new, ln.spec.t_final):
                 self._retire_member(lane)
         return self.retired[retired_before:]
 

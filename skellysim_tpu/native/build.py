@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -35,7 +36,12 @@ def load_library(name: str) -> ctypes.CDLL | None:
                     check=True, capture_output=True)
                 os.replace(tmp, so)
             lib = ctypes.CDLL(so)
-        except (OSError, subprocess.CalledProcessError):
+        except (OSError, subprocess.CalledProcessError) as e:
+            detail = (getattr(e, "stderr", b"") or b"").decode(
+                errors="replace").strip()[-300:]
+            logging.getLogger("skellysim_tpu").warning(
+                "native helper %s not built (%s%s); taking the Python path",
+                name, e, f": {detail}" if detail else "")
             lib = None
         _CACHE[name] = lib
         return lib

@@ -149,6 +149,7 @@ def record_step(entry_state, new_state, solution, *, residual_true, health,
 
     from ..bodies import bodies as bd
     from ..fibers import container as fc
+    from ..parallel import compat
 
     ring = new_state.flight
     if ring is None:
@@ -162,10 +163,10 @@ def record_step(entry_state, new_state, solution, *, residual_true, health,
     shard = lax.axis_index(axis_name).astype(i32) if spmd else None
 
     def _pmax(v):
-        return lax.pmax(v, axis_name) if spmd else v
+        return compat.pmax(v, axis_name) if spmd else v
 
     def _pmin(v):
-        return lax.pmin(v, axis_name) if spmd else v
+        return compat.pmin(v, axis_name) if spmd else v
 
     old_buckets = fc.as_buckets(entry_state.fibers)
     new_buckets = fc.as_buckets(new_state.fibers)

@@ -25,12 +25,15 @@ Conventions (matched to the reference semantics):
 
 from __future__ import annotations
 
+import logging
 import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+logger = logging.getLogger("skellysim_tpu")
 
 DEFAULT_REG = 5e-3
 DEFAULT_EPS = 1e-5
@@ -237,6 +240,15 @@ def pallas_impl_for(impl: str, *arrays) -> str:
     """
     if impl == "pallas" and any(jnp.asarray(a).dtype == jnp.float64
                                 for a in arrays):
+        # never silent: this runs at trace time, so it says so once per
+        # build — in the log and as a ``fault`` event for `obs summarize`
+        # (like `parallel.compat._fused_fallback` does for the ring)
+        from ..obs import tracer as obs_tracer
+
+        logger.warning("kernel_impl='pallas' got float64 operands: the "
+                       "pallas tile is f32-only, running the 'exact' tile")
+        obs_tracer.emit("fault", kind="pallas_tile_fallback",
+                        reason="float64-operand")
         return "exact"
     return impl
 

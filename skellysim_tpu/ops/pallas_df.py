@@ -21,9 +21,10 @@ there). On real TPUs the Mosaic pipeline evaluates each kernel value once
 into a vreg (no XLA-style cross-fusion cloning), so the hazard class that
 motivated the hardening cannot arise; in `interpret=True` mode the kernel
 body runs through XLA:CPU where LLVM's FMA contraction is live, and the
-`select` hardening keeps the compensation intact there. The on-TPU
-agreement gate (`tests/test_pallas_df.py::test_tpu_agreement`) is the
-authority for real-hardware accuracy, mirroring the exact-kernel gate.
+`select` hardening keeps the compensation intact there. The on-chip
+agreement gate (`chip_smoke.py`, `gate_kernels`: both tiles against a
+NumPy f64 oracle) is the authority for real-hardware accuracy, mirroring
+the exact-kernel gate.
 
 Reference parity: same evaluator contract as `kernels.{stokeslet,
 stresslet}_direct` (self pairs drop, factor 1/(8 pi eta); stresslet factor
@@ -141,8 +142,10 @@ def _df_reduce_lanes(h, l):
         # rotation direction is irrelevant for a log-reduce (pltpu.roll
         # requires non-negative shifts): after all steps every lane holds
         # the full 128-lane total
-        hr = pltpu.roll(h, w, 1)
-        lr = pltpu.roll(l, w, 1)
+        # the shift must be 32-bit: a Python int traces as i64 under x64
+        # (which `_require_x64` demands) and Mosaic's dynamic_rotate refuses it
+        hr = pltpu.roll(h, np.int32(w), 1)
+        lr = pltpu.roll(l, np.int32(w), 1)
         h, l = _df_add(h, l, hr, lr)
         w //= 2
     return h[:, 0], l[:, 0]

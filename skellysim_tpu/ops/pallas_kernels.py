@@ -48,26 +48,16 @@ STRESSLET_TILE_S = 2048
 
 def _vma(*arrays):
     """Union of the operands' varying-mesh-axes: pallas_call under shard_map
-    must declare which mesh axes its output varies over (jax >= 0.9
-    check_vma); outside shard_map every vma is empty and this is a no-op.
-    Pre-0.9 jax (the pinned container version) has neither `jax.typeof` nor
-    the vma system — nothing to declare (`parallel.compat` runs those
-    shard_maps with replication checking off)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
+    must declare which mesh axes its output varies over (check_vma);
+    outside shard_map every vma is empty and this is a no-op."""
     out = frozenset()
     for a in arrays:
-        out |= getattr(typeof(a), "vma", frozenset())
+        out |= jax.typeof(a).vma
     return out
 
 
 def _out_struct(shape, dtype, *arrays):
-    """`jax.ShapeDtypeStruct` carrying the operands' vma union where the
-    jax version supports it (>= 0.9); plain struct on the pre-vma pinned
-    container jax, whose ShapeDtypeStruct rejects the kwarg."""
-    if getattr(jax, "typeof", None) is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
+    """`jax.ShapeDtypeStruct` carrying the operands' vma union."""
     return jax.ShapeDtypeStruct(shape, dtype, vma=_vma(*arrays))
 
 

@@ -1,4 +1,3 @@
-from .compat import use_mesh  # noqa: F401
 from .mesh import (FIBER_AXIS, MEMBER_AXIS, make_mesh,  # noqa: F401
                    make_member_mesh, shard_ensemble, shard_state)
 from .multihost import initialize as initialize_multihost  # noqa: F401

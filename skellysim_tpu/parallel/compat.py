@@ -1,39 +1,43 @@
-"""Version compatibility seam for the sharding API surface.
+"""Build-time selection of the ring transfer path, next to its fallbacks.
 
-The repo targets the modern spellings (``jax.shard_map`` with ``check_vma``,
-``jax.set_mesh``), but the pinned container jax (0.4.x) only ships
-``jax.experimental.shard_map.shard_map`` with ``check_rep`` and has no
-``jax.set_mesh`` at all — which left every mesh test red at seed. All
-sharded code routes through this module so the call sites stay written
-against the modern API and the fallback logic lives in exactly one place.
+The package calls `jax.shard_map` (with ``check_vma``) and `jax.set_mesh`
+directly — it is written for the one installed jax. What stays here is
+`pmax`/`pmin` in the form the chip compiles at 64 bits, and the seam that
+decides, per build, whether a source-block ring runs as the fused
+Pallas RDMA kernel or as the `lax.ppermute` loop, and says so when an
+environmental reason takes the fused kernel away.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 
 import jax
+from jax import lax
 
 logger = logging.getLogger("skellysim_tpu")
 
+#: `bench.py` still imports the name from here (its rewrite is ROADMAP
+#: Queue 1 item 2); package code calls `jax.shard_map` itself
+shard_map = jax.shard_map
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` where available, else the 0.4.x experimental API.
 
-    ``check_vma`` maps onto the old ``check_rep``; the fallback always
-    disables it because 0.4.x's replication checker has no rule for
-    ``while``/``scan`` bodies (every solver loop here is a `lax.while_loop`)
-    — the modern checker, where present, stays on as requested.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
+def pmax(x, axis_name):
+    """`lax.pmax` the chip's compiler accepts at 64 bits. It lowers a
+    64-bit all-reduce for sums only ("Supported lowering only of Sum all
+    reduce" — f64 is emulated on a TPU), so 64-bit values take the max over
+    an all-gather of the per-shard values: the same number on every shard."""
+    if x.dtype.itemsize == 8:
+        return lax.all_gather(x, axis_name).max(axis=0)
+    return lax.pmax(x, axis_name)
 
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+
+def pmin(x, axis_name):
+    """`lax.pmin` with `pmax`'s 64-bit route."""
+    if x.dtype.itemsize == 8:
+        return lax.all_gather(x, axis_name).min(axis=0)
+    return lax.pmin(x, axis_name)
 
 
 def fused_ring_mode(impl: str = "pallas") -> str:
@@ -108,16 +112,3 @@ def fused_ring_budget_fallback(kind: str, n_trg: int, n_src: int,
     the fault table could not tell it apart from an environmental one."""
     _fused_fallback(
         f"vmem-budget-{kind}-{n_trg}x{n_src}x{n_dev}", leg="budget")
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for sharding resolution.
-
-    ``jax.set_mesh`` on modern jax; on 0.4.x the `Mesh` object itself is the
-    (legacy) context manager. A None mesh is a no-op context either way.
-    """
-    if mesh is None:
-        return contextlib.nullcontext()
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh

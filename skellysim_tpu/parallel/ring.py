@@ -33,11 +33,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..obs import tracer as obs_tracer
 from ..ops.kernels import (DEFAULT_EPS, DEFAULT_REG, oseen_block,
                            pallas_impl_for, stokeslet_block,
                            stokeslet_block_mxu, stresslet_block,
                            stresslet_block_mxu)
-from .compat import fused_ring_mode, shard_map
+from .compat import fused_ring_mode
 from .mesh import FIBER_AXIS
 
 
@@ -60,6 +61,10 @@ def _ring_or_fused(kind, impl: str, block_fn, axis_name: str, n_dev: int,
 
         if ring_fused.fused_ring_fits(kind, r_trg.shape[0],
                                       rotating[0].shape[0], n_dev):
+            # trace-time: once per build, the ring that went fused
+            obs_tracer.emit("ring_fused", kind=kind, mode=mode,
+                            n_trg=r_trg.shape[0],
+                            n_src=rotating[0].shape[0], n_dev=n_dev)
             return ring_fused.fused_ring_block_sum(
                 kind, r_trg, *rotating, axis_name=axis_name, n_dev=n_dev,
                 interpret=(mode == "fused-interpret"))
@@ -161,9 +166,9 @@ def _ring_eval(block_fn, mesh: Mesh, axis_name: str, specs, scale, *operands,
     # _pallas_interpret): its grid emulation's dynamic_slice mixes
     # varying/non-varying operands, which the vma checker rejects — the jax
     # error message itself prescribes check_vma=False as the workaround
-    return shard_map(local, mesh=mesh, in_specs=specs,
-                     out_specs=P(axis_name),
-                     check_vma=not unroll)(*operands)
+    return jax.shard_map(local, mesh=mesh, in_specs=specs,
+                         out_specs=P(axis_name),
+                         check_vma=not unroll)(*operands)
 
 
 #: per-kernel block table for `ring_flow_local`: (exact block, MXU block,
@@ -326,9 +331,9 @@ def _ring_df(block_fn, mesh: Mesh, axis_name: str, r_src, r_trg, payload, eta,
             axis_name, n_dev, u0, sh_l, sl_l, ph_l, pl_l, unroll=unroll)
         return u / (8.0 * math.pi) / _jnp.asarray(eta, dtype=jnp.float64)  # skelly-lint: ignore[dtype-discipline] — eta scales the f64 DF accumulator; a weak-typed eta would demote it
 
-    return shard_map(local, mesh=mesh, in_specs=(spec,) * 6,
-                     out_specs=spec,
-                     check_vma=not unroll)(th, tl, sh, sl, ph, pl)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 6,
+                         out_specs=spec,
+                         check_vma=not unroll)(th, tl, sh, sl, ph, pl)
 
 
 @partial(jax.jit, static_argnames=("mesh", "axis_name", "impl"))

@@ -48,8 +48,11 @@ from the traced kernel every CI run — per-slot read/write ordering against
 the recv semaphores, credit balance, and an explicit-state search over the
 barrier protocol that both proves the ENTRY+EXIT pairing bounds phase skew
 to 1 and *derives* the entry-only counterexample as a reachable overwrite.
-The slot buffers cost ``n_dev * (3 + payload_rows) * ns`` floats of VMEM,
-bounded by `fused_ring_fits` alongside the pair tile.
+Each slot is padded to whole 8-sublane tiles (`dmaflow.comm_slot_rows`: 8
+rows for the stokeslet, 16 for the stresslet — the chip's compiler refuses
+a 6- or 12-row slot slice), so the slot buffers cost ``n_dev *
+comm_slot_rows * ns`` floats of VMEM, bounded by `fused_ring_fits`
+alongside the pair tile.
 
 The accumulation order around the ring is the SAME as the ppermute ring's
 (my block first, then left neighbor's, ...), so the two paths agree to the
@@ -67,6 +70,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..audit.dmaflow import comm_slot_rows
 from ..ops.pallas_kernels import (_PAD_SENTINEL, _out_struct, _pad_to,
                                   stokeslet_tile_sums, stresslet_tile_sums)
 
@@ -100,13 +104,14 @@ def _ring_kernel(kind: str, axis_name: str, n_dev: int):
     """Kernel body: resident targets x rotating [rows, ns] comm blocks."""
     tile_sums = (stokeslet_tile_sums if kind == "stokeslet"
                  else stresslet_tile_sums)
+    prows = _PAYLOAD_ROWS[kind]
 
     def kernel(trg_ref, blk_ref, out_ref, comm, send_sem, recv_sem):
-        my_id = lax.axis_index(axis_name)
-        right = lax.rem(my_id + 1, n_dev)
-        left = lax.rem(my_id + n_dev - 1, n_dev)
+        my_id = lax.axis_index(axis_name)   # i32; a bare n_dev is i64 on x64
+        right = lax.rem(my_id + 1, jnp.int32(n_dev))
+        left = lax.rem(my_id + n_dev - 1, jnp.int32(n_dev))
 
-        comm[0] = blk_ref[:]
+        comm[np.int32(0)] = blk_ref[:]
         out_ref[:] = jnp.zeros_like(out_ref)
 
         def neighbor_barrier():
@@ -122,20 +127,24 @@ def _ring_kernel(kind: str, axis_name: str, n_dev: int):
         # bounds cross-instance skew to < 2 phases — module docstring)
         neighbor_barrier()
 
-        for step in range(n_dev):      # static unroll: n_dev is mesh size
+        # static unroll: n_dev is mesh size. Slot indices are np.int32: a
+        # Python int traces as i64 under x64 and Mosaic's memref_slice
+        # refuses it
+        for step in map(np.int32, range(n_dev)):
+            nxt = step + np.int32(1)
             rdma = None
             if step < n_dev - 1:
                 # slot step -> right neighbor's slot step+1: every slot is
                 # written once and read once, so steps need no slot-reuse
                 # synchronization beyond the per-slot recv semaphore
                 rdma = pltpu.make_async_remote_copy(
-                    src_ref=comm.at[step], dst_ref=comm.at[step + 1],
-                    send_sem=send_sem.at[step], recv_sem=recv_sem.at[step + 1],
+                    src_ref=comm.at[step], dst_ref=comm.at[nxt],
+                    send_sem=send_sem.at[step], recv_sem=recv_sem.at[nxt],
                     device_id=right,
                     device_id_type=pltpu.DeviceIdType.LOGICAL)
                 rdma.start()           # transfer in flight DURING compute
-            blk = comm[step]
-            ux, uy, uz = tile_sums(trg_ref[:], blk[:3], blk[3:])
+            blk = comm[step]           # whole padded slot; live rows below
+            ux, uy, uz = tile_sums(trg_ref[:], blk[:3], blk[3:3 + prows])
             out_ref[0, :] += ux
             out_ref[1, :] += uy
             out_ref[2, :] += uz
@@ -169,22 +178,27 @@ def fused_ring_block_sum(kind: str, r_trg, src, payload, *, axis_name: str,
     trg_T = _pad_to(r_trg.T, nt, axis=1)
     src_T = _pad_to(src.T, ns, axis=1, value=_PAD_SENTINEL)
     pay_T = _pad_to(payload.reshape(n_src, prows).T, ns, axis=1)
-    blk = jnp.concatenate([src_T, pay_T], axis=0)  # [3 + prows, ns]
+    # [slot_rows, ns]: the live 3 + prows rows, zero rows up to a whole
+    # sublane tile so every comm-slot load and RDMA is tile-aligned
+    slot_rows = comm_slot_rows(prows)
+    blk = _pad_to(jnp.concatenate([src_T, pay_T], axis=0), slot_rows, axis=0)
 
     # no grid: operands stage whole-block into VMEM (the budget check in
     # `fused_ring_fits` is what makes that legal), comm slots in VMEM so
     # the RDMA lands directly where the next step computes
-    compiler_params = pltpu.TPUCompilerParams(collective_id=_COLLECTIVE_ID)
+    compiler_params = pltpu.CompilerParams(collective_id=_COLLECTIVE_ID)
     u_T = pl.pallas_call(
         _ring_kernel(kind, axis_name, n_dev),
         out_shape=_out_struct((3, nt), dtype, trg_T, blk),
         scratch_shapes=(
-            pltpu.VMEM((n_dev, 3 + prows, ns), dtype),
+            pltpu.VMEM((n_dev, slot_rows, ns), dtype),
             pltpu.SemaphoreType.DMA((n_dev,)),
             pltpu.SemaphoreType.DMA((n_dev,)),
         ),
         compiler_params=compiler_params,
-        interpret=interpret,
+        # the TPU interpreter, not the generic one: only it emulates remote
+        # DMA and semaphores (on the virtual CPU devices of a shard_map)
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(trg_T, blk)
     return u_T.T[:n_trg]
 
@@ -200,7 +214,6 @@ def auditable_kernels():
 
     from ..audit.dmaflow import pallas_calls
     from ..audit.registry import AuditKernel, BuiltKernel
-    from .compat import shard_map
     from .mesh import FIBER_AXIS, make_mesh
 
     n_dev, n_trg, n_src = 8, 8, 128
@@ -210,7 +223,7 @@ def auditable_kernels():
             payload_shape = ((n_src * n_dev, 3) if kind == "stokeslet"
                              else (n_src * n_dev, 3, 3))
             mesh = make_mesh(n_dev)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda r, s, w: fused_ring_block_sum(
                     kind, r, s, w, axis_name=FIBER_AXIS, n_dev=n_dev),
                 mesh=mesh,

@@ -64,7 +64,7 @@ from ..periphery import periphery as peri
 from ..solver import gmres, gmres_ir
 from ..system.system import (SimState, StepInfo, _cast_floats, _rewrap_bodies,
                              _rewrap_fibers, body_buckets, fiber_buckets)
-from .compat import shard_map
+from .compat import pmax
 from .mesh import FIBER_AXIS
 
 
@@ -722,7 +722,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                     fibers=_rewrap_fibers(new_state.fibers, repinned))
             err_local = jnp.max(jnp.stack(
                 [fc.fiber_error(g) for g in fiber_buckets(new_state.fibers)]))
-            fiber_error = lax.pmax(err_local, axis)
+            fiber_error = pmax(err_local, axis)
 
         # the guard health word rides the mesh program too: the solver's
         # bits are replicated (psum'd reductions), the fiber-error check is
@@ -776,22 +776,23 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                                 loss_of_accuracy=False, refines=0,
                                 cycles=0, history=None, health=0,
                                 dt_used=0.0, guard_retries=0))
-    # check_vma off: the 0.4.x replication checker has no while-loop rule
-    # (every solver loop is lax.while_loop), and replicated-output
+    # check_vma off on this one program: with it on, the GMRES while_loop's
+    # carries (H, cs, sn — initialised unvarying, fed by psum'd dots the
+    # checker sees as varying over `fib`) fail to trace until each initial
+    # carry gets a `lax.pcast` (tried in PR 22). Replicated-output
     # correctness is guaranteed by construction here (psum-or-replicated
-    # inputs only — see the module docstring) and pinned by the parity tests
+    # inputs only — see the module docstring), audited by `audit.repflow`
+    # and pinned by the parity tests
     if has_pair:
         # the plan's traced anchors enter as one replicated operand so a
         # quantized anchor hop under drift reuses the compiled program
-        sharded = shard_map(local_step, mesh=mesh,
-                            in_specs=(state_specs, P()),
-                            out_specs=(state_specs, sol_specs, info_specs),
-                            check_vma=False)
+        sharded = jax.shard_map(
+            local_step, mesh=mesh, in_specs=(state_specs, P()),
+            out_specs=(state_specs, sol_specs, info_specs), check_vma=False)
     else:
-        sharded = shard_map(lambda st: local_step(st), mesh=mesh,
-                            in_specs=(state_specs,),
-                            out_specs=(state_specs, sol_specs, info_specs),
-                            check_vma=False)
+        sharded = jax.shard_map(
+            lambda st: local_step(st), mesh=mesh, in_specs=(state_specs,),
+            out_specs=(state_specs, sol_specs, info_specs), check_vma=False)
 
     def step(st, pair_anchors=None):
         if has_pair:

@@ -124,6 +124,19 @@ def crossed_write_boundary(t_new: float, dt: float, dt_write: float) -> bool:
     return idx(t_new) > idx(t_new - dt)
 
 
+def reached_t_final(t, t_final):
+    """Float-robust ``t >= t_final`` (floats or arrays): a 1e-9 relative
+    shortfall counts as reached, the same tolerance as
+    `crossed_write_boundary`. A clock accumulated by repeated addition can
+    land a hair below the end (ten 0.1-steps reach 0.9999999999999999), and
+    on a TPU every f64 scalar is emulated and reads back a hair off (two
+    0.005-steps read back under 0.01): either way a plain ``<`` buys one
+    step more than ``t_final / dt``. Shared by `System._run_loop` and the
+    ensemble (device mask and host retire) so they stop on the same step.
+    """
+    return t >= t_final - 1e-9 * abs(t_final)
+
+
 class StepInfo(NamedTuple):
     converged: jnp.ndarray
     iters: jnp.ndarray
@@ -1466,7 +1479,7 @@ class System:
         donate_ok = (not p.adaptive_timestep_flag
                      and jax.default_backend() != "cpu")
         step_fn = self._step_donating if donate_ok else self.step
-        while float(state.time) < p.t_final:
+        while not reached_t_final(float(state.time), p.t_final):
             if max_steps is not None and n_steps >= max_steps:
                 break
             backup = state
@@ -1487,10 +1500,10 @@ class System:
             with obs_tracer.span("step", step=n_steps) as sp:
                 wall0 = _time.perf_counter()
                 new_state, solution, info = step_fn(state)
-                # host fetch, not block_until_ready: blocking on one leaf
-                # was observed returning before the program finished,
-                # undermeasuring wall_s by >100x — the fetch doubles as the
-                # span's device-work sync
+                # host fetch: the loop needs the value anyway, and it doubles
+                # as the span's device-work sync (on the v5e
+                # block_until_ready waits just as long — chip_smoke.py
+                # times both; an older backend was seen returning early)
                 residual = float(info.residual)
                 wall_s = _time.perf_counter() - wall0
                 sp.note(iters=int(info.iters), residual=residual)

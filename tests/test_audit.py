@@ -38,7 +38,7 @@ def _ids(findings):
 
 @pytest.fixture(scope="module")
 def psum_prog():
-    from skellysim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from skellysim_tpu.parallel.mesh import FIBER_AXIS, make_mesh
 
     mesh = make_mesh(8)
@@ -199,7 +199,7 @@ def test_retrace_budget_flags_over_budget_and_missing_probe():
 
 def _shmap_prog(inner, in_specs, out_specs, *args, name="synthetic"):
     """A shard_map program on the 8-device mesh, registered audit-style."""
-    from skellysim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from skellysim_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(8)
@@ -357,7 +357,7 @@ def _two_axis_prog(full_reduce: bool):
     must flag — a single-axis analyzer would call it replicated."""
     from jax.sharding import PartitionSpec as P
 
-    from skellysim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from skellysim_tpu.parallel.mesh import (FIBER_AXIS, MEMBER_AXIS,
                                              make_2d_mesh)
 

@@ -1,0 +1,130 @@
+"""The chip's compiler, without the chip: the Pallas kernels of the main
+path compiled at real widths for a DESCRIBED TPU v5e 2x2 topology.
+
+Interpret mode hides what Mosaic refuses (a 6-sublane comm-slot slice, an
+i64 rotate shift); these compiles do not. Nothing runs — a pass says the
+kernel compiles for the chip, never that it computes the right thing
+(`chip_smoke.py` is the run). The topology is described inside a
+module-scoped fixture, never at import: only one process may load the TPU
+library, and every xdist worker imports this file. All chip compiles live
+in THIS file for the same reason.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *structs):
+    return jax.jit(fn).lower(*structs).compile().as_text()
+
+
+def _cloud(n_src, n_trg, payload_tail, dtype, sharding):
+    def st(shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return st((n_src, 3)), st((n_trg, 3)), st((n_src,) + payload_tail)
+
+
+@pytest.mark.parametrize("kind,payload_tail", [("stokeslet", (3,)),
+                                               ("stresslet", (3, 3))])
+def test_f32_pallas_tile_compiles(one_chip, kind, payload_tail):
+    """The f32 VMEM tiles at the 1,024-fiber smoke scene's width (65,536^2
+    pairs) — under x64, as every CLI runs them."""
+    from skellysim_tpu.ops import pallas_kernels
+
+    fn = getattr(pallas_kernels, f"{kind}_pallas")
+    r_src, r_trg, pay = _cloud(65536, 65536, payload_tail, F32, one_chip)
+    assert jax.config.jax_enable_x64
+    text = _compile(lambda s, t, p: fn(s, t, p, 1.0), r_src, r_trg, pay)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,payload_tail", [("stokeslet", (3,)),
+                                               ("stresslet", (3, 3))])
+def test_pallas_df_tile_compiles(one_chip, kind, payload_tail):
+    """The double-float Pallas tiles, f64 in / f64 out under x64 (Mosaic
+    refused the lane-roll's i64 shift before PR 22)."""
+    from skellysim_tpu.ops import pallas_df
+
+    fn = getattr(pallas_df, f"{kind}_pallas_df")
+    r_src, r_trg, pay = _cloud(16384, 16384, payload_tail, jnp.float64,
+                               one_chip)
+    text = _compile(lambda s, t, p: fn(s, t, p, 1.0), r_src, r_trg, pay)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,payload_tail", [("stokeslet", (3,)),
+                                               ("stresslet", (3, 3))])
+def test_fused_ring_compiles_on_four_chips(topo, kind, payload_tail):
+    """The fused RDMA ring under shard_map on the four described chips,
+    1,024 rows per shard (Mosaic refused the 6/12-sublane comm slots
+    before PR 22), at a shape `fused_ring_fits` accepts."""
+    from skellysim_tpu.parallel.mesh import FIBER_AXIS
+    from skellysim_tpu.parallel.ring_fused import (fused_ring_block_sum,
+                                                   fused_ring_fits)
+
+    n_dev, rows = 4, 1024
+    assert fused_ring_fits(kind, rows, rows, n_dev)
+    mesh = Mesh(topo.devices[:n_dev], (FIBER_AXIS,))
+    spec = NamedSharding(mesh, P(FIBER_AXIS))
+    r_src, r_trg, pay = _cloud(n_dev * rows, n_dev * rows, payload_tail, F32,
+                               spec)
+    ring = jax.shard_map(
+        lambda t, s, p: fused_ring_block_sum(kind, t, s, p,
+                                             axis_name=FIBER_AXIS,
+                                             n_dev=n_dev),
+        mesh=mesh, in_specs=(P(FIBER_AXIS),) * 3, out_specs=P(FIBER_AXIS))
+    text = _compile(ring, r_trg, r_src, pay)
+    assert "tpu_custom_call" in text
+
+
+def test_df_direct_tile_compiles(one_chip):
+    """The XLA double-float tile (the resolved default refinement tile on a
+    chip) at 8,192^2."""
+    from skellysim_tpu.ops.df_kernels import stokeslet_direct_df
+
+    r_src, r_trg, f = _cloud(8192, 8192, (3,), jnp.float64, one_chip)
+    _compile(lambda s, t, p: stokeslet_direct_df(s, t, p, 1.0), r_src, r_trg,
+             f)
+
+
+def test_f64_direct_tile_compiles(one_chip):
+    """The native-f64 exact tile (emulated on the chip) at 8,192^2 — the
+    tile `pallas_impl_for` swaps in for f64 operands."""
+    from skellysim_tpu.ops.kernels import stokeslet_direct
+
+    r_src, r_trg, f = _cloud(8192, 8192, (3,), jnp.float64, one_chip)
+    _compile(lambda s, t, p: stokeslet_direct(s, t, p, 1.0), r_src, r_trg, f)

@@ -1,18 +1,14 @@
-"""Direct coverage for `parallel.compat` — the jax-version seam itself.
+"""The start-up seams: `jax.shard_map` as the package calls it, the ring
+transfer-mode selection in `parallel.compat`, and the compile-cache rule in
+`utils.bootstrap`.
 
-The seam un-broke 27 seed tests (PR 3) but until ISSUE 8 had no tests of
-its own: everything exercised it only through the big SPMD programs. These
-pin the three behaviors the call sites rely on, fast-tier sized:
-
-* `shard_map` routes to whatever API the running jax ships, and the 0.4.x
-  fallback ALWAYS disables replication checking (`check_rep=False`) — the
-  old checker has no while/scan rule, and every solver loop here is a
-  `lax.while_loop` (requesting `check_vma=True` must still build);
-* `use_mesh` yields a context manager on every jax (modern `jax.set_mesh`
-  or the legacy Mesh-as-context), None being a no-op;
+* `jax.shard_map` with ``check_vma=True`` builds and runs a `lax.while_loop`
+  body (every solver loop here is one) — the ring evaluators rely on it;
 * `fused_ring_mode` selects the ring transfer path at build time:
   ppermute on CPU / non-pallas tiles / explicit opt-out, the fused Pallas
-  kernel only where the backend can compile it.
+  kernel only where the backend can compile it;
+* the persistent compile cache can be placed from outside: where
+  ``JAX_COMPILATION_CACHE_DIR`` is set, no flag or config moves it.
 """
 
 import jax
@@ -22,16 +18,15 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from skellysim_tpu.parallel import make_mesh
-from skellysim_tpu.parallel.compat import (fused_ring_mode, shard_map,
-                                           use_mesh)
+from skellysim_tpu.parallel.compat import fused_ring_mode
 from skellysim_tpu.parallel.mesh import FIBER_AXIS
 
 
-def test_shard_map_fallback_selection():
-    """The wrapper uses `jax.shard_map` where it exists, else the 0.4.x
-    experimental spelling — exactly one of the two, chosen by presence."""
+def test_shard_map_psum_over_the_fiber_axis():
+    """`jax.shard_map` over `make_mesh`'s fiber axis: psum of per-shard
+    partials lands on every shard."""
     mesh = make_mesh(2)
-    f = shard_map(lambda x: lax.psum(x, FIBER_AXIS), mesh=mesh,
+    f = jax.shard_map(lambda x: lax.psum(x, FIBER_AXIS), mesh=mesh,
                   in_specs=(P(FIBER_AXIS),), out_specs=P(FIBER_AXIS))
     x = jnp.arange(8, dtype=jnp.float32)
     out = f(x)
@@ -41,10 +36,8 @@ def test_shard_map_fallback_selection():
 
 
 def test_shard_map_check_vma_survives_while_loop():
-    """check_vma=True must BUILD AND RUN a while_loop body on the pinned
-    0.4.x jax: the fallback maps it onto check_rep=False because the old
-    replication checker rejects every solver loop (the exact seed
-    breakage this seam exists to absorb)."""
+    """check_vma=True must BUILD AND RUN a while_loop body whose carry
+    mixes a varying operand with a psum — the shape of every ring solve."""
     mesh = make_mesh(4)
 
     def local(x):
@@ -59,19 +52,84 @@ def test_shard_map_check_vma_survives_while_loop():
         y, _ = lax.while_loop(cond, body, (x, jnp.int32(0)))
         return y
 
-    f = shard_map(local, mesh=mesh, in_specs=(P(FIBER_AXIS),),
-                  out_specs=P(FIBER_AXIS), check_vma=True)
+    f = jax.shard_map(local, mesh=mesh, in_specs=(P(FIBER_AXIS),),
+                      out_specs=P(FIBER_AXIS), check_vma=True)
     out = f(jnp.zeros(8, dtype=jnp.float32))
     assert jnp.allclose(out, 3.0)
 
 
-def test_use_mesh_none_and_mesh():
-    with use_mesh(None):
-        pass  # no-op context
+def test_set_mesh_context_keeps_sharded_work_running():
     mesh = make_mesh(2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # inside the active-mesh context sharded computation still works
         assert jnp.asarray(1.0) + 1.0 == 2.0
+
+
+# ------------------------------------------------- compile-cache placement
+
+@pytest.fixture
+def cache_config():
+    """Restore jax's cache directory after a test moved it."""
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("asked", ["auto", "a-directory", "flag"])
+def test_cache_dir_from_environment_is_never_overridden(
+        monkeypatch, tmp_path, cache_config, asked):
+    """With JAX_COMPILATION_CACHE_DIR set the environment places the cache:
+    "auto", an explicit directory and a `--jax-cache` flag all leave jax's
+    own setting alone and report the environment's directory."""
+    from skellysim_tpu.cli import resolve_cache_dir
+    from skellysim_tpu.utils import bootstrap
+
+    placed = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    jax.config.update("jax_compilation_cache_dir", placed)  # as jax reads it
+    want = {"auto": "auto", "a-directory": str(tmp_path / "other"),
+            "flag": resolve_cache_dir("no-such-config.toml",
+                                      flag=str(tmp_path / "flagged"),
+                                      off=False)}[asked]
+    assert bootstrap.enable_compilation_cache(want) == placed
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert not (tmp_path / "other").exists()
+    assert not (tmp_path / "flagged").exists()
+
+
+def test_cache_dir_defaults_to_the_checkout(monkeypatch, cache_config):
+    """Unset, "auto" is the fixed `<checkout>/.jax_cache` (the path is part
+    of the cache key: a directory that moves never hits)."""
+    import os
+
+    from skellysim_tpu.utils import bootstrap
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert bootstrap.enable_compilation_cache("auto") == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert bootstrap.enable_compilation_cache("off") is None
+
+
+def test_cache_wiring_surfaces_everything_but_an_unwritable_dir(
+        monkeypatch, tmp_path, cache_config):
+    """An unwritable directory must not kill a run; any other failure (a
+    config key this jax does not know) must surface."""
+    from skellysim_tpu.utils import bootstrap
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    blocker = tmp_path / "a-file"
+    blocker.write_text("")
+    assert bootstrap.enable_compilation_cache(str(blocker / "sub")) is None
+
+    def boom(key, value):
+        raise AttributeError(f"Unrecognized config option: {key}")
+
+    with monkeypatch.context() as m:
+        m.setattr(jax.config, "update", boom)
+        with pytest.raises(AttributeError):
+            bootstrap.enable_compilation_cache(str(tmp_path / "fine"))
 
 
 def test_fused_ring_mode_cpu_defaults_to_ppermute(monkeypatch):
@@ -178,23 +236,3 @@ def test_fused_ring_fallback_legs(monkeypatch):
     assert fault["kind"] == "fused_ring_fallback"
     assert fault["leg"] == "budget"
     assert "vmem-budget-stokeslet-4096x4096x8" == fault["reason"]
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="compiled fused ring needs a TPU backend")
-def test_fused_ring_executes_on_tpu():
-    """On real hardware the fused kernel must agree with the ppermute ring
-    (same tile math, same accumulation order) to f32 tile tolerance."""
-    import numpy as np
-
-    from skellysim_tpu.ops import kernels
-    from skellysim_tpu.parallel.ring import ring_stokeslet
-
-    rng = np.random.default_rng(0)
-    n = 512
-    r = jnp.asarray(rng.uniform(-1, 1, (n, 3)), dtype=jnp.float32)
-    f = jnp.asarray(rng.standard_normal((n, 3)), dtype=jnp.float32)
-    mesh = make_mesh(min(4, len(jax.devices())))
-    ref = kernels.stokeslet_direct(r, r, f, 1.0)
-    u = ring_stokeslet(r, r, f, 1.0, mesh=mesh, impl="pallas")
-    assert float(jnp.abs(u - ref).max()) < 5e-5

@@ -25,7 +25,7 @@ from skellysim_tpu.audit import dmaflow, engine
 from skellysim_tpu.audit.cli import main as audit_main
 from skellysim_tpu.audit.registry import AuditKernel, BuiltKernel
 from skellysim_tpu.config import toml_io
-from skellysim_tpu.parallel.compat import shard_map
+from jax import shard_map
 from skellysim_tpu.parallel.mesh import FIBER_AXIS, make_mesh
 
 N_DEV = 4
@@ -38,8 +38,8 @@ def _ring_variant(variant, n_dev=N_DEV):
 
     def kernel(blk_ref, out_ref, comm, send_sem, recv_sem):
         my = lax.axis_index(FIBER_AXIS)
-        right = lax.rem(my + 1, n_dev)
-        left = lax.rem(my + n_dev - 1, n_dev)
+        right = lax.rem(my + 1, jnp.int32(n_dev))
+        left = lax.rem(my + n_dev - 1, jnp.int32(n_dev))
         comm[0] = blk_ref[:]
         out_ref[:] = jnp.zeros_like(out_ref)
 
@@ -79,13 +79,14 @@ def _built(variant, n_dev=N_DEV):
     def local(blk):
         return pl.pallas_call(
             _ring_variant(variant, n_dev),
-            out_shape=jax.ShapeDtypeStruct((ROWS, NS), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((ROWS, NS), jnp.float32,
+                                           vma=frozenset({FIBER_AXIS})),
             scratch_shapes=(
                 pltpu.VMEM((n_dev, ROWS, NS), jnp.float32),
                 pltpu.SemaphoreType.DMA((n_dev,)),
                 pltpu.SemaphoreType.DMA((n_dev,)),
             ),
-            compiler_params=pltpu.TPUCompilerParams(collective_id=7),
+            compiler_params=pltpu.CompilerParams(collective_id=7),
         )(blk)
 
     f = shard_map(local, mesh=make_mesh(n_dev),
@@ -231,7 +232,8 @@ def test_budget_perturbation_flips_builder_and_verifier_together(
 
 def test_footprint_formula_values():
     fp = dmaflow.fused_ring_footprint(3, 8, 8, 128)
-    assert fp == {"pair_elems": 1024, "comm_floats": 8 * 6 * 128}
+    # each 6-row stokeslet slot is padded to one 8-sublane tile
+    assert fp == {"pair_elems": 1024, "comm_floats": 8 * 8 * 128}
     assert dmaflow.gridded_footprint(256, 1024) == {"pair_elems": 262144}
     assert not dmaflow.gridded_within_budget(1024, 2048)
 

@@ -326,7 +326,7 @@ def test_in_mesh_escalation_pattern_analyzes_replication_safe():
     from jax.sharding import PartitionSpec as P
 
     from skellysim_tpu.audit import repflow
-    from skellysim_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from skellysim_tpu.parallel.mesh import FIBER_AXIS, make_mesh
 
     mesh = make_mesh(2)

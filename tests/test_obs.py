@@ -867,15 +867,16 @@ def test_perf_compare_skips_unparseable_rounds(tmp_path):
 
 def test_perf_real_benchmarks_trajectory():
     """Acceptance pin: `obs perf --compare benchmarks/` renders the
-    r01..r08 multichip trajectory and the gate passes on the checked-in
+    r02..r08 multichip trajectory (r01, a record of a backend that is
+    gone, was deleted in PR 22) and the gate passes on the checked-in
     (downscaled) rounds."""
     from skellysim_tpu.obs.perf import render_report
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     report, rc = render_report(os.path.join(repo, "benchmarks"))
     assert rc == 0
-    assert "== multichip trajectory (8 round(s)) ==" in report
-    for label in ("r01", "r07", "r08"):
+    assert "== multichip trajectory (7 round(s)) ==" in report
+    for label in ("r02", "r07", "r08"):
         assert label in report
     assert "diff r07 -> r08" in report
     assert "coupled_spmd.d8.speedup_vs_1dev: 0.44 -> 0.63" in report

@@ -4,14 +4,9 @@ Mirrors the XLA DF kernel pins (`test_df_kernels.py`): the fused Pallas
 tiles must deliver the same ~1e-14-class relative accuracy from pure f32
 pair arithmetic, drop self pairs, survive padding, and ride the
 `kernels.*_direct(impl="pallas_df")` seam. The real-hardware authority is
-the `@pytest.mark.tpu` agreement gate at the bottom (interpret mode runs
-XLA:CPU arithmetic, not Mosaic's).
+`chip_smoke.py`'s pallas_df gate (interpret mode runs XLA:CPU arithmetic,
+not Mosaic's); `tests/test_chip_compile.py` compiles the tiles for the chip.
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -128,61 +123,3 @@ def test_mixed_solver_accepts_pallas_df():
 
     _, _, info = jax.jit(system._solve_impl)(state)
     assert float(info.residual_true) <= 1e-10
-
-
-_TPU_SNIPPET = r"""
-import json
-import numpy as np
-import jax
-jax.config.update("jax_enable_x64", True)
-import jax.numpy as jnp
-from skellysim_tpu.ops.pallas_df import stokeslet_pallas_df, stresslet_pallas_df
-
-rng = np.random.default_rng(7)
-r_src = rng.uniform(-5, 5, (1024, 3))
-r_trg = np.concatenate([r_src[:128], rng.uniform(-5, 5, (517, 3))], axis=0)
-f = rng.standard_normal((1024, 3))
-S = rng.standard_normal((1024, 3, 3))
-
-d = r_trg[:, None, :] - r_src[None, :, :]
-r2 = np.sum(d * d, axis=-1)
-rinv = np.where(r2 > 0, 1.0 / np.sqrt(np.where(r2 > 0, r2, 1.0)), 0.0)
-df = np.einsum("tsk,sk->ts", d, f)
-ref_sto = (np.einsum("ts,sk->tk", rinv, f)
-           + np.einsum("ts,tsk->tk", df * rinv**3, d)) / (8 * np.pi)
-dSd = np.einsum("tsi,sij,tsj->ts", d, S, d)
-ref_str = np.einsum("ts,tsk->tk", -3.0 * dSd * rinv**5, d) / (8 * np.pi)
-
-got_sto = np.asarray(stokeslet_pallas_df(
-    jnp.asarray(r_src), jnp.asarray(r_trg), jnp.asarray(f), 1.0))
-got_str = np.asarray(stresslet_pallas_df(
-    jnp.asarray(r_src), jnp.asarray(r_trg), jnp.asarray(S), 1.0))
-print("RESULT=" + json.dumps({
-    "backend": jax.default_backend(),
-    "err_sto": float(np.linalg.norm(got_sto - ref_sto)
-                     / np.linalg.norm(ref_sto)),
-    "err_str": float(np.linalg.norm(got_str - ref_str)
-                     / np.linalg.norm(ref_str)),
-}))
-"""
-
-
-@pytest.mark.tpu
-@pytest.mark.slow  # interpret-mode pallas: minutes-class on the 1-core CPU tier
-def test_tpu_agreement():
-    """Mosaic-compiled DF tiles on the real chip: the hardware authority for
-    the compensation surviving the TPU pipeline (the reference's 5e-9
-    backend-agreement gate, `kernel_test.cpp:93`, with 4+ orders margin)."""
-    from tests.test_tpu_device import _tpu_available, _tpu_env
-
-    if not _tpu_available():
-        pytest.skip("no reachable TPU backend")
-    p = subprocess.run([sys.executable, "-c", _TPU_SNIPPET],
-                       capture_output=True, text=True, timeout=540,
-                       env=_tpu_env())
-    assert p.returncode == 0, p.stderr[-2000:]
-    line = next(ln for ln in p.stdout.splitlines() if ln.startswith("RESULT="))
-    res = json.loads(line[len("RESULT="):])
-    assert res["backend"] == "tpu"
-    assert res["err_sto"] < 1e-12, res
-    assert res["err_str"] < 1e-12, res

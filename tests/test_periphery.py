@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from scipy.spatial import ConvexHull
 
+import jax
 import jax.numpy as jnp
 
 from skellysim_tpu.ops import kernels
@@ -148,3 +149,23 @@ def test_fiber_inside_shell_coupled_solve():
     assert not bool(system._collision_jit(new_state))
     # the shell density actually responded to the flow
     assert float(jnp.linalg.norm(new_state.shell.density)) > 0.0
+
+
+@pytest.mark.parametrize("rows", [64, 70, 33])
+def test_large_f64_operator_applies_in_row_blocks(monkeypatch, rows):
+    """`peri._apply_operator` blocks a large float64 operator by rows (a TPU
+    emulates the f64 dot through an 8x f32 expansion of the whole matrix —
+    the walkthrough's 6,000-node shell did not fit a v5e without this). The
+    blocked product equals ``op @ x``, ragged last block included; f32 and
+    small operators keep the plain product."""
+    monkeypatch.setattr(peri, "_F64_ROW_BLOCK", 16)
+    rng = np.random.default_rng(rows)
+    op = jnp.asarray(rng.standard_normal((rows, 40)))
+    x = jnp.asarray(rng.standard_normal(40))
+    assert "dynamic_slice" in str(jax.make_jaxpr(peri._apply_operator)(op, x))
+    np.testing.assert_allclose(np.asarray(peri._apply_operator(op, x)),
+                               np.asarray(op) @ np.asarray(x), rtol=1e-13)
+    for plain in ((op.astype(jnp.float32), x.astype(jnp.float32)),
+                  (op[:32], x)):
+        assert "dynamic_slice" not in str(
+            jax.make_jaxpr(peri._apply_operator)(*plain))

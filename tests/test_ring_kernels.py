@@ -211,30 +211,55 @@ def test_ring_pallas_impl_matches_single_program():
 def test_fused_ring_traces_with_correct_shapes():
     """The fused Pallas ring kernel (`parallel.ring_fused`) abstract-evals
     inside shard_map with the ring contract's shapes — compiled execution
-    is TPU-only (tests/test_compat.py::test_fused_ring_executes_on_tpu),
-    but shape/trace regressions must fail on CPU CI too."""
+    is TPU-only (`python chip_smoke.py --chips 4`; the compile itself is
+    pinned in tests/test_chip_compile.py), but shape/trace regressions
+    must fail on CPU CI too."""
     from jax.sharding import PartitionSpec as P
 
-    from skellysim_tpu.parallel.compat import shard_map
     from skellysim_tpu.parallel.ring_fused import fused_ring_block_sum
 
     mesh = make_mesh(4)
     st = jax.ShapeDtypeStruct((64, 3), jnp.float32)
     out = jax.eval_shape(
-        shard_map(lambda r, s, f: fused_ring_block_sum(
+        jax.shard_map(lambda r, s, f: fused_ring_block_sum(
             "stokeslet", r, s, f, axis_name="fib", n_dev=4),
             mesh=mesh, in_specs=(P("fib"),) * 3, out_specs=P("fib"),
             check_vma=False), st, st, st)
     assert out.shape == (64, 3) and out.dtype == jnp.float32
     # stresslet family: [ns, 3, 3] payload
     out = jax.eval_shape(
-        shard_map(lambda r, s, f: fused_ring_block_sum(
+        jax.shard_map(lambda r, s, f: fused_ring_block_sum(
             "stresslet", r, s, f, axis_name="fib", n_dev=4),
             mesh=mesh,
             in_specs=(P("fib"), P("fib"), P("fib", None, None)),
             out_specs=P("fib"), check_vma=False),
         st, st, jax.ShapeDtypeStruct((64, 3, 3), jnp.float32))
     assert out.shape == (64, 3)
+
+
+@pytest.mark.parametrize("kind", ["stokeslet", "stresslet"])
+def test_fused_ring_interpret_matches_ppermute_ring(monkeypatch, kind):
+    """The fused RDMA ring EXECUTES off the chip on the TPU interpreter
+    (`pltpu.InterpretParams`: remote DMA + semaphores emulated on the
+    virtual CPU devices), padded comm slots and all, and agrees with the
+    `lax.ppermute` ring on the same Pallas tile math."""
+    n_dev, n = 4, 4 * 40           # 40 rows per shard: not a tile multiple
+    mesh = make_mesh(n_dev)
+    rng = np.random.default_rng(7)
+    r = jnp.asarray(rng.uniform(-10, 10, (n, 3)), dtype=jnp.float32)
+    tail = (3,) if kind == "stokeslet" else (3, 3)
+    pay = jnp.asarray(rng.standard_normal((n,) + tail), dtype=jnp.float32)
+    ring = ring_stokeslet if kind == "stokeslet" else ring_stresslet
+
+    def run(mode):
+        monkeypatch.setenv("SKELLY_FUSED_RING", mode)
+        jax.clear_caches()          # the mode is read at trace time
+        return np.asarray(ring(r, r, pay, 1.2, mesh=mesh, impl="pallas"))
+
+    fused, ppermute = run("interpret"), run("ppermute")
+    err = np.linalg.norm(fused - ppermute) / np.linalg.norm(ppermute)
+    assert err < 1e-5, err
+    assert not np.array_equal(fused, np.zeros_like(fused))
 
 
 def test_fused_ring_fits_budget():

@@ -12,7 +12,7 @@ import numpy as np
 
 from skellysim_tpu.fibers import container as fc
 from skellysim_tpu.params import Params
-from skellysim_tpu.parallel import make_mesh, shard_state, use_mesh
+from skellysim_tpu.parallel import make_mesh, shard_state
 from skellysim_tpu.system import BackgroundFlow, System
 
 N_DEV = 8
@@ -43,7 +43,7 @@ def test_ring_solve_matches_direct_solve():
 
     sys_ring = System(Params(**params, pair_evaluator="ring"), mesh=mesh)
     state = shard_state(_state(sys_ring), mesh)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_ring, sol_ring, info_ring = sys_ring.step(state)
         jax.block_until_ready(s_ring)
 
@@ -96,7 +96,7 @@ def test_ring_coupled_solve_matches_direct_solve():
     # of the (tiny) dense operators; the ring path is what's under test
     state = shard_state(_coupled_state(sys_ring), mesh,
                         allow_replicated_shell=True)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_ring, sol_ring, info_ring = sys_ring.step(state)
         jax.block_until_ready(s_ring)
 
@@ -120,7 +120,7 @@ def test_ring_indivisible_fiber_nodes_raises():
                              pair_evaluator="ring"), mesh=mesh)
     state = _state(sys_ring, n_fibers=3, n_nodes=8)  # 24 nodes % 5 != 0
     with pytest.raises(ValueError, match="divisible by the mesh size"):
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             sys_ring.step(state)
 
 
@@ -152,6 +152,6 @@ def test_builder_autopads_ring_fiber_batch(tmp_path):
     assert (nf * n) % mesh.size == 0
     assert int(np.asarray(state.fibers.active).sum()) == 3
     # the padded state still solves
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         _, _, info = system.step(shard_state(state, mesh))
     assert bool(info.converged)

@@ -13,7 +13,7 @@ import numpy as np
 
 from skellysim_tpu.fibers import container as fc
 from skellysim_tpu.params import Params
-from skellysim_tpu.parallel import make_mesh, shard_state, use_mesh
+from skellysim_tpu.parallel import make_mesh, shard_state
 from skellysim_tpu.periphery import periphery as peri
 from skellysim_tpu.periphery.precompute import precompute_periphery
 from skellysim_tpu.system import System
@@ -56,7 +56,7 @@ def test_sharded_shell_solve_matches_replicated():
     state = shard_state(_coupled_state(sys_sh, shell_data), mesh)
     # the dense operators really are distributed row-wise
     assert len(state.shell.M_inv.sharding.device_set) == N_DEV
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_sh, sol_sh, info_sh = sys_sh.step(state)
         jax.block_until_ready(sol_sh)
 
