@@ -179,6 +179,7 @@ def place(group: BodyGroup):
     return nodes, normals, sites
 
 
+@jax.named_scope("body")
 def update_cache(group: BodyGroup, eta, precond_dtype=None) -> BodyCaches:
     """Lab placement + singularity subtraction + K matrix + dense LU
     (`update_cache_variables`, `body_spherical.cpp:94-127`).
@@ -230,6 +231,7 @@ def update_cache(group: BodyGroup, eta, precond_dtype=None) -> BodyCaches:
 
 # ------------------------------------------------------------------ operators
 
+@jax.named_scope("body")
 def matvec(group: BodyGroup, caches: BodyCaches, x_bodies, v_bodies):
     """A_body x per body (`SphericalBody::matvec`, `body_spherical.cpp:39-63`).
 
@@ -251,6 +253,7 @@ def matvec(group: BodyGroup, caches: BodyCaches, x_bodies, v_bodies):
     return jnp.concatenate([res_nodes, res_com], axis=1)
 
 
+@jax.named_scope("body")
 def apply_preconditioner(group: BodyGroup, caches: BodyCaches, x_bodies):
     """Dense LU solves (`apply_preconditioner`, `body_spherical.cpp:37`);
     solves in the LU factors' (possibly lower) precision and casts back."""
@@ -266,6 +269,7 @@ def update_RHS(group: BodyGroup, v_on_bodies):
                             jnp.zeros((nb, 6), dtype=v_on_bodies.dtype)], axis=1)
 
 
+@jax.named_scope("body")
 def flow(group: BodyGroup, caches: BodyCaches, r_trg, x_bodies, forces_torques,
          eta, impl: str = "exact", ewald_plan=None, ewald_anchors=None,
          pair=None, pair_anchors=None):

@@ -141,11 +141,13 @@ def main(argv=None) -> None:
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="device profiler capture of the whole loop "
                          "(obs.profile.profile_session — python tracer "
-                         "off so device ops survive the buffer); the dump "
-                         "is parsed afterwards and device_phase events "
-                         "are appended to --trace-file. Render with "
-                         "`python -m skellysim_tpu.obs profile DIR` / "
-                         "`obs timeline` (docs/observability.md)")
+                         "off so device ops survive the buffer; programs "
+                         "compile with their scope paths in the cache "
+                         "key); the dump is parsed afterwards and "
+                         "device_phase events are appended to "
+                         "--trace-file. Render with `python -m "
+                         "skellysim_tpu.obs profile DIR` / `obs timeline` "
+                         "(docs/observability.md)")
     ap.add_argument("--jax-cache", default=None, metavar="DIR",
                     help="persistent XLA compilation cache directory shared "
                          "across runs/CLIs (default: [runtime] jax_cache, "
@@ -181,6 +183,14 @@ def main(argv=None) -> None:
 
     enable_compilation_cache(resolve_cache_dir(
         args.config_file, flag=args.jax_cache, off=args.no_jax_cache))
+
+    if args.profile:
+        # ahead of the build and the first compile: the capture is going to
+        # be folded by scope, and the compile cache would otherwise serve
+        # whatever scope paths the first compile of each program had
+        from .obs.profile import include_scopes_in_cache_key
+
+        include_scopes_in_cache_key()
 
     # multi-host bring-up (no-op single-process; the analogue of the
     # reference's MPI_Init, `skelly_sim.cpp:14`) — must run before any JAX

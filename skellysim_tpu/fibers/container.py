@@ -183,6 +183,7 @@ def node_active_flat(group: FiberGroup) -> jnp.ndarray:
     return act
 
 
+@jax.named_scope("fiber")
 def update_cache(group: FiberGroup, dt, eta) -> FiberCaches:
     """Derivatives, self-mobility, pre-BC operator, force operator (vmapped).
 
@@ -209,6 +210,7 @@ def update_cache(group: FiberGroup, dt, eta) -> FiberCaches:
                        lu=zeros44, piv=jnp.zeros((group.n_fibers, 4 * group.n_nodes), dtype=jnp.int32))
 
 
+@jax.named_scope("fiber")
 def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
                       v_on_fibers, f_total, f_ext,
                       precond_dtype=None) -> FiberCaches:
@@ -501,6 +503,7 @@ def flow_multi_local(buckets, caches_list, forces_list, r_loc, r_rep, eta, *,
     return v_loc, v_rep
 
 
+@jax.named_scope("fiber")
 def apply_fiber_force(group: FiberGroup, caches: FiberCaches, x_all) -> jnp.ndarray:
     """Solution -> force density on nodes, [nf, n, 3] (`apply_fiber_force`, `:272-287`)."""
     f = jnp.einsum("fij,fj->fi", caches.force_op, x_all)  # [nf, 3n]
@@ -508,6 +511,7 @@ def apply_fiber_force(group: FiberGroup, caches: FiberCaches, x_all) -> jnp.ndar
     return jnp.stack([f[:, :n], f[:, n:2 * n], f[:, 2 * n:]], axis=-1)
 
 
+@jax.named_scope("fiber")
 def matvec(group: FiberGroup, caches: FiberCaches, x_all, v_fib, v_boundary) -> jnp.ndarray:
     """Block-diagonal fiber matvec [nf, 4n] (`matvec`, `:216-234`)."""
     mats = group.mats
@@ -518,6 +522,7 @@ def matvec(group: FiberGroup, caches: FiberCaches, x_all, v_fib, v_boundary) -> 
     return jnp.where(group.active[:, None], res, x_all)
 
 
+@jax.named_scope("fiber")
 def apply_preconditioner(group: FiberGroup, caches: FiberCaches, x_all) -> jnp.ndarray:
     """Batched LU solves, [nf, 4n] (`apply_preconditioner`, `:331-339`).
 

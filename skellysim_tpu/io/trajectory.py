@@ -26,6 +26,7 @@ import numpy as np
 
 from .. import TRAJECTORY_VERSION, __version__
 from ..native import load_library
+from ..obs import tracer as obs_tracer
 from . import eigen
 
 FIBER_TYPE_NONE = 0
@@ -349,10 +350,18 @@ class TrajectoryWriter:
             self._fh.flush()
 
     def write_frame(self, state, solution=None, *, rng_state=None):
-        """Append one frame. ``solution`` is accepted (and ignored) so this can
-        be passed directly as ``System.run(..., writer=tw.write_frame)``."""
-        self._fh.write(frame_bytes(state, rng_state))
-        self._fh.flush()
+        """Append one frame; returns the bytes written. ``solution`` is
+        accepted (and ignored) so this can be passed directly as
+        ``System.run(..., writer=tw.write_frame)``. The two halves are spans
+        of their own (children of the run loop's ``write_frame``):
+        ``encode`` fetches the state from the device and packs it, ``io``
+        writes and flushes."""
+        with obs_tracer.span("encode"):
+            data = frame_bytes(state, rng_state)
+        with obs_tracer.span("io", bytes=len(data)):
+            self._fh.write(data)
+            self._fh.flush()
+        return len(data)
 
     def close(self):
         self._fh.close()

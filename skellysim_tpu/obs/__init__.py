@@ -14,7 +14,7 @@ Four legs over one JSONL event format:
   metrics JSONL mix) and `cost [--check|--update]` (the CI drift gate).
 
 Import-light on purpose: the obs modules themselves import jax only
-lazily (span sync, compile observation, the cost gate), and `summarize`
+lazily (span annotations, compile observation, the cost gate), and `summarize`
 never initializes a jax backend. NOTE the *package* import still runs
 `skellysim_tpu/__init__.py`, which imports jax at module level — that is
 why bench.py's jax-avoiding parent process pins its own

@@ -15,7 +15,7 @@ occupancy, and solver convergence stats. Pure host-side text processing —
 it never initializes a jax backend (the package import pulls the jax
 *module* in, nothing more).
 
-``profile DIR [--by phase|collective|op] [--json]`` attributes the device
+``profile DIR [--by phase|cross|collective|op] [--json]`` attributes the device
 op time of a ``--profile`` dump to the named_scope phase vocabulary
 (`obs.profile`, docs/observability.md "Device-time attribution").
 
@@ -287,7 +287,7 @@ def main(argv=None) -> int:
     p_prof.add_argument("dir", metavar="DIR",
                         help="jax.profiler.trace dump directory")
     p_prof.add_argument("--by", default="phase",
-                        choices=("phase", "collective", "op"),
+                        choices=("phase", "cross", "collective", "op"),
                         help="grouping for the attribution table")
     p_prof.add_argument("--json", action="store_true",
                         help="machine-readable report (all groupings)")

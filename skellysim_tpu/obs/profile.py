@@ -1,36 +1,47 @@
 """Device-time attribution from `jax.profiler` dumps (skelly-pulse).
 
-`--profile DIR` wraps a run in `jax.profiler.trace(DIR)`, which drops a
-TensorBoard profile bundle nobody in the tree could read until now: a
-Chrome trace-event JSON (`*.trace.json.gz` — per-op device execution
-events) and an XSpace protobuf (`*.xplane.pb`) that EMBEDS the optimized
-HLO of every profiled module. This module joins the two into per-phase
-device-time totals:
+`--profile DIR` wraps a run in a profiler session, which drops an XSpace
+protobuf (`*.xplane.pb`) under ``DIR/plugins/profile/<run>/``. That one
+file holds everything this module joins, on a TPU and on the CPU alike:
 
-* the trace events carry each executed op's wall time but only its HLO
-  instruction name (``dot.3``, ``fusion.17``);
-* the HLO proto's per-instruction ``metadata.op_name`` carries the
+* the executed ops, each with its start and duration. On a TPU they are
+  the ``XLA Ops`` line of every ``/device:*`` plane: an event's name is the
+  instruction's whole HLO text (``%fusion.17 = ...``), its module is the
+  ``XLA Modules`` event that contains it in time, and an op inside a
+  ``while`` is an event of its own inside the ``while``'s event. On the CPU
+  they are the host-plane events that carry ``hlo_op`` / ``hlo_module``
+  stats;
+* the optimized HLO of every profiled module on the ``/host:metadata``
+  plane, whose per-instruction ``metadata.op_name`` carries the
   `jax.named_scope` path the tracing code declared
-  (``jit(step)/.../prep/dot_general``) — the hot pipeline threads the
-  phase vocabulary below through every layer (`system/system.py`,
-  `solver/gmres.py`, `parallel/spmd.py`, `parallel/ring.py`,
-  `ops/treecode.py`).
+  (``jit(step)/.../gmres/arnoldi/precond/fiber/...``) — the hot pipeline
+  threads the vocabulary below through every layer (`system/system.py`,
+  `solver/gmres.py`, the operators, `ops/kernels.py`, `parallel/`);
+* the run loop's own host spans (`obs.tracer.span` enters a
+  ``TraceAnnotation("skelly/<path>")``), on the same clock as the ops.
 
-Folding device op time onto the scope path gives the table ROADMAP item 2
-needs: where a d8 coupled solve actually spends its device time, with
-collectives split by kind (the same ``all_reduce``/``all_gather``/
-``collective_permute`` names the audit contracts pin).
+One fold (`load_device_trace`) turns that into self time per scope path,
+phase x operator, collectives by kind, and every device idle gap cut at
+the edges of the ``skelly/`` spans and put down to the one the host was in. Self time is an event's duration
+less its same-line children's; a container (``while``, ``conditional``,
+``call``) keeps none of its own, its trips being events already.
 
-No protobuf dependency: the XSpace/HLO containers are walked with a
-~50-line protobuf wire-format reader over the handful of field numbers
-involved (`XSpace.planes` -> the ``/host:metadata`` plane ->
-``Hlo Proto`` stats -> `HloModuleProto.computations[].instructions[]`).
-Unknown fields are skipped by wire type, so schema growth degrades to
-missing metadata (reported as unattributed time), never a crash.
+**The compile cache serves the first compiler's metadata.** JAX strips
+locations, and with them every `named_scope`, from the compile-cache key,
+so an executable cached before a scope was added or renamed comes back
+with the OLD paths in its HLO. A capture that is going to be folded
+therefore compiles with ``jax_compilation_cache_include_metadata_in_key``
+set (`include_scopes_in_cache_key`: `profile_session`, `cli.py --profile`
+ahead of the build, the benchmark's phase helper), and the fold says
+``stale metadata`` where a solve's paths hold no operator scope at all.
 
-jax-free on purpose (json/gzip/struct only): `obs profile` and
-`obs timeline` parse dumps without paying JAX backend init, like
-`obs summarize`.
+No protobuf dependency: the containers are walked with a ~50-line
+wire-format reader over the handful of field numbers involved. Unknown
+fields are skipped by wire type, so schema growth degrades to missing
+metadata (reported as unattributed time), never a crash.
+
+jax-free on purpose (gzip/json/re only): `obs profile` and `obs timeline`
+parse dumps without paying JAX backend init, like `obs summarize`.
 """
 
 from __future__ import annotations
@@ -39,6 +50,7 @@ import contextlib
 import gzip
 import json
 import os
+import re
 from typing import Optional
 
 #: the named_scope phase vocabulary threaded through the hot pipeline.
@@ -61,7 +73,20 @@ PHASE_SCOPES = frozenset({
     # in-trace auxiliaries: the device DI update (scenarios/di_device.py)
     # and the jitted collision gate (system/system.py)
     "dynamic-instability", "collision",
+    # operators, nested under whatever phase calls them: every pair-sum
+    # evaluation (ops/kernels.py), the shell, the fiber blocks, the bodies
+    "pair", "shell", "fiber", "body",
 })
+
+#: the operator scopes: every solve applies at least one of them, so a
+#: solve whose paths hold none was compiled before they existed
+OPERATOR_SCOPES = ("pair", "shell", "fiber", "body")
+
+#: ops that only contain other ops: their trips are events of their own
+CONTAINERS = frozenset({"while", "conditional", "call"})
+
+#: every `obs.tracer.span` annotation starts with this
+SPAN_PREFIX = "skelly/"
 
 #: HLO collective opcode -> the audit contract's collective kind names
 #: (audit/checks.py collective-contract inventory)
@@ -157,123 +182,238 @@ def _module_op_names(hlo_module: bytes) -> dict:
     return out
 
 
-def load_op_name_map(xplane_path: str) -> dict:
-    """{(module_name, instruction_name): scope path} from an xplane dump.
+def _map_entries(entries):
+    """A protobuf ``map<int64, Message>`` field -> [(key, message fields)]."""
+    out = []
+    for entry_b in entries:
+        entry = _fields(entry_b)
+        if not entry or 2 not in entry:
+            continue
+        msg = _fields(entry[2][0])
+        if msg:
+            out.append((entry.get(1, msg.get(1, [0]))[0], msg))
+    return out
+
+
+def _read_planes(xplane_path: str) -> list:
+    """XSpace.planes (field 1) of a ``.xplane.pb`` (or ``.xplane.pb.gz``),
+    one field dict each; [] on any structural surprise."""
+    opener = gzip.open if xplane_path.endswith(".gz") else open
+    with opener(xplane_path, "rb") as fh:
+        space = _fields(fh.read())
+    if not space:
+        return []
+    return [p for p in map(_fields, space.get(1, [])) if p]
+
+
+def _hlo_scopes(planes: list) -> dict:
+    """{``module(program_id)``: {instruction name: scope path}}.
 
     The profiler stores each profiled module's optimized `HloProto` as a
     bytes stat (stat-metadata name ``Hlo Proto``) on the ``/host:metadata``
-    plane's event metadata; the event-metadata name is
-    ``module_name(program_id)``. Degrades to {} on any structural surprise
-    — callers then report the time as unattributed, never crash."""
-    with open(xplane_path, "rb") as fh:
-        space = _fields(fh.read())
+    plane's event metadata (XPlane.event_metadata = field 4,
+    .stat_metadata = 5; XEventMetadata.name = 2, .stats = 5; XStat
+    .metadata_id = 1, .bytes_value = 6); the event-metadata name is
+    ``module_name(program_id)``, as an ``XLA Modules`` event is named."""
     out: dict = {}
-    if not space:
-        return out
-    for plane_b in space.get(1, []):
-        plane = _fields(plane_b)
-        if not plane:
-            continue
-        # find the "Hlo Proto" stat-metadata id for THIS plane
-        hlo_stat_ids = set()
-        for sm_entry in plane.get(5, []):
-            entry = _fields(sm_entry)
-            if not entry or 2 not in entry:
-                continue
-            meta = _fields(entry[2][0])
-            if meta and _utf8(meta.get(2, [b""])[0]) == "Hlo Proto":
-                hlo_stat_ids.add(meta.get(1, entry.get(1, [0]))[0])
+    for plane in planes:
+        hlo_stat_ids = {key for key, meta in _map_entries(plane.get(5, []))
+                        if _utf8(meta.get(2, [b""])[0]) == "Hlo Proto"}
         if not hlo_stat_ids:
             continue
-        for em_entry in plane.get(4, []):
-            entry = _fields(em_entry)
-            if not entry or 2 not in entry:
-                continue
-            emeta = _fields(entry[2][0])
-            if not emeta:
-                continue
-            # "jit_f(5)" -> "jit_f" (trace events carry the bare name)
-            mod_name = _utf8(emeta.get(2, [b""])[0]).rsplit("(", 1)[0]
+        for _, emeta in _map_entries(plane.get(4, [])):
+            name = _utf8(emeta.get(2, [b""])[0])
             for stat_b in emeta.get(5, []):
                 stat = _fields(stat_b)
                 if (not stat or stat.get(1, [None])[0] not in hlo_stat_ids
                         or 6 not in stat):
                     continue
                 hlo = _fields(stat[6][0])
-                if not hlo or 1 not in hlo:
-                    continue
-                for instr, op_name in _module_op_names(hlo[1][0]).items():
-                    out[(mod_name, instr)] = op_name
+                if hlo and 1 in hlo:
+                    out.setdefault(name, {}).update(
+                        _module_op_names(hlo[1][0]))
     return out
 
 
-# ------------------------------------------------------- trace-event reading
+def _bare(module: str) -> str:
+    """``jit_f(5)`` -> ``jit_f``."""
+    return module.rsplit("(", 1)[0]
 
-def find_profile_files(profile_dir: str):
-    """(trace_json_paths, xplane_paths) for the LATEST run under a
-    `jax.profiler.trace` dump dir (``DIR/plugins/profile/<ts>/``); a dir
-    already containing the files (or a run dir itself) works too."""
-    candidates = [profile_dir]
+
+def load_op_name_map(xplane_path: str) -> dict:
+    """{(module_name, instruction_name): scope path} from an xplane dump,
+    module names without their program id. Degrades to {} on any structural
+    surprise — callers then report the time as unattributed, never crash."""
+    return {(_bare(module), instr): op_name
+            for module, names in _hlo_scopes(_read_planes(xplane_path)).items()
+            for instr, op_name in names.items()}
+
+
+# ------------------------------------------------------------ event reading
+
+def find_xplanes(profile_dir: str) -> list:
+    """The ``.xplane.pb`` files of the LATEST run under a profiler dump
+    dir (``DIR/plugins/profile/<run>/``); a run dir itself, or one dump
+    file (``.xplane.pb`` / ``.xplane.pb.gz``), works too."""
+    if os.path.isfile(profile_dir):
+        return [profile_dir]
+    cand = profile_dir
     runs_root = os.path.join(profile_dir, "plugins", "profile")
     if os.path.isdir(runs_root):
         runs = sorted(d for d in os.listdir(runs_root)
                       if os.path.isdir(os.path.join(runs_root, d)))
-        candidates = [os.path.join(runs_root, runs[-1])] if runs else []
-    for cand in candidates:
-        if not os.path.isdir(cand):
+        if not runs:
+            return []
+        cand = os.path.join(runs_root, runs[-1])
+    if not os.path.isdir(cand):
+        return []
+    return [os.path.join(cand, f) for f in sorted(os.listdir(cand))
+            if f.endswith((".xplane.pb", ".xplane.pb.gz"))]
+
+
+_OPCODE = re.compile(r"(?:^|[\s)\]}])([a-z][a-z0-9\-]*)\(")
+
+
+def _instruction(text: str):
+    """(instruction name, opcode) of an op event's name: a TPU event is
+    named by its whole HLO line, a CPU event by the instruction alone."""
+    lhs, sep, rhs = text.partition(" = ")
+    if sep and lhs.startswith("%"):
+        m = _OPCODE.search(rhs)
+        return lhs[1:], (m.group(1) if m else "")
+    return text, text.split(".")[0]
+
+
+def _stat_value(stat: dict, stat_names: dict):
+    """XStat value: uint64 = 3, int64 = 4, str = 5, ref (a stat-metadata
+    name) = 7; doubles are not read."""
+    if 3 in stat:
+        return stat[3][0]
+    if 4 in stat:
+        v = stat[4][0]
+        return v - (1 << 64) if v >= 1 << 63 else v
+    if 5 in stat:
+        return _utf8(stat[5][0])
+    if 7 in stat:
+        return stat_names.get(stat[7][0], "")
+    return None
+
+
+def _plane_events(plane: dict):
+    """(ops, spans) of one plane, times in picoseconds on the dump's clock
+    (XLine.timestamp_ns = field 3, .events = 4; XEvent.metadata_id = 1,
+    .offset_ps = 2, .duration_ps = 3, .stats = 4).
+
+    ops: dicts with name (the instruction), opcode, module, ts, dur, pid
+    (the plane), tid (the line). spans: (start, end, name, stats) of every
+    event named ``skelly/...``."""
+    plane_name = _utf8(plane.get(2, [b""])[0])
+    stat_names = {k: _utf8(m.get(2, [b""])[0])
+                  for k, m in _map_entries(plane.get(5, []))}
+    meta_names = {k: _utf8(m.get(2, [b""])[0])
+                  for k, m in _map_entries(plane.get(4, []))}
+    on_device = plane_name.startswith("/device:")
+    hlo_stats = {"hlo_op", "hlo_module"} & set(stat_names.values())
+
+    def stats_of(ev):
+        out = {}
+        for stat_b in ev.get(4, []):
+            stat = _fields(stat_b)
+            if stat and 1 in stat:
+                out[stat_names.get(stat[1][0], "")] = _stat_value(
+                    stat, stat_names)
+        return out
+
+    ops, spans, modules = [], [], []
+    instr_memo: dict = {}
+    for line_b in plane.get(3, []):
+        line = _fields(line_b)
+        if not line:
             continue
-        names = sorted(os.listdir(cand))
-        traces = [os.path.join(cand, f) for f in names
-                  if f.endswith(".trace.json.gz")
-                  or f.endswith(".trace.json")]
-        xplanes = [os.path.join(cand, f) for f in names
-                   if f.endswith(".xplane.pb")]
-        if traces:
-            return traces, xplanes
-    return [], []
-
-
-def _load_trace_events(path: str) -> list:
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as fh:
-        doc = json.load(fh)
-    return doc.get("traceEvents", doc) if isinstance(doc, dict) else doc
-
-
-def _self_times(events: list) -> list:
-    """Per-event SELF durations: each complete ("X") event's duration minus
-    its same-thread children's — so nested op events (fusions wrapping
-    sub-ops, while bodies re-reporting region ops) never double-count.
-    Returns [(event, self_dur_us)]."""
-    by_tid: dict = {}
-    for ev in events:
-        if ev.get("ph") != "X" or "dur" not in ev or "ts" not in ev:
+        line_name = _utf8(line.get(2, [b""])[0])
+        if on_device and line_name not in ("XLA Ops", "XLA Modules"):
             continue
-        by_tid.setdefault((ev.get("pid"), ev.get("tid")), []).append(ev)
-    out = []
-    for evs in by_tid.values():
+        base_ps = line.get(3, [0])[0] * 1000
+        for ev_b in line.get(4, []):
+            ev = _fields(ev_b)
+            if not ev or 1 not in ev:
+                continue
+            name = meta_names.get(ev[1][0], "")
+            ts = base_ps + ev.get(2, [0])[0]
+            dur = ev.get(3, [0])[0]
+            if on_device:
+                if line_name == "XLA Modules":
+                    modules.append((ts, ts + dur, name))
+                    continue
+                if name not in instr_memo:
+                    instr_memo[name] = _instruction(name)
+                instr, opcode = instr_memo[name]
+                ops.append({"name": instr, "opcode": opcode, "module": None,
+                            "ts": ts, "dur": dur, "pid": plane_name,
+                            "tid": line_name})
+            elif name.startswith(SPAN_PREFIX):
+                spans.append((ts, ts + dur, name, stats_of(ev)))
+            elif hlo_stats:
+                st = stats_of(ev)
+                if "hlo_op" not in st and "hlo_module" not in st:
+                    continue
+                instr = st.get("hlo_op") or name
+                module = st.get("hlo_module") or "?"
+                if st.get("program_id") is not None:
+                    module = f"{module}({st['program_id']})"
+                ops.append({"name": instr, "opcode": instr.split(".")[0],
+                            "module": module, "ts": ts, "dur": dur,
+                            "pid": plane_name, "tid": line_name})
+    # a TPU op's module is the `XLA Modules` event that contains it in time
+    modules.sort()
+    ops.sort(key=lambda e: e["ts"])
+    i = 0
+    for e in ops:
+        if e["module"] is not None:
+            continue
+        while i < len(modules) and modules[i][1] <= e["ts"]:
+            i += 1
+        inside = i < len(modules) and modules[i][0] <= e["ts"]
+        e["module"] = modules[i][2] if inside else "?"
+    return ops, spans
+
+
+def _self_times(events: list) -> None:
+    """Sets ``self`` on each event: its duration minus its same-line
+    children's, so nested events (a ``while`` around its trips) never
+    double-count."""
+    by_line: dict = {}
+    for e in events:
+        by_line.setdefault((e["pid"], e["tid"]), []).append(e)
+    for evs in by_line.values():
         evs.sort(key=lambda e: (e["ts"], -e["dur"]))
-        stack: list = []   # (end_ts, child_sum_slot) — slot is a 1-elem list
-        for ev in evs:
-            ts, dur = ev["ts"], ev["dur"]
-            while stack and ts >= stack[-1][0] - 1e-9:
+        stack: list = []   # open events, outermost first
+        for e in evs:
+            e["self"] = e["dur"]
+            while stack and e["ts"] >= stack[-1]["ts"] + stack[-1]["dur"]:
                 stack.pop()
             if stack:
-                stack[-1][1][0] += dur
-            slot = [0.0]
-            stack.append((ts + dur, slot))
-            out.append((ev, slot))
-    return [(ev, max(ev["dur"] - slot[0], 0.0)) for ev, slot in out]
+                stack[-1]["self"] -= e["dur"]
+            stack.append(e)
+    for e in events:
+        e["self"] = max(e["self"], 0)
 
 
 def phase_of(op_name: str) -> Optional[str]:
     """Slash-joined RECOGNIZED scope components of a metadata op_name, or
     None — ``jit(step)/.../gmres/precond/dot_general`` -> ``gmres/precond``.
-    Dedupes immediate repeats (a scope re-entered per ring hop)."""
+    Each component once, where it first appears: a scope re-entered per
+    ring hop, or a name stack that JAX repeats under ``vmap`` of a ``jit``,
+    adds nothing. JAX names a loop's body ``while/body``: that ``body`` is
+    no scope of ours. XLA joins the names of instructions it merged with
+    ``;``: the first counts."""
     comps = []
-    for c in op_name.split("/"):
-        if c in PHASE_SCOPES and (not comps or comps[-1] != c):
+    prev = ""
+    for c in op_name.split(";", 1)[0].split("/"):
+        if (c in PHASE_SCOPES and c not in comps
+                and not (c == "body" and prev == "while")):
             comps.append(c)
+        prev = c
     return "/".join(comps) if comps else None
 
 
@@ -292,19 +432,46 @@ def collective_kind(op_event_name: str) -> Optional[str]:
     return None
 
 
+def operator_of(phase: Optional[str]) -> str:
+    """The operator components of a phase path, slash-joined
+    (``gmres/arnoldi/precond/fiber`` -> ``fiber``, ``prep/shell/pair`` ->
+    ``shell/pair``), or ``-`` for a path outside every operator."""
+    ops = [c for c in (phase or "").split("/") if c in OPERATOR_SCOPES]
+    return "/".join(ops) or "-"
+
+
+def step_phase_of(phase: Optional[str]) -> str:
+    """The step phase a path belongs to — ``refine`` wherever it appears
+    (the f64 residual sweeps run inside ``gmres``), else the outermost
+    component — or ``(unattributed)``."""
+    comps = (phase or "").split("/")
+    if "refine" in comps:
+        return "refine"
+    return comps[0] or "(unattributed)"
+
+
 class DeviceTrace:
     """Aggregated per-op device time from one profile dump.
 
     ``rows`` is a list of dicts: op (instruction name), module, phase
     (recognized scope path or None), collective (kind or None), scope (the
     full metadata op_name when known), dur_us (summed SELF time), count.
-    ``events`` keeps the raw per-execution op events (ts/dur/self_us/
-    phase/...) for the timeline renderer.
+    ``events`` keeps the raw per-execution op events (ts/dur/self_us in
+    microseconds on the dump's clock, phase, ...) for the timeline
+    renderer; ``spans`` the run loop's ``skelly/`` annotations as
+    (start_us, end_us, path, stats); ``window_us`` what was folded.
+    ``stale`` is True where the executed modules' paths name a ``gmres``
+    phase and no operator scope: metadata from before the operator scopes,
+    served by the compile cache.
     """
 
-    def __init__(self, rows: list, events: list):
+    def __init__(self, rows: list, events: list, spans: list = (),
+                 window_us=None, stale: bool = False):
         self.rows = rows
         self.events = events
+        self.spans = list(spans)
+        self.window_us = window_us
+        self.stale = stale
 
     # ------------------------------------------------------------- totals
 
@@ -325,6 +492,19 @@ class DeviceTrace:
     def attributed_frac(self) -> float:
         tot = self.total_us
         return (self.attributed_us / tot) if tot > 0 else 0.0
+
+    def seconds(self, has=(), lacks=()) -> Optional[float]:
+        """Self time, in seconds, of the ops whose phase path holds every
+        component of ``has`` and none of ``lacks``; None where no op's
+        path holds ``has`` at all (not seen is not zero)."""
+        total, seen = 0.0, False
+        for r in self.rows:
+            comps = (r["phase"] or "").split("/")
+            if all(c in comps for c in has):
+                seen = True
+                if not any(c in comps for c in lacks):
+                    total += r["dur_us"]
+        return total * 1e-6 if seen else None
 
     def _group(self, key_fn) -> list:
         groups: dict = {}
@@ -361,62 +541,175 @@ class DeviceTrace:
     def by_op(self) -> list:
         return self._group(lambda r: f"{r['module']}/{r['op']}")
 
+    def cross_table(self) -> dict:
+        """{step phase: {operator: seconds}} — `step_phase_of` x
+        `operator_of` over every row's self time."""
+        table: dict = {}
+        for r in self.rows:
+            row = table.setdefault(step_phase_of(r["phase"]), {})
+            op = operator_of(r["phase"])
+            row[op] = row.get(op, 0.0) + r["dur_us"] * 1e-6
+        return table
 
-def load_device_trace(profile_dir: str) -> DeviceTrace:
-    """Parse a `jax.profiler.trace` dump dir into a `DeviceTrace`.
+    # ---------------------------------------------------------- idle gaps
 
-    Device op events are the trace events carrying an ``hlo_op``/
-    ``hlo_module`` arg (XLA executor events — host Python/runtime frames
-    never carry them); their scope paths come from the xplane-embedded
-    HLO metadata. Raises FileNotFoundError when the dir holds no trace."""
-    traces, xplanes = find_profile_files(profile_dir)
-    if not traces:
-        raise FileNotFoundError(
-            f"no *.trace.json(.gz) under {profile_dir!r} — is this a "
-            "`--profile DIR` dump (DIR/plugins/profile/<run>/)?")
-    op_names: dict = {}
-    for xp in xplanes:
-        try:
-            op_names.update(load_op_name_map(xp))
-        except Exception:
-            pass   # missing metadata -> unattributed time, reported as such
+    def busy_intervals(self, pid=None) -> list:
+        """The union of one device's op intervals (the first device's by
+        default), clipped to the window: sorted [start_us, end_us]."""
+        pid = pid if pid is not None else min(
+            (e["pid"] for e in self.events), default=None)
+        merged: list = []
+        for s, e in sorted((e["ts"], e["ts"] + e["dur"])
+                           for e in self.events if e["pid"] == pid):
+            if merged and s <= merged[-1][1]:
+                merged[-1][1] = max(merged[-1][1], e)
+            else:
+                merged.append([s, e])
+        return merged
 
-    kept_events = []
-    for tpath in traces:
-        events = _load_trace_events(tpath)
-        for ev, self_us in _self_times(events):
-            args = ev.get("args") or {}
-            op = args.get("hlo_op")
-            module = args.get("hlo_module")
-            if not op and not module:
+    @property
+    def busy_us(self) -> float:
+        return sum(e - s for s, e in self.busy_intervals())
+
+    def idle_gaps(self, min_us: float = 0.0) -> list:
+        """The first device's idle time inside the window, put down to the
+        run loop: each gap between busy intervals (those longer than
+        ``min_us``) is cut at the edges of the ``skelly/`` spans it
+        crosses, and each piece carries the innermost span around it
+        (without the prefix; ``-`` outside every span). Longest first:
+        (start_us, end_us, label)."""
+        if not self.events or self.window_us is None:
+            return []
+        lo, hi = self.window_us
+        gaps, cur = [], lo
+        for s, e in self.busy_intervals():
+            if s - cur > min_us:
+                gaps.append((cur, s))
+            cur = max(cur, e)
+        if hi - cur > min_us:
+            gaps.append((cur, hi))
+        out = []
+        for s, e in gaps:
+            around = [sp for sp in self.spans if sp[0] < e and sp[1] > s]
+            edges = sorted({s, e} | {t for a, b, _, _ in around
+                                     for t in (a, b) if s < t < e})
+            for u, v in zip(edges, edges[1:]):
+                inner = min(((b - a, path) for a, b, path, _ in around
+                             if a <= u and v <= b), default=None)
+                out.append((u, v, inner[1][len(SPAN_PREFIX):] if inner
+                            else "-"))
+        out.sort(key=lambda g: g[0] - g[1])
+        return out
+
+    def gap_table(self, min_us: float = 0.0) -> list:
+        """`idle_gaps` summed by label, pieces shorter than ``min_us`` left
+        out: [{label, count, ms}], largest first."""
+        table: dict = {}
+        for s, e, label in self.idle_gaps(min_us):
+            if e - s < min_us:
                 continue
-            op = op or ev.get("name", "?")
-            scope = (op_names.get((module, op))
-                     or op_names.get((module, ev.get("name", ""))) or "")
-            phase = phase_of(scope) if scope else None
-            coll = collective_kind(ev.get("name", "")) or collective_kind(op)
-            kept_events.append({
-                "name": ev.get("name", op), "op": op,
-                "module": module or "?", "ts": ev.get("ts", 0.0),
-                "dur": ev.get("dur", 0.0), "self_us": self_us,
-                "phase": phase, "inferred": False, "collective": coll,
-                "pid": ev.get("pid"), "tid": ev.get("tid")})
-    _infer_gap_phases(kept_events)
+            row = table.setdefault(label, {"label": label, "count": 0,
+                                           "ms": 0.0})
+            row["count"] += 1
+            row["ms"] += (e - s) * 1e-3
+        return sorted(table.values(), key=lambda r: -r["ms"])
+
+    def idle_us_inside(self, path: str) -> float:
+        """Device idle time inside the spans named ``path`` (``skelly/run``)
+        and their children, in microseconds."""
+        name = path[len(SPAN_PREFIX):]
+        return sum(e - s for s, e, label in self.idle_gaps()
+                   if label == name or label.startswith(name + "/"))
+
+
+def load_device_trace(profile_dir: str, window=None) -> DeviceTrace:
+    """Fold a profiler dump (a `--profile DIR`, a run dir, or one
+    ``.xplane.pb[.gz]``) into a `DeviceTrace`.
+
+    ``window`` = ``(t0_ns, t1_ns)`` on the dump's own clock (what
+    `jax.profiler.ProfileData` calls ``start_ns``) clips every op to it and
+    drops the spans outside; without one the fold spans the first to the
+    last op. Scope
+    paths come from the HLO metadata in the same file; an op whose
+    instruction misses there is unattributed, or inferred from its
+    neighbours (`_infer_gap_phases`). Raises FileNotFoundError when there
+    is no dump to read."""
+    xplanes = find_xplanes(profile_dir)
+    if not xplanes:
+        raise FileNotFoundError(
+            f"no *.xplane.pb under {profile_dir!r} — is this a "
+            "`--profile DIR` dump (DIR/plugins/profile/<run>/)?")
+    scopes: dict = {}
+    events, spans = [], []
+    for xp in xplanes:
+        planes = _read_planes(xp)
+        scopes.update(_hlo_scopes(planes))
+        for plane in planes:
+            ops, sp = _plane_events(plane)
+            events.extend(ops)
+            spans.extend(sp)
+    # module names without a program id serve events that carry none
+    for module, names in list(scopes.items()):
+        scopes.setdefault(_bare(module), names)
+
+    if window is not None:
+        lo, hi = (int(round(t * 1000)) for t in window)
+    elif events:
+        lo = min(e["ts"] for e in events)
+        hi = max(e["ts"] + e["dur"] for e in events)
+    else:
+        lo = hi = 0
+    clipped = []
+    for e in events:
+        s, t = max(e["ts"], lo), min(e["ts"] + e["dur"], hi)
+        if t > s or (e["dur"] == 0 and lo <= e["ts"] <= hi):
+            e["ts"], e["dur"] = s, t - s
+            clipped.append(e)
+    events = clipped
+    if window is not None:      # host spans stay whole: they only label
+        spans = [sp for sp in spans if sp[1] > lo and sp[0] < hi]
+    _self_times(events)
+
+    ran: dict = {}     # module -> its {instruction: scope path}
+    phases: dict = {"": None}      # scope path -> phase, each folded once
+    for e in events:
+        names = ran.get(e["module"])
+        if names is None:
+            names = ran[e["module"]] = (scopes.get(e["module"])
+                                        or scopes.get(_bare(e["module"]), {}))
+        scope = names.get(e["name"], "")
+        if scope not in phases:
+            phases[scope] = phase_of(scope)
+        e["scope"] = scope
+        e["phase"] = phases[scope]
+        e["inferred"] = False
+        e["collective"] = collective_kind(e["name"])
+        if e.pop("opcode") in CONTAINERS:
+            e["self"] = 0
+        # microseconds from here on (the timeline's and the tables' unit)
+        e["ts"], e["dur"] = e["ts"] * 1e-6, e["dur"] * 1e-6
+        e["self_us"] = e.pop("self") * 1e-6
+        e["op"] = e["name"]
+    _infer_gap_phases(events)
+    declared = {c for names in ran.values() for path in names.values()
+                for c in (phase_of(path) or "").split("/")}
+    stale = "gmres" in declared and not declared & set(OPERATOR_SCOPES)
 
     agg: dict = {}
-    for e in kept_events:
+    for e in events:
         key = (e["module"], e["op"], e["phase"])
         row = agg.setdefault(key, {
             "op": e["op"], "module": e["module"], "phase": e["phase"],
             "inferred": e["inferred"], "collective": e["collective"],
-            "scope": op_names.get((e["module"], e["op"]), ""),
-            "dur_us": 0.0, "count": 0})
+            "scope": e["scope"], "dur_us": 0.0, "count": 0})
         row["dur_us"] += e["self_us"]
         row["count"] += 1
     rows = sorted(agg.values(), key=lambda r: -r["dur_us"])
     for r in rows:
-        r["dur_us"] = round(r["dur_us"], 3)
-    return DeviceTrace(rows, kept_events)
+        r["dur_us"] = round(r["dur_us"], 6)
+    return DeviceTrace(rows, events,
+                       [(a * 1e-6, b * 1e-6, p, st) for a, b, p, st in spans],
+                       (lo * 1e-6, hi * 1e-6), stale)
 
 
 def _infer_gap_phases(events: list) -> None:
@@ -455,21 +748,40 @@ def _infer_gap_phases(events: list) -> None:
 
 # -------------------------------------------------------------- rendering
 
+def _columns(rows: list) -> list:
+    widths = [max(len(r[i]) for r in rows) for i in range(len(rows[0]))]
+    return ["  ".join(c.ljust(w) for c, w in zip(r, widths)).rstrip()
+            for r in rows]
+
+
+def _render_cross(trace: DeviceTrace) -> list:
+    table = trace.cross_table()
+    cols = sorted({op for row in table.values() for op in row},
+                  key=lambda op: -sum(r.get(op, 0.0) for r in table.values()))
+    rows = [("phase \\ operator (ms)", *cols, "total")]
+    for phase, row in sorted(table.items(),
+                             key=lambda kv: -sum(kv[1].values())):
+        rows.append((phase, *(f"{row.get(c, 0.0) * 1e3:.3f}" for c in cols),
+                     f"{sum(row.values()) * 1e3:.3f}"))
+    return _columns(rows)
+
+
 def render_table(trace: DeviceTrace, by: str = "phase") -> str:
     """The `obs profile` text report (docs/observability.md)."""
-    groups = {"phase": trace.by_phase, "collective": trace.by_collective,
-              "op": trace.by_op}[by]()
-    rows = [(by, "time_ms", "share", "ops", "collectives")]
-    for g in groups[:40]:
-        colls = "  ".join(f"{k}={v / 1e3:.3f}ms"
-                          for k, v in g["collectives"].items())
-        rows.append((str(g["key"]), f"{g['dur_us'] / 1e3:.3f}",
-                     f"{g['share']:.1%}", str(g["count"]), colls))
-    widths = [max(len(r[i]) for r in rows) for i in range(5)]
-    out = ["  ".join(c.ljust(w) for c, w in zip(r, widths)).rstrip()
-           for r in rows]
-    if len(groups) > 40:
-        out.append(f"... ({len(groups) - 40} more rows; --json for all)")
+    if by == "cross":
+        out = _render_cross(trace)
+    else:
+        groups = {"phase": trace.by_phase, "collective": trace.by_collective,
+                  "op": trace.by_op}[by]()
+        rows = [(by, "time_ms", "share", "ops", "collectives")]
+        for g in groups[:40]:
+            colls = "  ".join(f"{k}={v / 1e3:.3f}ms"
+                              for k, v in g["collectives"].items())
+            rows.append((str(g["key"]), f"{g['dur_us'] / 1e3:.3f}",
+                         f"{g['share']:.1%}", str(g["count"]), colls))
+        out = _columns(rows)
+        if len(groups) > 40:
+            out.append(f"... ({len(groups) - 40} more rows; --json for all)")
     out.append("")
     tot = trace.total_us
     inf_frac = (trace.inferred_us / tot) if tot > 0 else 0.0
@@ -478,18 +790,34 @@ def render_table(trace: DeviceTrace, by: str = "phase") -> str:
                f"{trace.attributed_frac:.1%} attributed to named phases "
                f"({trace.attributed_frac - inf_frac:.1%} via HLO metadata, "
                f"{inf_frac:.1%} inferred from phase-contiguous neighbors)")
+    if trace.stale:
+        out.append("stale metadata: the solve's scope paths name no operator "
+                   f"({', '.join(OPERATOR_SCOPES)}); the compile cache "
+                   "served executables from before those scopes "
+                   "(docs/observability.md \"The cache and the scopes\")")
+    gaps = trace.gap_table(min_us=100.0)
+    if trace.spans and gaps:
+        out.append("")
+        out.append("device idle time by run-loop span (pieces of 0.1 ms or "
+                   "more):")
+        out.extend(_columns([("span", "gaps", "ms")] + [
+            (g["label"], str(g["count"]), f"{g['ms']:.3f}") for g in gaps]))
     return "\n".join(out) + "\n"
 
 
 def profile_json(trace: DeviceTrace) -> dict:
     return {
         "total_us": round(trace.total_us, 3),
+        "busy_us": round(trace.busy_us, 3),
         "attributed_us": round(trace.attributed_us, 3),
         "inferred_us": round(trace.inferred_us, 3),
         "attributed_frac": round(trace.attributed_frac, 4),
+        "stale_metadata": trace.stale,
         "by_phase": trace.by_phase(),
         "by_collective": trace.by_collective(),
         "by_op": trace.by_op(),
+        "phase_by_operator": trace.cross_table(),
+        "idle_gaps": trace.gap_table(),
     }
 
 
@@ -514,22 +842,26 @@ def _write_profile_provenance(profile_dir: str) -> None:
         pass   # a sidecar must never fail the capture it describes
 
 
+def include_scopes_in_cache_key(on: bool = True) -> bool:
+    """Make `jax.named_scope` paths part of the compile-cache key; returns
+    what the setting was. JAX leaves locations out of the key, so a
+    persistent cache serves whatever metadata the FIRST compile of a
+    program had: a scope added or renamed since is invisible in the HLO of
+    every later run that hits that entry. Call before the first compile of
+    anything a capture is going to fold; an executable already in memory
+    keeps the metadata it was built or loaded with."""
+    import jax
+
+    was = bool(jax.config.jax_compilation_cache_include_metadata_in_key)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      bool(on))
+    return was
+
+
 @contextlib.contextmanager
-def profile_session(profile_dir: str):
-    """Profiler capture tuned for device-time attribution.
-
-    `jax.profiler.trace` captures Python host frames too
-    (``python_tracer_level=1``); around a loop that COMPILES inside the
-    window, those frames flood the ~1M-event trace buffer and evict the
-    device op events this parser needs (observed: a 2-step `System.run`
-    produced 1,000,027 events with ZERO surviving ``hlo_op`` args). This
-    context creates the profiler session with the Python tracer OFF and
-    ``enable_hlo_proto`` on — host-side timing is the span tracer's job
-    (docs/observability.md), the profiler's is the device. Falls back to
-    plain `jax.profiler.trace` when the options API is unavailable.
-
-    jax imports stay inside the context so module import remains jax-free.
-    """
+def _capture(profile_dir: str):
+    """The profiler session itself: Python tracer off, HLO protos on; plain
+    `jax.profiler.trace` where the options API is unavailable."""
     import jax
 
     try:
@@ -543,16 +875,38 @@ def profile_session(profile_dir: str):
         opts.enable_hlo_proto = True
         sess = xla_client.profiler.ProfilerSession(opts)
     except Exception:
-        try:
-            with jax.profiler.trace(str(profile_dir)):
-                yield
-        finally:
-            _write_profile_provenance(str(profile_dir))
+        with jax.profiler.trace(str(profile_dir)):
+            yield
         return
     try:
         yield
     finally:
         sess.export(sess.stop(), str(profile_dir))
+
+
+@contextlib.contextmanager
+def profile_session(profile_dir: str):
+    """Profiler capture tuned for device-time attribution.
+
+    `jax.profiler.trace` captures Python host frames too
+    (``python_tracer_level=1``); around a loop that COMPILES inside the
+    window, those frames flood the ~1M-event trace buffer and evict the
+    device op events this parser needs (observed: a 2-step `System.run`
+    produced 1,000,027 events with ZERO surviving ``hlo_op`` args). This
+    context creates the profiler session with the Python tracer OFF and
+    ``enable_hlo_proto`` on — host-side timing is the span tracer's job
+    (its ``skelly/`` annotations land in the same dump), the profiler's is
+    the device. Programs compiled inside the session carry the scopes the
+    running source declares (`include_scopes_in_cache_key`).
+
+    jax imports stay inside the context so module import remains jax-free.
+    """
+    was = include_scopes_in_cache_key()
+    try:
+        with _capture(profile_dir):
+            yield
+    finally:
+        include_scopes_in_cache_key(was)
         _write_profile_provenance(str(profile_dir))
 
 
@@ -569,7 +923,8 @@ def device_phase_events(profile_dir: str) -> list:
         out.append({"phase": g["key"], "dur_s": round(g["dur_us"] / 1e6, 6),
                     "share": round(g["share"], 4), "ops": g["count"],
                     "collectives": {k: round(v / 1e6, 6)
-                                    for k, v in g["collectives"].items()}})
+                                    for k, v in g["collectives"].items()},
+                    "stale_metadata": trace.stale})
     return out
 
 

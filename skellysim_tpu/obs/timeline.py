@@ -55,6 +55,14 @@ def _load_jsonl(path: str) -> list:
     return out
 
 
+def _span_start(rec) -> float:
+    """A span record's start on the stream's clock: its own ``start``, or
+    (streams from before that field) the exit stamp less the duration."""
+    if rec.get("start") is not None:
+        return float(rec["start"])
+    return rec["ts"] - float(rec.get("dur_s", 0.0))
+
+
 def timeline_events(trace_paths, profile_dir=None) -> list:
     """The merged ``traceEvents`` list (Chrome trace-event JSON array
     form). ``trace_paths`` is one path or a list of telemetry JSONL
@@ -79,8 +87,7 @@ def timeline_events(trace_paths, profile_dir=None) -> list:
         ts = rec.get("ts")
         if ts is None:
             continue
-        start = ts - float(rec.get("dur_s", 0.0)) \
-            if rec.get("ev") == "span" else ts
+        start = _span_start(rec) if rec.get("ev") == "span" else ts
         starts.append(start)
         if (rec.get("ev") == "span" and rec.get("name") == "step"
                 and first_step_start is None):
@@ -112,11 +119,12 @@ def timeline_events(trace_paths, profile_dir=None) -> list:
         if ev == "span":
             dur_s = float(rec.get("dur_s", 0.0))
             args = {k: v for k, v in rec.items()
-                    if k not in ("ev", "ts", "dur_s", "name", "_stream")
+                    if k not in ("ev", "ts", "start", "dur_s", "name",
+                                 "_stream")
                     and isinstance(v, (str, int, float, bool))}
             events.append({"ph": "X", "pid": HOST_PID, "tid": tid_of(rec),
-                           "ts": us(ts - dur_s), "dur": round(dur_s * 1e6,
-                                                              3),
+                           "ts": us(_span_start(rec)),
+                           "dur": round(dur_s * 1e6, 3),
                            "name": rec.get("name", "?"), "args": args})
             n_spans += 1
         elif ev == "compile":

@@ -10,28 +10,36 @@ churn, and bench group timings from one format.
 
 Design constraints:
 
-* **Import-light.** This module imports jax only lazily
-  (`jax.block_until_ready`, and only when a span actually registered a
-  device sync tree). Reaching it through the package still runs
-  `skellysim_tpu/__init__.py`'s module-level `import jax` — which is why
-  `bench.py`'s parent process (which must never import jax: a parent that
-  has touched jax holds the chip its children need) pins its own `TELEMETRY_VERSION`
-  literal instead of importing this module; only the bench *children*
-  (which import jax anyway) construct tracers.
-* **Zero-cost when inactive.** The module-level `span()` / `emit()` helpers
-  consult the active tracer once and no-op without one, so the run loop and
-  scheduler carry their instrumentation unconditionally.
-* **Device-work attribution.** XLA dispatch is async: a jit call returns
-  before the device finishes, so a naive span around it undercounts by
-  >100x (the `_run_loop` wall_s lesson). A span that should absorb its
-  device work registers the output pytree via ``sp.sync(tree)``; the span
-  blocks on it at exit, so the duration covers the device execution.
+* **Import-light.** This module imports jax only lazily (the first
+  `span` asks `jax.profiler` for `TraceAnnotation`). Reaching it through
+  the package still runs `skellysim_tpu/__init__.py`'s module-level
+  `import jax` — which is why `bench.py`'s parent process (which must
+  never import jax: a parent that has touched jax holds the chip its
+  children need) pins its own `TELEMETRY_VERSION` literal instead of
+  importing this module; only the bench *children* (which import jax
+  anyway) construct tracers.
+* **Near-free when inactive.** `emit()` consults the active tracer once and
+  no-ops without one. `span()` always enters a
+  `jax.profiler.TraceAnnotation("skelly/<path>")` — a whole span costs
+  about 3 us while no profiler capture runs (CPU reading; the inert span it
+  replaces cost 1.1 us) — so the run loop and scheduler carry their
+  instrumentation unconditionally, and a capture (`--profile DIR`) holds
+  the host spans in the same dump, on the same clock, as the device ops:
+  `obs.profile` labels every device idle gap with the span that covers it.
+* **A span never changes when the program waits.** XLA dispatch is async: a
+  jit call returns before the device finishes, so a span around it times
+  the enqueue (the run loop's ``dispatch``), and the span around the first
+  host fetch of a result times the device (``wait``). Spans only observe
+  that; none blocks at exit.
 
 Event lines are JSON objects with common keys ``ev`` (event kind), ``ts``
-(monotonic seconds, arbitrary origin — deltas only), ``pid``, ``host``.
-Kinds emitted here: ``telemetry`` (stream header, carries ``version``),
-``span`` (``name``, ``path`` = slash-joined open-span stack, ``dur_s``,
-plus caller fields), and whatever callers pass to `emit` (``compile`` from
+(`time.perf_counter()` seconds: one monotonic clock per process, arbitrary
+origin — compare within a stream only), ``pid``, ``host``. Kinds emitted
+here: ``telemetry`` (stream header, carries ``version``), ``span``
+(``name``, ``path`` = slash-joined open-span stack, ``start`` on the
+``ts`` clock, ``dur_s``, ``parent`` = the enclosing span's path or null,
+``step`` = the caller's or the nearest enclosing span's step id, plus
+caller fields), and whatever callers pass to `emit` (``compile`` from
 `obs.compile_log`, ``lane`` from the ensemble scheduler). The step records
 of the run-loop/ensemble metrics JSONL (`system.METRICS_FIELDS`) carry no
 ``ev`` key; `obs summarize` accepts both shapes in any mix.
@@ -83,25 +91,53 @@ def provenance(downscaled=None) -> dict:
     return info
 
 
+#: the open spans of this process, outermost first: (name, step). Module
+#: state like `_ACTIVE` below: annotations need the path with no tracer on
+_STACK: list = []
+
+_TRACE_ANNOTATION = None
+
+
 class _Span:
-    """Mutable handle yielded by `Tracer.span`: attach fields / a sync tree."""
+    """One timed scope (`span` / `Tracer.span`): a profiler annotation
+    always, a ``span`` event at exit where a tracer listens."""
 
-    __slots__ = ("fields", "_sync")
+    __slots__ = ("name", "fields", "_tracer", "_ann", "_t0")
 
-    def __init__(self):
-        self.fields = {}
-        self._sync = None
+    def __init__(self, name: str, fields: dict, tracer):
+        self.name = name
+        self.fields = fields
+        self._tracer = tracer
 
     def note(self, **fields):
         """Attach extra fields to the span event emitted at exit."""
         self.fields.update(fields)
 
-    def sync(self, tree):
-        """Register a pytree to `jax.block_until_ready` at span exit, so the
-        device work producing it is attributed to THIS span (returns the
-        tree unchanged, for inline use)."""
-        self._sync = tree
-        return tree
+    def __enter__(self):
+        global _TRACE_ANNOTATION
+        if _TRACE_ANNOTATION is None:
+            from jax.profiler import TraceAnnotation as _TRACE_ANNOTATION
+        step = self.fields.get("step", _STACK[-1][1] if _STACK else None)
+        _STACK.append((self.name, step))
+        path = "/".join(n for n, _ in _STACK)
+        kw = self.fields if step is None else {**self.fields, "step": step}
+        self._ann = _TRACE_ANNOTATION("skelly/" + path, **kw)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        path = "/".join(n for n, _ in _STACK)
+        _, step = _STACK.pop()
+        if self._tracer is not None:
+            self._tracer.emit(
+                "span", name=self.name, path=path,
+                start=round(self._t0, 6), dur_s=round(dur, 6),
+                parent=path.rpartition("/")[0] or None,
+                **{**self.fields, "step": step})
+        return False
 
 
 class Tracer:
@@ -117,7 +153,6 @@ class Tracer:
         self.path = path
         self.events = [] if path is None else None
         self._fh = open(path, "a") if path else None
-        self._stack: list[str] = []
         self._pid = os.getpid()
         try:
             self._host = socket.gethostname()
@@ -140,28 +175,10 @@ class Tracer:
         else:
             self.events.append(rec)
 
-    @contextlib.contextmanager
     def span(self, name: str, **fields):
         """Nestable timed scope; emits ONE ``span`` event at exit whose
-        ``path`` is the slash-joined stack of open spans (attribution) and
-        whose ``dur_s`` includes any registered device sync."""
-        sp = _Span()
-        self._stack.append(name)
-        t0 = time.perf_counter()
-        try:
-            yield sp
-        finally:
-            try:
-                if sp._sync is not None:
-                    import jax
-
-                    jax.block_until_ready(sp._sync)
-            finally:
-                dur = time.perf_counter() - t0
-                path = "/".join(self._stack)
-                self._stack.pop()
-                self.emit("span", name=name, path=path,
-                          dur_s=round(dur, 6), **{**fields, **sp.fields})
+        ``path`` is the slash-joined stack of open spans (attribution)."""
+        return _Span(name, fields, self)
 
     # ----------------------------------------------------------- lifecycle
 
@@ -202,24 +219,10 @@ def use(tracer: Optional[Tracer]):
         _ACTIVE = prev
 
 
-_NULL_SPAN = _Span()
-
-
-@contextlib.contextmanager
-def _null_span():
-    # a fresh-enough dummy: note()/sync() write into a shared throwaway
-    _NULL_SPAN.fields.clear()
-    _NULL_SPAN._sync = None
-    yield _NULL_SPAN
-
-
 def span(name: str, **fields):
-    """`Tracer.span` on the active tracer, or an inert span when telemetry
-    is off — instrumentation sites never branch."""
-    tr = _ACTIVE
-    if tr is None:
-        return _null_span()
-    return tr.span(name, **fields)
+    """A `_Span` reporting to the active tracer, if any — instrumentation
+    sites never branch."""
+    return _Span(name, fields, _ACTIVE)
 
 
 def emit(ev: str, **fields):

@@ -49,6 +49,12 @@ __all__ = [
 ]
 
 
+#: every pair-sum evaluation below is scoped ``pair`` for device-time
+#: attribution (obs/profile.py OPERATOR_SCOPES): the tile, whichever it is,
+#: and its glue. `jax.named_scope` under the `jax.jit` decorator, so the
+#: scope is part of the traced body; metadata only, the program is unchanged
+
+
 def _block_iter(n: int, block: int) -> int:
     """Number of blocks covering n (n padded up to a multiple of block)."""
     return -(-n // block)
@@ -254,6 +260,7 @@ def pallas_impl_for(impl: str, *arrays) -> str:
 
 
 @partial(jax.jit, static_argnames=("block_size", "source_block", "impl"))
+@jax.named_scope("pair")
 def stokeslet_direct(r_src, r_trg, f_src, eta, *, block_size: int = 4096,
                      source_block: int | None = None, impl: str = "exact"):
     """Singular Stokeslet sum: [n_src,3] sources, [n_trg,3] targets -> [n_trg,3].
@@ -312,6 +319,7 @@ def stokeslet_direct(r_src, r_trg, f_src, eta, *, block_size: int = 4096,
 
 
 @partial(jax.jit, static_argnames=("block_size", "source_block", "impl"))
+@jax.named_scope("pair")
 def stresslet_direct(r_dl, r_trg, f_dl, eta, *, block_size: int = 4096,
                      source_block: int | None = None, impl: str = "exact"):
     """Singular stresslet (double-layer) sum.
@@ -388,6 +396,7 @@ def _regularized_frgr(r2, eta, reg, epsilon_distance):
 
 
 @partial(jax.jit, static_argnames=("block_size", "source_block"))
+@jax.named_scope("pair")
 def oseen_contract(r_src, r_trg, density, eta, reg=DEFAULT_REG,
                    epsilon_distance=DEFAULT_EPS, *, block_size: int = 4096,
                    source_block: int | None = None):
@@ -419,6 +428,7 @@ def oseen_tensor(r_src, r_trg, eta, reg=DEFAULT_REG, epsilon_distance=DEFAULT_EP
 
 
 @partial(jax.jit, static_argnames=("block_size", "source_block"))
+@jax.named_scope("pair")
 def rotlet(r_src, r_trg, density, eta, reg=DEFAULT_REG, epsilon_distance=DEFAULT_EPS,
            *, block_size: int = 4096, source_block: int | None = None):
     """Rotlet sum ``u = 1/(8 pi eta) sum_j (rho_j x d)/r^3`` -> [n_trg, 3].

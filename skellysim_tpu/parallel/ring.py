@@ -149,6 +149,7 @@ def _ring_block(impl: str, exact_block, mxu_block, pallas_block_name=None):
         "'pallas' (double-float rides ring_stokeslet_df / ring_stresslet_df)")
 
 
+@jax.named_scope("pair")   # obs/profile.py: the ring is a pair sum
 def _ring_eval(block_fn, mesh: Mesh, axis_name: str, specs, scale, *operands,
                unroll: bool = False, kind: str | None = None,
                impl: str = "exact"):
@@ -183,6 +184,7 @@ _LOCAL_FLOW_BLOCKS = {
 }
 
 
+@jax.named_scope("pair")   # obs/profile.py: the ring is a pair sum
 def ring_flow_local(kind: str, impl: str, r_trg, src, payload, eta, *,
                     axis_name: str, n_dev: int, ring: bool = True):
     """Pairwise flow for callers ALREADY INSIDE a `shard_map` over
@@ -296,6 +298,7 @@ def _df_ring_block(impl: str, xla_block, pallas_block_name: str):
     raise ValueError(f"DF ring tiles serve 'df' or 'pallas_df', got {impl!r}")
 
 
+@jax.named_scope("pair")   # obs/profile.py: the ring is a pair sum
 def _ring_df(block_fn, mesh: Mesh, axis_name: str, r_src, r_trg, payload, eta,
              unroll: bool = False):
     """Shared driver for the double-float ring tiles.

@@ -556,9 +556,12 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                     with jax.named_scope("allgather-density"):
                         x_full = lax.all_gather(x_shell, axis, tiled=True)
                     shell = st.shell
-                    y_shell = (shell.M_inv
-                               @ x_full.astype(shell.M_inv.dtype)
-                               ).astype(x.dtype)
+                    # `peri.apply_preconditioner` on the gathered density:
+                    # the one shell product it does not reach, scoped alike
+                    with jax.named_scope("shell"):
+                        y_shell = (shell.M_inv
+                                   @ x_full.astype(shell.M_inv.dtype)
+                                   ).astype(x.dtype)
                 else:
                     y_shell = peri.apply_preconditioner(st.shell, x_shell)
 

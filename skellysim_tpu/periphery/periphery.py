@@ -243,6 +243,7 @@ def grow_capacity(shell: PeripheryState, new_n: int) -> PeripheryState:
 
 # ------------------------------------------------------------------ operators
 
+@jax.named_scope("shell")
 def matvec(shell: PeripheryState, x, v_on_shell):
     """A_shell x = (S + N) x + v (`periphery.cpp:38-47`); v is [N, 3].
 
@@ -260,6 +261,7 @@ def matvec(shell: PeripheryState, x, v_on_shell):
 _F64_ROW_BLOCK = 2048
 
 
+@jax.named_scope("shell")
 def _apply_operator(op, x):
     """``op @ x``; a large float64 operator goes in row blocks.
 
@@ -285,6 +287,7 @@ def _apply_operator(op, x):
     return jax.lax.fori_loop(0, -(-rows // block), body, y0)
 
 
+@jax.named_scope("shell")
 def apply_preconditioner(shell: PeripheryState, x):
     """P^-1 x = M_inv x (`periphery.cpp:21-29`); applied in M_inv's (possibly
     lower) precision and cast back."""
@@ -300,6 +303,7 @@ def update_RHS(v_on_shell, node_mask=None):
     return -v_on_shell.reshape(-1)
 
 
+@jax.named_scope("shell")
 def flow(shell: PeripheryState, r_trg, density, eta, *, evaluator: str = "direct",
          mesh=None, impl: str = "exact", ewald_plan=None, ewald_anchors=None,
          pair=None, pair_anchors=None):
@@ -379,6 +383,7 @@ def flow(shell: PeripheryState, r_trg, density, eta, *, evaluator: str = "direct
     return kernels.stresslet_direct(shell.nodes, r_trg, f_dl, eta, impl=impl)
 
 
+@jax.named_scope("shell")
 def flow_local(shell: PeripheryState, r_loc, r_rep, density, eta, *,
                axis_name, n_dev: int, impl: str = "exact"):
     """`flow` for callers ALREADY INSIDE a `shard_map` over the fiber axis

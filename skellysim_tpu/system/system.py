@@ -1461,6 +1461,58 @@ class System:
                 metrics_fh.close()
         return state
 
+    def _log_step(self, t_cur, dt, iters, residual, residual_true,
+                  fiber_error, accept, wall_s, converged, loss_of_accuracy,
+                  health, guard_retries, flight_row):
+        """One trial step's log lines (the reference's per-step spdlog
+        lines, `system.cpp:474,567`), from values already on the host."""
+        p = self.params
+        logger.info(
+            "step t=%.6g dt=%.4g iters=%d residual=%.3e (true %.3e) "
+            "fiber_error=%.3e %s (%.3fs)", t_cur, dt, iters, residual,
+            residual_true, fiber_error,
+            "accepted" if accept else "rejected", wall_s)
+        if not converged and accept:
+            # without adaptive timestepping a non-converged solve is
+            # still accepted (the reference's loop likewise only rejects
+            # under the adaptive gate) — but never silently: the
+            # round-5 x64 CLI bug surfaced as exactly this, a 1e-10
+            # request quietly flooring at f32 noise
+            logger.warning(
+                "GMRES did not converge: residual %.3e (true %.3e) vs "
+                "tol %.1e; step accepted (adaptive timestep off)",
+                residual, residual_true, p.gmres_tol)
+        if loss_of_accuracy:
+            # `solver_hydro.cpp:85-92`: implicit convergence with a
+            # drifted explicit residual means the answer is worse than
+            # the solver claims
+            logger.warning(
+                "GMRES loss of accuracy: implicit residual %.3e converged "
+                "but explicit ||b-Ax||/||b|| = %.3e (> 10x tol %.1e)",
+                residual, residual_true, p.gmres_tol)
+        if health:
+            # the device-side verdict, surfaced host-side exactly once
+            # per trial: a structured `fault` telemetry event (the obs
+            # summarize fault table) plus the log line the reference
+            # would have aborted with
+            verdict_s = _verdict.describe(health)
+            # flight provenance rides the fault event when the recorder
+            # localized the offender (obs.flight — "who and where"
+            # next to guard's "something died")
+            prov = (flight_row or {}).get("provenance") or {}
+            prov_fields = ({"prov_field": prov.get("field"),
+                            "prov_fiber": prov.get("fiber"),
+                            "prov_node": prov.get("node")}
+                           if prov else {})
+            obs_tracer.emit("fault", kind="solver_health",
+                            verdict=verdict_s, health=health,
+                            t=t_cur, dt=dt, retries=guard_retries,
+                            **prov_fields)
+            logger.warning(
+                "solver health verdict at t=%.6g: %s (health=%#x, "
+                "guard retries=%d)", t_cur, verdict_s, health,
+                guard_retries)
+
     def _run_loop(self, state: SimState, *, writer, max_steps, rng, metrics_fh):
         from .dynamic_instability import (_count_active as _di_count_active,
                                           apply_dynamic_instability)
@@ -1479,182 +1531,172 @@ class System:
         donate_ok = (not p.adaptive_timestep_flag
                      and jax.default_backend() != "cpu")
         step_fn = self._step_donating if donate_ok else self.step
-        while not reached_t_final(float(state.time), p.t_final):
+        span = obs_tracer.span
+
+        def clock_read(st):
+            # the loop's clock, as the host holds it: two scalar fetches
+            with span("clock_read"):
+                return float(st.time), float(st.dt)
+
+        # every span below is a child of ``step`` and carries its step id
+        # (docs/observability.md "Run-loop spans"): `obs.profile` puts each
+        # device idle gap down to the one the host was in
+        t_cur, dt = clock_read(state)
+        while not reached_t_final(t_cur, p.t_final):
             if max_steps is not None and n_steps >= max_steps:
                 break
-            backup = state
-            di_stats = None
-            if rng is not None and p.dynamic_instability.n_nodes > 0:
-                # a ring mesh constrains nucleation's capacity growth to
-                # mesh-divisible node counts (grow_capacity invariant)
-                nm = self.mesh.size if self._ring_active() else 1
-                di_stats = {}
-                with obs_tracer.span("dynamic_instability"):
-                    state = apply_dynamic_instability(state, p, rng,
-                                                      node_multiple=nm,
-                                                      stats=di_stats)
-            # snapshot the time scalars BEFORE the step: with donation on,
-            # the step consumes the input state's buffers
-            t_cur = float(state.time)
-            dt = float(state.dt)
-            with obs_tracer.span("step", step=n_steps) as sp:
+            with span("step", step=n_steps) as sp:
+                backup = state
+                di_stats = None
+                if rng is not None and p.dynamic_instability.n_nodes > 0:
+                    # a ring mesh constrains nucleation's capacity growth to
+                    # mesh-divisible node counts (grow_capacity invariant)
+                    nm = self.mesh.size if self._ring_active() else 1
+                    di_stats = {}
+                    with span("dynamic_instability"):
+                        state = apply_dynamic_instability(
+                            state, p, rng, node_multiple=nm, stats=di_stats)
+                # t_cur/dt were read BEFORE the step: with donation on, the
+                # step consumes the input state's buffers
                 wall0 = _time.perf_counter()
-                new_state, solution, info = step_fn(state)
-                # host fetch: the loop needs the value anyway, and it doubles
-                # as the span's device-work sync (on the v5e
+                with span("dispatch"):
+                    new_state, solution, info = step_fn(state)
+                # host fetch: the loop needs the value anyway, and it is
+                # where the host waits for the device (on the v5e
                 # block_until_ready waits just as long — chip_smoke.py
                 # times both; an older backend was seen returning early)
-                residual = float(info.residual)
+                with span("wait"):
+                    residual = float(info.residual)
                 wall_s = _time.perf_counter() - wall0
-                sp.note(iters=int(info.iters), residual=residual)
-            n_steps += 1
-            converged = bool(info.converged)
-            fiber_error = float(info.fiber_error)
-            health = int(info.health)
-            # skelly-flight: the trial's decoded diagnostics row (one small
-            # device fetch), consumed by the metrics JSONL, the telemetry
-            # stream (timeline counter tracks), and fault provenance below
-            flight_row = None
-            if new_state.flight is not None and (
-                    metrics_fh is not None or health
-                    or obs_tracer.active() is not None):
-                flight_row = flight_mod.last_row(new_state.flight.rows,
-                                                 new_state.flight.count)
-                if flight_row is not None:
-                    obs_tracer.emit("flight", step=n_steps - 1,
-                                    **flight_row)
-            # the guard ladder may have retried this trial at a halved dt
-            # (Params.guard_dt_halvings): the dt that actually advanced the
-            # state is info.dt_used — identical to `dt` when the ladder is
-            # off or never fired, so the pre-guard arithmetic is unchanged
-            dt = float(info.dt_used)
+                with span("fetch_info"):
+                    iters = int(info.iters)
+                    cycles = int(info.cycles)
+                    refines = int(info.refines)
+                    residual_true = float(info.residual_true)
+                    converged = bool(info.converged)
+                    fiber_error = float(info.fiber_error)
+                    health = int(info.health)
+                    loss_of_accuracy = bool(info.loss_of_accuracy)
+                    guard_retries = int(info.guard_retries)
+                    # the guard ladder may have retried this trial at a
+                    # halved dt (Params.guard_dt_halvings): the dt that
+                    # actually advanced the state is info.dt_used —
+                    # identical to `dt` when the ladder is off or never
+                    # fired, so the pre-guard arithmetic is unchanged
+                    dt = float(info.dt_used)
+                sp.note(iters=iters, residual=residual)
+                n_steps += 1
+                # skelly-flight: the trial's decoded diagnostics row (one
+                # small device fetch), consumed by the metrics JSONL, the
+                # telemetry stream (timeline counter tracks), and fault
+                # provenance below
+                flight_row = None
+                if new_state.flight is not None and (
+                        metrics_fh is not None or health
+                        or obs_tracer.active() is not None):
+                    with span("flight_row"):
+                        flight_row = flight_mod.last_row(
+                            new_state.flight.rows, new_state.flight.count)
+                    if flight_row is not None:
+                        obs_tracer.emit("flight", step=n_steps - 1,
+                                        **flight_row)
 
-            dt_new = dt
-            accept = True
-            if p.adaptive_timestep_flag:
-                if converged and fiber_error <= p.fiber_error_tol:
-                    accept = True
-                    if fiber_error <= 0.9 * p.fiber_error_tol:
-                        dt_new = min(p.dt_max, dt * p.beta_up)
-                else:
-                    dt_new = dt * p.beta_down
-                    accept = False
+                dt_new = dt
+                accept = True
+                if p.adaptive_timestep_flag:
+                    if converged and fiber_error <= p.fiber_error_tol:
+                        accept = True
+                        if fiber_error <= 0.9 * p.fiber_error_tol:
+                            dt_new = min(p.dt_max, dt * p.beta_up)
+                    else:
+                        dt_new = dt * p.beta_down
+                        accept = False
 
-                if converged and bool(self._collision_jit(new_state)):
-                    dt_new = dt * 0.5
-                    accept = False
+                    if converged:
+                        with span("collision_gate"):
+                            collided = bool(self._collision_jit(new_state))
+                        if collided:
+                            dt_new = dt * 0.5
+                            accept = False
 
-                if dt_new < p.dt_min:
-                    raise RuntimeError("Timestep smaller than dt_min")
+                    if dt_new < p.dt_min:
+                        raise RuntimeError("Timestep smaller than dt_min")
 
-            logger.info(
-                "step t=%.6g dt=%.4g iters=%d residual=%.3e (true %.3e) "
-                "fiber_error=%.3e %s (%.3fs)", t_cur, dt,
-                int(info.iters), residual,
-                float(info.residual_true), fiber_error,
-                "accepted" if accept else "rejected", wall_s)
-            if not converged and accept:
-                # without adaptive timestepping a non-converged solve is
-                # still accepted (the reference's loop likewise only rejects
-                # under the adaptive gate) — but never silently: the
-                # round-5 x64 CLI bug surfaced as exactly this, a 1e-10
-                # request quietly flooring at f32 noise
-                logger.warning(
-                    "GMRES did not converge: residual %.3e (true %.3e) vs "
-                    "tol %.1e; step accepted (adaptive timestep off)",
-                    residual, float(info.residual_true), p.gmres_tol)
-            if bool(info.loss_of_accuracy):
-                # `solver_hydro.cpp:85-92`: implicit convergence with a
-                # drifted explicit residual means the answer is worse than
-                # the solver claims
-                logger.warning(
-                    "GMRES loss of accuracy: implicit residual %.3e converged "
-                    "but explicit ||b-Ax||/||b|| = %.3e (> 10x tol %.1e)",
-                    residual, float(info.residual_true),
-                    p.gmres_tol)
-            if health:
-                # the device-side verdict, surfaced host-side exactly once
-                # per trial: a structured `fault` telemetry event (the obs
-                # summarize fault table) plus the log line the reference
-                # would have aborted with
-                verdict_s = _verdict.describe(health)
-                # flight provenance rides the fault event when the recorder
-                # localized the offender (obs.flight — "who and where"
-                # next to guard's "something died")
-                prov = (flight_row or {}).get("provenance") or {}
-                prov_fields = ({"prov_field": prov.get("field"),
-                                "prov_fiber": prov.get("fiber"),
-                                "prov_node": prov.get("node")}
-                               if prov else {})
-                obs_tracer.emit("fault", kind="solver_health",
-                                verdict=verdict_s, health=health,
-                                t=t_cur, dt=dt,
-                                retries=int(info.guard_retries),
-                                **prov_fields)
-                logger.warning(
-                    "solver health verdict at t=%.6g: %s (health=%#x, "
-                    "guard retries=%d)", t_cur, verdict_s, health,
-                    int(info.guard_retries))
-            if metrics_fh is not None:
-                # key set == METRICS_FIELDS (schema-pinned; docs/performance.md)
-                metrics_fh.write(json.dumps({
-                    "step": n_steps - 1,
-                    "t": t_cur, "dt": dt, "iters": int(info.iters),
-                    "gmres_cycles": int(info.cycles),
-                    # dot-product psum rounds this solve paid through the
-                    # rdot seam (the s-step lever; `gmres.collective_rounds`
-                    # — restart= floors boundaries at ceil(iters/restart)
-                    # so mixed-precision inner restarts still register)
-                    "collective_rounds": collective_rounds(
-                        info.iters, info.cycles, p.gmres_block_s,
-                        restart=p.gmres_restart),
-                    "residual": residual,
-                    "residual_true": float(info.residual_true),
-                    "fiber_error": fiber_error, "accepted": accept,
-                    "refines": int(info.refines),
-                    "loss_of_accuracy": bool(info.loss_of_accuracy),
-                    "health": health,
-                    "guard_retries": int(info.guard_retries),
-                    # dynamic-instability trajectory (docs/scenarios.md):
-                    # events applied this trial (a rejected trial discards
-                    # its DI update, so it reports 0/0, matching the
-                    # ensemble records) and the live count that persists
-                    "nucleations": (di_stats["nucleations"]
-                                    if accept and di_stats else 0),
-                    "catastrophes": (di_stats["catastrophes"]
-                                     if accept and di_stats else 0),
-                    "active_fibers": (_di_count_active(
-                        (new_state if accept else backup).fibers)
-                        if di_stats is not None else 0),
-                    "wall_s": round(wall_s, 4),
-                    "wall_ms": round(wall_s * 1e3, 3),
-                    "gmres_history": history_rows(info.history,
-                                                  info.cycles),
-                    # the flight recorder's decoded row for THIS trial
-                    # (None at flight_window=0; docs/observability.md)
-                    "flight": flight_row}) + "\n")
-                metrics_fh.flush()
+                with span("log"):
+                    self._log_step(t_cur, dt, iters, residual, residual_true,
+                                   fiber_error, accept, wall_s, converged,
+                                   loss_of_accuracy, health, guard_retries,
+                                   flight_row)
+                if metrics_fh is not None:
+                    with span("metrics_row"):
+                        # key set == METRICS_FIELDS (schema-pinned;
+                        # docs/performance.md)
+                        metrics_fh.write(json.dumps({
+                            "step": n_steps - 1,
+                            "t": t_cur, "dt": dt, "iters": iters,
+                            "gmres_cycles": cycles,
+                            # dot-product psum rounds this solve paid
+                            # through the rdot seam (the s-step lever;
+                            # `gmres.collective_rounds` — restart= floors
+                            # boundaries at ceil(iters/restart) so
+                            # mixed-precision inner restarts still register)
+                            "collective_rounds": collective_rounds(
+                                iters, cycles, p.gmres_block_s,
+                                restart=p.gmres_restart),
+                            "residual": residual,
+                            "residual_true": residual_true,
+                            "fiber_error": fiber_error, "accepted": accept,
+                            "refines": refines,
+                            "loss_of_accuracy": loss_of_accuracy,
+                            "health": health,
+                            "guard_retries": guard_retries,
+                            # dynamic-instability trajectory
+                            # (docs/scenarios.md): events applied this trial
+                            # (a rejected trial discards its DI update, so it
+                            # reports 0/0, matching the ensemble records) and
+                            # the live count that persists
+                            "nucleations": (di_stats["nucleations"]
+                                            if accept and di_stats else 0),
+                            "catastrophes": (di_stats["catastrophes"]
+                                             if accept and di_stats else 0),
+                            "active_fibers": (_di_count_active(
+                                (new_state if accept else backup).fibers)
+                                if di_stats is not None else 0),
+                            "wall_s": round(wall_s, 4),
+                            "wall_ms": round(wall_s * 1e3, 3),
+                            "gmres_history": history_rows(info.history,
+                                                          cycles),
+                            # the flight recorder's decoded row for THIS
+                            # trial (None at flight_window=0;
+                            # docs/observability.md)
+                            "flight": flight_row}) + "\n")
+                        metrics_fh.flush()
 
-            if accept:
-                t_new = t_cur + dt
-                state = new_state._replace(
-                    time=jnp.asarray(t_new, dtype=state.time.dtype),
-                    dt=jnp.asarray(dt_new, dtype=state.dt.dtype))
-                if writer is not None and crossed_write_boundary(
+                with span("advance_clock"):
+                    if accept:
+                        t_new = t_cur + dt
+                        state = new_state._replace(
+                            time=jnp.asarray(t_new, dtype=state.time.dtype),
+                            dt=jnp.asarray(dt_new, dtype=state.dt.dtype))
+                    else:
+                        # a rejected trial rolls back the physics but KEEPS
+                        # the flight ring: the recorder's whole point is the
+                        # trajectory into trouble, and the rejected
+                        # attempt's row is evidence
+                        state = backup._replace(
+                            dt=jnp.asarray(dt_new, dtype=state.dt.dtype),
+                            flight=new_state.flight)
+                if accept and writer is not None and crossed_write_boundary(
                         t_new, dt, p.dt_write):
-                    with obs_tracer.span("write_frame", t=t_new):
-                        if rng is not None:
-                            writer(state, solution,
-                                   rng_state=rng.dump_state())
-                        else:
-                            writer(state, solution)
-            else:
-                # a rejected trial rolls back the physics but KEEPS the
-                # flight ring: the recorder's whole point is the trajectory
-                # into trouble, and the rejected attempt's row is evidence
-                state = backup._replace(
-                    dt=jnp.asarray(dt_new, dtype=state.dt.dtype),
-                    flight=new_state.flight)
+                    with span("write_frame", t=t_new) as wsp:
+                        # a `TrajectoryWriter` says how many bytes it wrote
+                        # (its ``encode`` and ``io`` spans nest here)
+                        kw = ({"rng_state": rng.dump_state()}
+                              if rng is not None else {})
+                        written = writer(state, solution, **kw)
+                        if written is not None:
+                            wsp.note(bytes=written)
+                t_cur, dt = clock_read(state)
         return state
 
 
