@@ -744,9 +744,9 @@ def _group_kernels(extra, ck, on_acc):
         ck()
 
     # fused Pallas DF tile (round 5, accelerator only): same f64-grade
-    # accuracy class with the whole chain in VMEM — the rate here plus the
-    # rel_err on real Mosaic is the promotion gate for refine_pair_impl
-    # "auto" -> "pallas_df"
+    # accuracy class with the whole chain in registers — what
+    # refine_pair_impl "auto" resolves to on a TPU since PR 27
+    # (`scripts/sweep_pallas_df.py` is the sweep it was pinned from)
     if on_acc and _remaining() > 60:
         if ref_df is None:
             # distinguish "no reference available" (the stokeslet_df step

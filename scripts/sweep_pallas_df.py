@@ -1,92 +1,148 @@
 """Sweep Pallas DF tile shapes on the live backend.
 
-The exact Pallas tiles were swept in round 5 ((256, 1024) stokeslet /
-(128, 2048) stresslet on v5e); the DF tiles hold ~3x the live temporaries,
-so their VMEM-feasible frontier is different. This sweeps (tile_t, tile_s)
-for both DF kernels, printing rate + accuracy per shape — run it on the
-TPU and pin the winners as `ops.pallas_df.DF_TILE_T/S`.
+The DF tiles (`ops.pallas_df`) walk a (tile_t, tile_s) VMEM block in strips
+of 8 targets x strip_w sources. This sweeps (tile_t, tile_s, strip_w) for
+both DF kernels at the shapes the benchmark's cells run — the fiber cell's
+16,384^2 and the walkthrough's 6,464 targets against 6,000 / 400 / 64
+sources — printing rate, error against the f64 oracle and compile seconds
+per shape. Run it on the TPU and pin the winners as
+`ops.pallas_df.DF_TILE_T / DF_TILE_S / DF_STRIP_W` (PERF.md holds the table
+they were pinned from).
 
-Usage: python scripts/sweep_pallas_df.py [--n 16384] [--trials 2]
+Usage: python scripts/sweep_pallas_df.py [--shapes 16384x16384,6464x6000]
+           [--tiles 256x2048x256,128x1024x128] [--kernel both] [--trials 3]
+           [--twin] [--root DIR] [--out FILE]
 """
 
 from __future__ import annotations
 
 import argparse
-import itertools
+import inspect
 import json
 import os
 import sys
+import time
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+#: (n_trg, n_src): the fiber cell's square sum, then the walkthrough's
+#: shell, body and fiber sources against all of its nodes
+SHAPES = ((16384, 16384), (6464, 6000), (6464, 400), (6464, 64))
+#: (tile_t, tile_s, strip_w) candidates
+TILES = ((128, 1024, 128), (256, 2048, 128), (128, 2048, 256),
+         (256, 1024, 256), (256, 2048, 256), (256, 4096, 256),
+         (512, 2048, 256), (128, 2048, 512), (256, 2048, 512),
+         (256, 4096, 512), (512, 2048, 512), (256, 2048, 1024))
 
-TILES_T = (64, 128, 256)
-TILES_S = (128, 256, 512, 1024)
+
+def _tuples(text, sep="x"):
+    return tuple(tuple(int(v) for v in item.split(sep))
+                 for item in text.split(","))
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--n", type=int, default=16384)
-    ap.add_argument("--trials", type=int, default=2)
+    ap.add_argument("--shapes", type=_tuples, default=SHAPES,
+                    help="n_trg x n_src, comma separated")
+    ap.add_argument("--tiles", type=_tuples, default=TILES,
+                    help="tile_t x tile_s x strip_w, comma separated")
+    ap.add_argument("--trials", type=int, default=3)
     ap.add_argument("--kernel", choices=("stokeslet", "stresslet", "both"),
                     default="both")
+    ap.add_argument("--twin", action="store_true",
+                    help="also time the XLA double-float twin per shape")
+    ap.add_argument("--root", default=os.path.join(os.path.dirname(__file__),
+                                                   ".."),
+                    help="checkout to import skellysim_tpu from")
+    ap.add_argument("--out", help="also append the JSON lines to this file")
     ap.add_argument("--interpret", action="store_true",
                     help="CPU smoke mode: force the CPU backend and run "
                          "the tiles in interpret mode")
     args = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(args.root))
 
     if args.interpret:
         from skellysim_tpu.utils.bootstrap import force_cpu_devices
 
         force_cpu_devices()
-        # interpret mode evaluates grid cells at Python speed: the TPU
-        # default (16384) would run for hours; clamp to smoke scale
-        args.n = min(args.n, 512)
+        # interpret mode runs the grid through XLA:CPU; clamp to smoke scale
+        args.shapes = tuple((min(t, 96), min(s, 300)) for t, s in args.shapes)
     import jax
 
     jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
     import numpy as np
 
-    from skellysim_tpu.ops import kernels
-    from skellysim_tpu.ops.pallas_df import (stokeslet_pallas_df,
-                                             stresslet_pallas_df)
-
-    n = args.n
-    rng = np.random.default_rng(1)
-    r = jnp.asarray(rng.uniform(-5, 5, (n, 3)), dtype=jnp.float64)
-    f = jnp.asarray(rng.standard_normal((n, 3)), dtype=jnp.float64)
-    S = jnp.asarray(rng.standard_normal((n, 3, 3)), dtype=jnp.float64)
-    print(json.dumps({"backend": jax.default_backend(), "n": n}), flush=True)
-
     import bench  # shared timing helper (host-fetch barrier, see bench._rate)
+    import chip_smoke  # the NumPy f64 oracles of the on-chip accuracy gate
+    from skellysim_tpu.ops import df_kernels, pallas_df
 
-    # accuracy oracle on a subsample (full f64 dense is slow on TPU);
-    # compute only the selected kernels' references — emulated-f64 work for
-    # a deselected kernel is pure waste on the chip
-    sub = np.random.default_rng(0).choice(n, size=min(n, 256), replace=False)
-    cases = []
-    if args.kernel in ("stokeslet", "both"):
-        cases.append(("stokeslet", stokeslet_pallas_df, f,
-                      np.asarray(kernels.stokeslet_direct(r, r[sub], f, 1.0))))
-    if args.kernel in ("stresslet", "both"):
-        cases.append(("stresslet", stresslet_pallas_df, S,
-                      np.asarray(kernels.stresslet_direct(r, r[sub], S, 1.0))))
+    def say(**row):
+        line = json.dumps(row)
+        print(line, flush=True)
+        if args.out:
+            os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+            with open(args.out, "a") as fh:
+                fh.write(line + "\n")
 
-    for tt, ts in itertools.product(TILES_T, TILES_S):
-        for name, fn, payload, ref in cases:
-            try:
-                call = lambda: fn(r, r, payload, 1.0, tile_t=tt, tile_s=ts,
-                                  interpret=args.interpret)
-                rr = bench._rate(call, n * n, trials=args.trials)
-                err = (np.linalg.norm(np.asarray(call())[sub] - ref)
-                       / np.linalg.norm(ref))
-                print(json.dumps({"kernel": name, "tile": [tt, ts],
-                                  "gpairs_per_s": round(rr / 1e9, 3),
-                                  "rel_err": float(err)}), flush=True)
-            except Exception as e:
-                print(json.dumps({"kernel": name, "tile": [tt, ts],
-                                  "error": repr(e).splitlines()[0][:160]}),
-                      flush=True)
+    say(backend=jax.default_backend(),
+        device=jax.devices()[0].device_kind, root=os.path.abspath(args.root))
+    rng = np.random.default_rng(1)
+    for n_trg, n_src in args.shapes:
+        # the square shape sums a cloud over itself (self pairs drop), the
+        # others over targets of their own
+        r_src = jnp.asarray(rng.uniform(-5, 5, (n_src, 3)))
+        r_trg = (r_src if n_trg == n_src
+                 else jnp.asarray(rng.uniform(-5, 5, (n_trg, 3))))
+        # accuracy oracle in NumPy f64 on the host, on a subsample of the
+        # targets; only the selected kernels' references are computed
+        sub = np.random.default_rng(0).choice(n_trg, size=min(n_trg, 256),
+                                              replace=False)
+        src_np, sub_np = np.asarray(r_src), np.asarray(r_trg)[sub]
+        cases = []
+        if args.kernel in ("stokeslet", "both"):
+            f = jnp.asarray(rng.standard_normal((n_src, 3)))
+            cases.append(("stokeslet", pallas_df.stokeslet_pallas_df,
+                          df_kernels.stokeslet_direct_df, f,
+                          chip_smoke.stokeslet_oracle(src_np, sub_np,
+                                                      np.asarray(f))))
+        if args.kernel in ("stresslet", "both"):
+            S = jnp.asarray(rng.standard_normal((n_src, 3, 3)))
+            cases.append(("stresslet", pallas_df.stresslet_pallas_df,
+                          df_kernels.stresslet_direct_df, S,
+                          chip_smoke.stresslet_oracle(src_np, sub_np,
+                                                      np.asarray(S))))
+        for name, fn, twin, payload, ref in cases:
+            def measure(tile, call):
+                row = {"kernel": name, "shape": [n_trg, n_src], "tile": tile}
+                try:
+                    t0 = time.perf_counter()
+                    got = np.asarray(call())  # compiles
+                    row["compile_s"] = round(time.perf_counter() - t0, 2)
+                    rate = bench._rate(call, n_trg * n_src,
+                                       trials=args.trials)
+                    row["gpairs_per_s"] = round(rate / 1e9, 4)
+                    row["ms_per_call"] = round(n_trg * n_src / rate * 1e3, 3)
+                    row["rel_err"] = float(np.linalg.norm(got[sub] - ref)
+                                           / np.linalg.norm(ref))
+                except Exception as e:
+                    row["error"] = repr(e).splitlines()[0][:160]
+                say(**row)
+
+            if args.twin:
+                measure("xla_df",
+                        lambda: twin(r_src, r_trg, payload, 1.0))
+            # an older checkout's tile has no strip: sweep what it takes
+            strip = "strip_w" in inspect.signature(fn).parameters
+            seen = set()
+            for tt, ts, sw in args.tiles:
+                kw = dict(tile_t=tt, tile_s=ts, interpret=args.interpret)
+                if strip:
+                    kw["strip_w"] = sw
+                key = tuple(sorted(kw.items()))
+                if key in seen:
+                    continue
+                seen.add(key)
+                measure([tt, ts, sw] if strip else [tt, ts],
+                        lambda: fn(r_src, r_trg, payload, 1.0, **kw))
 
 
 if __name__ == "__main__":

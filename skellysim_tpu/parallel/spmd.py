@@ -223,6 +223,8 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
     # through the refinement tile (System._prep / _solve_impl semantics)
     refine = precision == "mixed" and is_f64
     prep_impl = hi_impl = (system._refine_impl if refine else p.kernel_impl)
+    if precision == "mixed":
+        system._announce_refine_tile(hi_impl)
     precond_dtype = jnp.float32 if precision == "mixed" else None
     has_pair = pair is not None and getattr(pair, "is_fast", False)
     if has_pair and pair.evaluator != "tree":

@@ -209,10 +209,14 @@ class Params:
     # pairwise-kernel tile for the f64 refinement residual (and prep flows)
     # in "mixed" mode: "exact" = native f64 (fast on CPU, ~100x slower than
     # f32 on TPUs, whose f64 is software-emulated), "df" = double-float f32
-    # (`ops.df_kernels`, ~1e-14 relative — far beyond gmres_tol needs),
-    # "pallas_df" = the same double-float arithmetic fused into Pallas VMEM
-    # tiles (`ops.pallas_df` — removes the XLA path's HBM-staged fusion
-    # round trips), "auto" = "df" on accelerators, "exact" on CPU. The ring
+    # in XLA blocks (`ops.df_kernels`, ~1e-14 relative — far beyond
+    # gmres_tol needs), "pallas_df" = the same double-float arithmetic fused
+    # into Pallas VMEM tiles walked in register-sized strips
+    # (`ops.pallas_df`; TPU only), "auto" = "pallas_df" on a TPU, "df" on
+    # any other accelerator, "exact" on CPU. Measured on a v5e at 16,384^2
+    # (PERF.md, PR 27): "pallas_df" 10.0 Gpairs/s (Stokeslet) / 7.6
+    # (stresslet), "df" 0.65 inside the step (3.9 standalone), both 2e-14 /
+    # 1e-13 off the f64 oracle. The ring
     # evaluator serves both DF spellings with its own double-float tiles
     # (`parallel.ring.ring_stokeslet_df` / `ring_stresslet_df`)
     refine_pair_impl: str = "auto"  # one of REFINE_PAIR_IMPLS

@@ -280,11 +280,33 @@ class System:
         Params.refine_pair_impl). Resolved lazily from self.params — the
         codebase's pattern of replacing params post-construction
         (`system.params = dataclasses.replace(...)`) must not pin a stale
-        tile."""
+        tile. "auto" follows the backend, as `solver_precision="auto"` does:
+        the fused Pallas double-float tile on a TPU (the only backend it
+        lowers for), the XLA double-float blocks on any other accelerator,
+        native f64 on a CPU."""
         impl = self.params.refine_pair_impl
         if impl == "auto":
-            return "df" if jax.default_backend() != "cpu" else "exact"
+            return {"tpu": "pallas_df", "cpu": "exact"}.get(
+                jax.default_backend(), "df")
         return impl
+
+    def _announce_refine_tile(self, taken: str) -> str:
+        """Trace-time (once per build, like `pallas_tile_fallback` and
+        `ring_fused`): name the tile the mixed solver's f64 flows take and
+        why, in the log and as a ``refine_tile`` event; a run that resolved
+        to the Pallas tile and takes any other says so as a ``fault``."""
+        requested = self.params.refine_pair_impl
+        backend = jax.default_backend()
+        logger.info("refine_tile impl=%s requested=%s backend=%s", taken,
+                    requested, backend)
+        obs_tracer.emit("refine_tile", impl=taken, requested=requested,
+                        backend=backend)
+        if self._refine_impl == "pallas_df" and taken != "pallas_df":
+            logger.warning("refine_pair_impl resolved to 'pallas_df' but the "
+                           "f64 flows take the %r tile", taken)
+            obs_tracer.emit("fault", kind="refine_tile_mismatch",
+                            resolved="pallas_df", taken=taken)
+        return taken
 
     def _precision_for(self, state) -> str:
         """Resolve Params.solver_precision for one state ("full"/"mixed").
@@ -943,8 +965,9 @@ class System:
             lo = _cast_floats((state, caches, body_caches), jnp.float32)
             # hi residual flows go through the refinement tile (df on
             # accelerators); state must be f64 for the df split to pay off
-            hi_impl = (self._refine_impl
-                       if state.time.dtype == jnp.float64 else p.kernel_impl)
+            hi_impl = self._announce_refine_tile(
+                self._refine_impl if state.time.dtype == jnp.float64
+                else p.kernel_impl)
             with jax.named_scope("gmres"):
                 result = gmres_ir(
                     # hi residual matvec: dense (no ewald plan) regardless

@@ -72,17 +72,55 @@ def test_f32_pallas_tile_compiles(one_chip, kind, payload_tail):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("kind,payload_tail", [("stokeslet", (3,)),
-                                               ("stresslet", (3, 3))])
-def test_pallas_df_tile_compiles(one_chip, kind, payload_tail):
+# the shapes the benchmark's cells run: the fiber cell's square sum, then
+# the walkthrough's 6,464 nodes against its shell, body and fiber sources
+@pytest.mark.parametrize("kind,payload_tail,n_src,n_trg", [
+    ("stokeslet", (3,), 16384, 16384), ("stresslet", (3, 3), 16384, 16384),
+    ("stresslet", (3, 3), 6000, 6464), ("stresslet", (3, 3), 400, 6464),
+    ("stokeslet", (3,), 64, 6464)])
+def test_pallas_df_tile_compiles(one_chip, kind, payload_tail, n_src, n_trg):
     """The double-float Pallas tiles, f64 in / f64 out under x64 (Mosaic
-    refused the lane-roll's i64 shift before PR 22)."""
+    refused the lane-roll's i64 shift before PR 22, and an i64 strip
+    counter and a 12-row source block in PR 27)."""
     from skellysim_tpu.ops import pallas_df
 
     fn = getattr(pallas_df, f"{kind}_pallas_df")
-    r_src, r_trg, pay = _cloud(16384, 16384, payload_tail, jnp.float64,
+    r_src, r_trg, pay = _cloud(n_src, n_trg, payload_tail, jnp.float64,
                                one_chip)
     text = _compile(lambda s, t, p: fn(s, t, p, 1.0), r_src, r_trg, pay)
+    assert "tpu_custom_call" in text
+
+
+def test_pallas_df_tile_compiles_under_vmap(one_chip):
+    """Two members' sums in one call, as the ensemble's vmapped step issues
+    them: the `pallas_call` grows a grid axis and keeps its scratch."""
+    from skellysim_tpu.ops.pallas_df import stokeslet_pallas_df
+
+    def st(shape):
+        return jax.ShapeDtypeStruct((2,) + shape, jnp.float64,
+                                    sharding=one_chip)
+
+    text = _compile(jax.vmap(lambda s, t, p: stokeslet_pallas_df(s, t, p,
+                                                                 1.0)),
+                    st((4096, 3)), st((4096, 3)), st((4096, 3)))
+    assert "tpu_custom_call" in text
+
+
+def test_pallas_df_ring_compiles_on_four_chips(topo, monkeypatch):
+    """`ring_stokeslet_df(impl="pallas_df")`, the tile `step_spmd` takes on
+    a TPU mesh, 4,096 nodes a shard. The ring asks the backend whether to
+    interpret; here it is told what the described chips are."""
+    from skellysim_tpu.parallel.mesh import FIBER_AXIS
+    from skellysim_tpu.parallel.ring import ring_stokeslet_df
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_dev, rows = 4, 4096
+    mesh = Mesh(topo.devices[:n_dev], (FIBER_AXIS,))
+    r_src, r_trg, f = _cloud(n_dev * rows, n_dev * rows, (3,), jnp.float64,
+                             NamedSharding(mesh, P(FIBER_AXIS)))
+    text = _compile(lambda s, t, p: ring_stokeslet_df(s, t, p, 1.0, mesh=mesh,
+                                                      impl="pallas_df"),
+                    r_src, r_trg, f)
     assert "tpu_custom_call" in text
 
 
@@ -112,8 +150,8 @@ def test_fused_ring_compiles_on_four_chips(topo, kind, payload_tail):
 
 
 def test_df_direct_tile_compiles(one_chip):
-    """The XLA double-float tile (the resolved default refinement tile on a
-    chip) at 8,192^2."""
+    """The XLA double-float tile (the refinement tile of accelerators other
+    than a TPU, and the twin the Pallas tile is tested against) at 8,192^2."""
     from skellysim_tpu.ops.df_kernels import stokeslet_direct_df
 
     r_src, r_trg, f = _cloud(8192, 8192, (3,), jnp.float64, one_chip)
