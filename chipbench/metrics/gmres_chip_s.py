@@ -1,0 +1,12 @@
+"""Step phases: device self time a traced step, a chip (mean over the
+device planes), of the ops under ``gmres`` outside ``refine`` — the f32
+Krylov loop. `gmres_device_s` a chip (`mesh_planes.py`)."""
+
+import mesh_planes
+
+probe = mesh_planes.probe
+
+
+def read(run):
+    return mesh_planes.per_chip_seconds(run, has=("gmres",),
+                                        lacks=("refine",))

@@ -223,12 +223,37 @@ def build_background(cfg_bg, dtype) -> BackgroundFlow | None:
                                scale=cfg_bg.scale_factor, dtype=dtype)
 
 
+def _config_mesh(params):
+    """The mesh a config asks for (``params.mesh_devices`` over 1), or None.
+    Refuses in words where fewer devices are visible; logs the one line that
+    says what the run loop will step."""
+    n = params.mesh_devices
+    if n == 1:
+        return None
+    visible = len(jax.devices())
+    if n > visible:
+        raise ValueError(
+            f"params.mesh_devices = {n} but only {visible} device(s) are "
+            f"visible to jax ({jax.default_backend()}); run on a host with "
+            f"{n} devices or lower params.mesh_devices")
+    from .parallel import FIBER_AXIS, make_mesh
+
+    # how each ring moves its blocks is said by the ring itself when the
+    # step is traced: a `ring_fused` event, or a `fused_ring_fallback` fault
+    # with the leg that failed (`parallel.ring._ring_or_fused`)
+    logger.info("mesh devices=%d axis=%s step=spmd", n, FIBER_AXIS)
+    return make_mesh(n)
+
+
 def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
                      mesh=None, synthesize_body_precompute: bool = False):
     """Config (object or TOML path) → (System, SimState, SimRNG).
 
     ``mesh`` enables the ring pair evaluator when the config selects
-    pair_evaluator = "ring"; without one the dense direct path runs.
+    pair_evaluator = "ring"; without one the dense direct path runs. A
+    config with ``params.mesh_devices`` over 1 gets its mesh made here
+    (`_config_mesh`) and `System.run` then steps the mesh program on it;
+    the argument stays for callers that bring their own.
     ``synthesize_body_precompute`` rebuilds missing analytic body npz
     in-process (`build_bodies`) — the serve submit path.
     """
@@ -236,13 +261,20 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
         config_dir = os.path.dirname(os.path.abspath(config)) or "."
         config = schema.load_config(str(config))
 
+    # a TOML is loaded unvalidated: the one field that decides what is built
+    for problem in schema._validate_mesh(config):
+        raise ValueError(problem)
     params = schema.to_runtime_params(config.params)
+    if mesh is None:
+        mesh = _config_mesh(params)
     if params.pair_evaluator == "ring" and mesh is None:
         # through the logger, not `warnings`: the CLIs build without a mesh
         # and must show that the ring a config asked for did not run
-        logger.warning("config selects pair_evaluator='ring' but no mesh "
-                       "was given to build_simulation; using the direct "
-                       "evaluator on one device")
+        logger.warning("config selects pair_evaluator='ring' but "
+                       "params.mesh_devices is 1 and no mesh was given to "
+                       "build_simulation; using the direct evaluator on one "
+                       "device (set params.mesh_devices to the device count "
+                       "to run on a mesh)")
     shell, shape = (None, None)
     if getattr(config, "periphery", None) is not None:
         # mixed mode gets an f32 M_inv, halving the shell preconditioner's
@@ -256,13 +288,13 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
                                        precond_dtype=pdt)
 
     fibers = build_fibers(config.fibers, dtype)
-    if (fibers is not None and params.pair_evaluator == "ring"
-            and mesh is not None):
-        # round the fiber batch up to a mesh-divisible node count with inert
-        # padding fibers so user configs never hit the ring divisibility
-        # ValueError (System._fiber_flow); re-homed onto the one bucket
-        # policy module (`system.buckets.pad_for_mesh`) — each bucket pads
-        # to a mesh-divisible node count, so the concatenated total divides
+    if fibers is not None and mesh is not None:
+        # a System with a mesh steps the mesh program in `System.run`,
+        # whoever made the mesh: round the fiber batch up to whole fibers a
+        # device with inert padding fibers, so user configs never hit a
+        # divisibility ValueError (`spmd_shell_mode`; the ring's node count,
+        # `System._fiber_flow`, then divides too) — one bucket policy module
+        # (`system.buckets.pad_for_mesh`) pads each bucket
         from .system.buckets import pad_for_mesh
 
         fibers = pad_for_mesh(fibers, mesh.size)

@@ -220,6 +220,12 @@ class Params:
     # coupled-solve preconditioner: "gs" (block Gauss-Seidel, shell-first
     # coupling correction) or "jacobi" (the reference's independent blocks)
     precond: str = "gs"
+    # the deployment's device count, the counterpart of `mpirun -n`: over 1,
+    # `builder.build_simulation` makes the mesh itself and `System.run`
+    # steps the explicitly-sharded program on it (docs/parallel.md). Stated,
+    # never derived from the visible devices: 1 is one device whatever the
+    # host holds
+    mesh_devices: int = 1
 
 
 @dataclass
@@ -696,6 +702,7 @@ class Config:
     def validate(self) -> list[str]:
         problems = _validate(self)
         problems += _validate_periodic(self)
+        problems += _validate_mesh(self)
         for j, b in enumerate(self.bodies):
             if getattr(b, "shape", None) == "deformable":
                 # fail at schema-validation time with the stub named, not
@@ -733,6 +740,18 @@ class ConfigRevolution(Config):
 
 # ---------------------------------------------------------------------------
 # validation / (de)serialization
+
+def _validate_mesh(cfg) -> list[str]:
+    """``params.mesh_devices`` is a whole number of devices, 1 or more
+    (whether that many are visible is the builder's question: a config is
+    written on one machine and run on another)."""
+    n = cfg.params.mesh_devices
+    if isinstance(n, bool) or not isinstance(n, int) or n < 1:
+        return [f"params.mesh_devices: must be an integer >= 1 (the number "
+                f"of devices the run is sharded over; 1 = one device), "
+                f"got {n!r}"]
+    return []
+
 
 def _validate_periodic(cfg) -> list[str]:
     """Periodic-box / evaluator pairing rules (docs/spectral.md).
@@ -917,6 +936,7 @@ def to_runtime_params(p: Params) -> runtime_params.Params:
         kernel_impl=p.kernel_impl,
         refine_pair_impl=p.refine_pair_impl,
         precond=p.precond,
+        mesh_devices=p.mesh_devices,
         dynamic_instability=runtime_params.DynamicInstability(
             **dataclasses.asdict(p.dynamic_instability)),
         periphery_binding=runtime_params.PeripheryBinding(

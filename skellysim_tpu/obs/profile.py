@@ -453,9 +453,12 @@ def step_phase_of(phase: Optional[str]) -> str:
 class DeviceTrace:
     """Aggregated per-op device time from one profile dump.
 
-    ``rows`` is a list of dicts: op (instruction name), module, phase
+    ``rows`` is a list of dicts, one per instruction and plane: pid (the
+    plane: one chip), op (instruction name), module, phase
     (recognized scope path or None), collective (kind or None), scope (the
     full metadata op_name when known), dur_us (summed SELF time), count.
+    The totals and tables sum over the planes; `plane_seconds` and
+    `plane_table` keep the chips of a mesh run apart.
     ``events`` keeps the raw per-execution op events (ts/dur/self_us in
     microseconds on the dump's clock, phase, ...) for the timeline
     renderer; ``spans`` the run loop's ``skelly/`` annotations as
@@ -505,6 +508,42 @@ class DeviceTrace:
                 if not any(c in comps for c in lacks):
                     total += r["dur_us"]
         return total * 1e-6 if seen else None
+
+    # ------------------------------------------------------------ per chip
+
+    @property
+    def planes(self) -> list:
+        """The planes that ran ops, sorted: one per chip on a TPU."""
+        return sorted({r["pid"] for r in self.rows})
+
+    def plane_seconds(self, has=(), lacks=(), collective=None) -> dict:
+        """{plane: self seconds} of the ops `seconds` would count, for each
+        plane that ran any op (0.0 where a chip ran none of these).
+        ``collective=True`` keeps the collective ops alone (permutes,
+        all-reduces, all-gathers: the exchange itself)."""
+        out = {pid: 0.0 for pid in self.planes}
+        for r in self.rows:
+            comps = (r["phase"] or "").split("/")
+            if (all(c in comps for c in has)
+                    and not any(c in comps for c in lacks)
+                    and (collective is None
+                         or bool(r["collective"]) == collective)):
+                out[r["pid"]] += r["dur_us"] * 1e-6
+        return out
+
+    def plane_table(self) -> list:
+        """One row a chip: busy, op self time, and the mesh's own scopes
+        and collectives, in seconds."""
+        ring = self.plane_seconds(has=("ring-step",))
+        psum = self.plane_seconds(has=("psum-dots",))
+        coll = self.plane_seconds(collective=True)
+        total = self.plane_seconds()
+        return [{"plane": pid,
+                 "busy_s": sum(e - s for s, e in
+                               self.busy_intervals(pid)) * 1e-6,
+                 "op_self_s": total[pid], "ring_step_s": ring[pid],
+                 "psum_dots_s": psum[pid], "collective_s": coll[pid]}
+                for pid in self.planes]
 
     def _group(self, key_fn) -> list:
         groups: dict = {}
@@ -695,10 +734,13 @@ def load_device_trace(profile_dir: str, window=None) -> DeviceTrace:
                 for c in (phase_of(path) or "").split("/")}
     stale = "gmres" in declared and not declared & set(OPERATOR_SCOPES)
 
+    # a row is one instruction on one plane (one chip): the totals below sum
+    # over the planes, `DeviceTrace.plane_seconds` keeps the chips apart
     agg: dict = {}
     for e in events:
-        key = (e["module"], e["op"], e["phase"])
+        key = (e["pid"], e["module"], e["op"], e["phase"])
         row = agg.setdefault(key, {
+            "pid": e["pid"],
             "op": e["op"], "module": e["module"], "phase": e["phase"],
             "inferred": e["inferred"], "collective": e["collective"],
             "scope": e["scope"], "dur_us": 0.0, "count": 0})
@@ -766,6 +808,26 @@ def _render_cross(trace: DeviceTrace) -> list:
     return _columns(rows)
 
 
+def _render_planes(trace: DeviceTrace) -> list:
+    """The per-chip lines of a mesh run's report (none for one plane): the
+    tables above SUM over the chips; the slowest chip sets the step."""
+    table = trace.plane_table()
+    if len(table) < 2:
+        return []
+    cols = ("busy_s", "op_self_s", "ring_step_s", "psum_dots_s",
+            "collective_s")
+    rows = [("chip", *(c[:-2] + "_ms" for c in cols))]
+    for r in table:
+        rows.append((r["plane"], *(f"{r[c] * 1e3:.3f}" for c in cols)))
+    means = {c: sum(r[c] for r in table) / len(table) for c in cols}
+    rows.append(("mean", *(f"{means[c] * 1e3:.3f}" for c in cols)))
+    rows.append(("spread (max-min)/mean", *(
+        f"{(max(r[c] for r in table) - min(r[c] for r in table)) / means[c]:.1%}"
+        if means[c] > 0 else "-" for c in cols)))
+    return ["", f"per chip ({len(table)} device planes; the tables above sum "
+            "over them):"] + _columns(rows)
+
+
 def render_table(trace: DeviceTrace, by: str = "phase") -> str:
     """The `obs profile` text report (docs/observability.md)."""
     if by == "cross":
@@ -795,6 +857,7 @@ def render_table(trace: DeviceTrace, by: str = "phase") -> str:
                    f"({', '.join(OPERATOR_SCOPES)}); the compile cache "
                    "served executables from before those scopes "
                    "(docs/observability.md \"The cache and the scopes\")")
+    out.extend(_render_planes(trace))
     gaps = trace.gap_table(min_us=100.0)
     if trace.spans and gaps:
         out.append("")
@@ -818,6 +881,7 @@ def profile_json(trace: DeviceTrace) -> dict:
         "by_op": trace.by_op(),
         "phase_by_operator": trace.cross_table(),
         "idle_gaps": trace.gap_table(),
+        "per_plane": trace.plane_table(),
     }
 
 

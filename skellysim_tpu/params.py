@@ -244,6 +244,12 @@ class Params:
     # setup for point sets that warrant it; set to 0 to force every flow
     # through the fast evaluator (parity tests)
     ewald_min_sources: int = 2048
+    # the deployment's device count (TOML `params.mesh_devices`, the
+    # counterpart of `mpirun -n`): over 1, `builder.build_simulation` builds
+    # `parallel.make_mesh(n)` and `System.run` steps `step_spmd` on it.
+    # Never read inside a traced program: one device's programs do not
+    # depend on it
+    mesh_devices: int = 1
     implicit_motor_activation_delay: float = 0.0
     periphery_interaction_flag: bool = False
     dynamic_instability: DynamicInstability = field(default_factory=DynamicInstability)

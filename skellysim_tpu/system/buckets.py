@@ -326,18 +326,21 @@ def admits(key: BucketKey, state) -> bool:
 
 
 def pad_for_mesh(fibers, mesh_size: int):
-    """Round each fiber group up to a mesh-divisible node count with inert
-    padding slots — the ring evaluator's divisibility invariant, re-homed
-    from `builder.build_simulation`'s ad-hoc pad onto the bucket module so
-    the growers can never drift (`System._fiber_flow` dies mid-flight on a
-    violation)."""
+    """Round each fiber group up to whole fibers a device with inert padding
+    slots (the node count then divides the mesh too): the mesh step shards
+    whole fibers (`parallel.spmd.spmd_shell_mode`) and the ring evaluator
+    needs a mesh-divisible node count (`System._fiber_flow` dies mid-flight
+    on a violation). Re-homed from `builder.build_simulation`'s ad-hoc pad
+    onto the bucket module so the growers can never drift."""
     if fibers is None or mesh_size <= 1:
         return fibers
+
+    def pad(g):
+        return fc.grow_capacity(g, -(-g.n_fibers // mesh_size) * mesh_size)
+
     if isinstance(fibers, fc.FiberGroup):
-        return fc.grow_capacity(fibers, fibers.n_fibers,
-                                node_multiple=mesh_size)
-    return tuple(fc.grow_capacity(g, g.n_fibers, node_multiple=mesh_size)
-                 for g in fibers)
+        return pad(fibers)
+    return tuple(pad(g) for g in fibers)
 
 
 def next_fiber_capacity(n_needed: int, policy: BucketPolicy = None) -> int:

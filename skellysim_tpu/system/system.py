@@ -1536,6 +1536,35 @@ class System:
                 "guard retries=%d)", t_cur, verdict_s, health,
                 guard_retries)
 
+    def _mesh_step(self, rng, donate: bool):
+        """(step function, clock-scalar maker) of a run loop on
+        ``self.mesh``; refuses in words what `step_spmd` does not do."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        p, mesh = self.params, self.mesh
+        if rng is not None and p.dynamic_instability.n_nodes > 0:
+            raise NotImplementedError(
+                "dynamic instability on a mesh run: nucleation re-shapes the "
+                "fiber batch on the host between steps, which the mesh step "
+                "(System.step_spmd) does not follow; run with "
+                "params.mesh_devices = 1")
+        if p.pair_evaluator in ("ewald", "spectral"):
+            raise NotImplementedError(
+                f"pair_evaluator {p.pair_evaluator!r} on a mesh run: the mesh "
+                "step rings its pair sums (or takes 'tree'); use 'ring', "
+                "'direct' or 'tree', or params.mesh_devices = 1")
+        replicated = NamedSharding(mesh, PartitionSpec())
+
+        def clock(value, dtype):
+            # committed like every other leaf the step returns: an
+            # uncommitted scalar is another argument signature to `jit`
+            return jax.device_put(jnp.asarray(value, dtype=dtype), replicated)
+
+        def step_fn(state):
+            return self.step_spmd(state, mesh, donate=donate)
+
+        return step_fn, clock
+
     def _run_loop(self, state: SimState, *, writer, max_steps, rng, metrics_fh):
         from .dynamic_instability import (_count_active as _di_count_active,
                                           apply_dynamic_instability)
@@ -1555,6 +1584,18 @@ class System:
                      and jax.default_backend() != "cpu")
         step_fn = self._step_donating if donate_ok else self.step
         span = obs_tracer.span
+        clock = jnp.asarray
+        if self.mesh is not None:
+            # a System with a mesh steps the mesh program (`step_spmd`:
+            # the same (new_state, solution, StepInfo) triple, so the
+            # loop's body stays one body); the state is placed at entry,
+            # `bucketize` and a `run(max_steps=1)` re-entry hand over leaves
+            # that are not (placing a placed leaf is a no-op)
+            step_fn, clock = self._mesh_step(rng, donate_ok)
+            with span("place_state", devices=self.mesh.size):
+                from ..parallel import shard_state
+
+                state = shard_state(state, self.mesh)
 
         def clock_read(st):
             # the loop's clock, as the host holds it: two scalar fetches
@@ -1699,15 +1740,15 @@ class System:
                     if accept:
                         t_new = t_cur + dt
                         state = new_state._replace(
-                            time=jnp.asarray(t_new, dtype=state.time.dtype),
-                            dt=jnp.asarray(dt_new, dtype=state.dt.dtype))
+                            time=clock(t_new, dtype=state.time.dtype),
+                            dt=clock(dt_new, dtype=state.dt.dtype))
                     else:
                         # a rejected trial rolls back the physics but KEEPS
                         # the flight ring: the recorder's whole point is the
                         # trajectory into trouble, and the rejected
                         # attempt's row is evidence
                         state = backup._replace(
-                            dt=jnp.asarray(dt_new, dtype=state.dt.dtype),
+                            dt=clock(dt_new, dtype=state.dt.dtype),
                             flight=new_state.flight)
                 if accept and writer is not None and crossed_write_boundary(
                         t_new, dt, p.dt_write):
@@ -1720,6 +1761,14 @@ class System:
                         if written is not None:
                             wsp.note(bytes=written)
                 t_cur, dt = clock_read(state)
+        if self.mesh is not None:
+            # once a run; how each ring moved its blocks is in the stream
+            # already, from the ring's own trace (`ring_fused` events,
+            # `fused_ring_fallback` faults: `parallel.ring._ring_or_fused`)
+            from ..parallel import FIBER_AXIS
+
+            obs_tracer.emit("mesh", devices=self.mesh.size, axis=FIBER_AXIS,
+                            step="spmd")
         return state
 
 

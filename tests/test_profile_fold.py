@@ -334,3 +334,54 @@ def test_run_loop_spans_land_in_the_capture(tmp_path):
         assert r["bytes"] > 0
     assert any(r["ev"] == "device_phase" for r in recs)
     assert not any(r["ev"] == "device_phase_error" for r in recs)
+
+
+# ------------------------------------------------------ several device planes
+
+def _two_chip_trace():
+    """A hand-made fold of a two-chip dump: the same three instructions a
+    chip, the second chip a little slower in its ring."""
+    def row(pid, op, phase, us, collective=None):
+        return {"pid": pid, "op": op, "module": "jit_step", "phase": phase,
+                "inferred": False, "collective": collective, "scope": "",
+                "dur_us": us, "count": 1}
+
+    rows, events = [], []
+    for pid, ring_us in (("/device:TPU:0", 700.0), ("/device:TPU:1", 900.0)):
+        rows += [row(pid, "tile.1", "gmres/pair/ring-step", ring_us),
+                 row(pid, "dot.2", "gmres/gram/psum-dots", 100.0),
+                 row(pid, "all-reduce-start.3", "gmres/gram/psum-dots", 10.0,
+                     collective="all_reduce"),
+                 row(pid, "collective-permute-done.4",
+                     "gmres/pair/ring-step", 5.0,
+                     collective="collective_permute")]
+        events.append({"pid": pid, "ts": 0.0, "dur": ring_us + 115.0})
+    return profile_mod.DeviceTrace(rows, events, window_us=(0.0, 1100.0))
+
+
+def test_a_dump_of_several_planes_reads_per_chip():
+    trace = _two_chip_trace()
+    assert trace.planes == ["/device:TPU:0", "/device:TPU:1"]
+    # the totals still sum over the planes ...
+    assert trace.total_us == pytest.approx(1830.0)
+    assert trace.seconds(has=("ring-step",)) == pytest.approx(1610e-6)
+    # ... and the chips are kept apart
+    assert trace.plane_seconds(has=("ring-step",)) == pytest.approx(
+        {"/device:TPU:0": 705e-6, "/device:TPU:1": 905e-6})
+    assert trace.plane_seconds(collective=True) == pytest.approx(
+        {"/device:TPU:0": 15e-6, "/device:TPU:1": 15e-6})
+    table = trace.plane_table()
+    assert [r["plane"] for r in table] == trace.planes
+    assert table[1]["busy_s"] == pytest.approx(1015e-6)
+    assert table[0]["psum_dots_s"] == pytest.approx(110e-6)
+    text = profile_mod.render_table(trace)
+    assert "per chip (2 device planes" in text
+    assert "/device:TPU:1" in text and "spread (max-min)/mean" in text
+    assert profile_mod.profile_json(trace)["per_plane"] == table
+
+
+def test_a_dump_of_one_plane_prints_no_per_chip_table(step):
+    assert len(step.planes) == 1
+    assert "per chip" not in profile_mod.render_table(step)
+    assert step.plane_seconds() == pytest.approx(
+        {step.planes[0]: step.total_us * 1e-6})
