@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import kernels
+from ..ops import block_precond, kernels
 from ..utils import quaternion as quat
 
 EXTFORCE_LINEAR = 0
@@ -82,8 +82,12 @@ class BodyCaches(NamedTuple):
     ex: jnp.ndarray          # [nb, n, 3] singularity-subtraction vectors
     ey: jnp.ndarray
     ez: jnp.ndarray
-    lu: jnp.ndarray          # batched LU of the dense body operator
-    piv: jnp.ndarray
+    #: the dense body operator's block preconditioner, held one of two
+    #: ways by tier (`ops.block_precond`): batched LU factors (full tier) or
+    #: the [nb, 3n+6, 3n+6] inverse formed once a step (mixed tier)
+    lu: jnp.ndarray | None
+    piv: jnp.ndarray | None
+    inv: jnp.ndarray | None
 
 
 def make_group(nodes_ref, normals_ref, weights, *, position=None, orientation=None,
@@ -181,11 +185,14 @@ def place(group: BodyGroup):
 
 @jax.named_scope("body")
 def update_cache(group: BodyGroup, eta, precond_dtype=None) -> BodyCaches:
-    """Lab placement + singularity subtraction + K matrix + dense LU
-    (`update_cache_variables`, `body_spherical.cpp:94-127`).
+    """Lab placement + singularity subtraction + K matrix + the dense
+    operator's block preconditioner (`update_cache_variables`,
+    `body_spherical.cpp:94-127`).
 
-    ``precond_dtype`` stores the LU factors in a lower precision (f32 for
-    TPU, whose LuDecomposition is f32-only)."""
+    ``precond_dtype`` factors the operator in a lower precision (f32 for
+    TPU, whose LuDecomposition is f32-only) and stores its inverse, formed
+    here once a step, in the factors' place; None keeps the LU factors in
+    the state dtype (`ops.block_precond`)."""
     nodes, normals, sites = place(group)
     nb, n = group.n_bodies, group.n_nodes
 
@@ -221,12 +228,10 @@ def update_cache(group: BodyGroup, eta, precond_dtype=None) -> BodyCaches:
         return jnp.concatenate([top, bottom], axis=0)
 
     A = jax.vmap(build_A)(nodes, normals, group.weights, ex, ey, ez, K)
-    if precond_dtype is not None:
-        A = A.astype(precond_dtype)
-    lu, piv = jax.vmap(jax.scipy.linalg.lu_factor)(A)
+    lu, piv, inv = block_precond.factor(A, precond_dtype)
 
     return BodyCaches(nodes=nodes, normals=normals, nucleation_sites=sites,
-                      K=K, ex=ex, ey=ey, ez=ez, lu=lu, piv=piv)
+                      K=K, ex=ex, ey=ey, ez=ez, lu=lu, piv=piv, inv=inv)
 
 
 # ------------------------------------------------------------------ operators
@@ -255,11 +260,12 @@ def matvec(group: BodyGroup, caches: BodyCaches, x_bodies, v_bodies):
 
 @jax.named_scope("body")
 def apply_preconditioner(group: BodyGroup, caches: BodyCaches, x_bodies):
-    """Dense LU solves (`apply_preconditioner`, `body_spherical.cpp:37`);
-    solves in the LU factors' (possibly lower) precision and casts back."""
-    out = jax.vmap(lambda lu, piv, b: jax.scipy.linalg.lu_solve((lu, piv), b))(
-        caches.lu, caches.piv, x_bodies.astype(caches.lu.dtype))
-    return out.astype(x_bodies.dtype)
+    """Every body's dense operator inverted on ``x_bodies``
+    (`apply_preconditioner`, `body_spherical.cpp:37`): one matmul with the
+    stored inverse in the mixed tier, LU solves in the full tier
+    (`ops.block_precond`), in the stored block's (possibly lower) precision,
+    cast back."""
+    return block_precond.solve(caches, x_bodies)
 
 
 def update_RHS(group: BodyGroup, v_on_bodies):

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import kernels
+from ..ops import block_precond, kernels
 from . import fd_fiber
 from .fd_fiber import FiberScalars
 from .matrices import FibMats, get_mats, padded_rt_mats, typed
@@ -97,8 +97,14 @@ class FiberCaches(NamedTuple):
     force_op: jnp.ndarray   # [nf, 3n, 4n]
     A_bc: jnp.ndarray       # [nf, 4n, 4n] (BC-applied)
     RHS: jnp.ndarray        # [nf, 4n] (BC-applied)
-    lu: jnp.ndarray         # batched LU factors of A_bc
-    piv: jnp.ndarray
+    #: the block preconditioner of A_bc, held one of two ways by tier
+    #: (`ops.block_precond`): batched LU factors in the state's dtype (full
+    #: tier), or the [nf, 4n, 4n] inverse formed from lower-precision factors
+    #: once a step, pivots folded in (mixed tier) — never both; all three
+    #: are None until `update_rhs_and_bc`
+    lu: jnp.ndarray | None
+    piv: jnp.ndarray | None
+    inv: jnp.ndarray | None
 
 
 def make_group(x, lengths, bending_rigidity, radius, *, eta=None,
@@ -207,20 +213,22 @@ def update_cache(group: FiberGroup, dt, eta) -> FiberCaches:
     zeros4 = jnp.zeros((group.n_fibers, 4 * group.n_nodes), dtype=group.x.dtype)
     return FiberCaches(xs=xs, xss=xss, xsss=xsss, xssss=xssss, stokeslet=stokeslet,
                        force_op=force_op, A_bc=zeros44, RHS=zeros4,
-                       lu=zeros44, piv=jnp.zeros((group.n_fibers, 4 * group.n_nodes), dtype=jnp.int32))
+                       lu=None, piv=None, inv=None)
 
 
 @jax.named_scope("fiber")
 def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
                       v_on_fibers, f_total, f_ext,
                       precond_dtype=None) -> FiberCaches:
-    """Assemble BC-applied A/RHS and the batched LU preconditioner.
+    """Assemble BC-applied A/RHS and the batched block preconditioner.
 
     Mirrors the prep sequence of `System::prep_state_for_solver`
     (`system.cpp:448-453`): RHS uses the total force (motor + external), the BC
-    rows use only the external force. ``precond_dtype`` stores the LU factors
-    in a lower precision (f32 for TPU, whose LuDecomposition is f32-only)
-    while A/RHS stay in the state dtype.
+    rows use only the external force. ``precond_dtype`` factors A_bc in a
+    lower precision (f32 for TPU, whose LuDecomposition is f32-only) and
+    stores each block's inverse, formed here once a step, in the factors'
+    place; None keeps the LU factors in the state dtype
+    (`ops.block_precond`). A/RHS stay in the state dtype either way.
     """
     mats = group.mats
     sc = group.scalars()
@@ -231,6 +239,7 @@ def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
         A_bc, RHS_bc = fd_fiber.apply_bc_rectangular(
             A, RHS, x, xs, xss, dt, eta, s, mats, mc, pp, v_on_fiber=v, f_on_fiber=fe)
         # inactive slots solve the identity so the LU stays well-posed
+        # (and, where the inverse is stored, invert to it exactly)
         eye = jnp.eye(A_bc.shape[0], dtype=A_bc.dtype)
         return A_bc, RHS_bc, eye
 
@@ -241,9 +250,8 @@ def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
     A_bc = jnp.where(act, A_bc, eye)
     RHS_bc = jnp.where(group.active[:, None], RHS_bc, 0.0)
 
-    A_lu = A_bc if precond_dtype is None else A_bc.astype(precond_dtype)
-    lu, piv = jax.vmap(jax.scipy.linalg.lu_factor)(A_lu)
-    return caches._replace(A_bc=A_bc, RHS=RHS_bc, lu=lu, piv=piv)
+    lu, piv, inv = block_precond.factor(A_bc, precond_dtype)
+    return caches._replace(A_bc=A_bc, RHS=RHS_bc, lu=lu, piv=piv, inv=inv)
 
 
 def weighted_forces(group: FiberGroup, forces) -> jnp.ndarray:
@@ -524,14 +532,14 @@ def matvec(group: FiberGroup, caches: FiberCaches, x_all, v_fib, v_boundary) -> 
 
 @jax.named_scope("fiber")
 def apply_preconditioner(group: FiberGroup, caches: FiberCaches, x_all) -> jnp.ndarray:
-    """Batched LU solves, [nf, 4n] (`apply_preconditioner`, `:331-339`).
+    """Every fiber's block A_bc^-1 applied, [nf, 4n] (`apply_preconditioner`,
+    `:331-339`): one batched matmul with the stored inverse in the mixed
+    tier, batched LU solves in the full tier (`ops.block_precond`).
 
-    Solves in the LU factors' (possibly lower) precision and casts back — a
-    preconditioner only needs to approximate A^-1.
+    Works in the stored block's (possibly lower) precision and casts back —
+    a preconditioner only needs to approximate A^-1.
     """
-    out = jax.vmap(lambda lu, piv, b: jax.scipy.linalg.lu_solve((lu, piv), b))(
-        caches.lu, caches.piv, x_all.astype(caches.lu.dtype))
-    return out.astype(x_all.dtype)
+    return block_precond.solve(caches, x_all)
 
 
 def step(group: FiberGroup, fiber_sol) -> FiberGroup:

@@ -10,7 +10,8 @@ One parser for every line shape the repo emits (docs/observability.md):
   `io.ensemble_io`);
 * resume markers (``{"resume": true, ...}``).
 
-The report's sections — per-span timings, compile events, faults, lane
+The report's sections — per-span timings, compile events, how the block
+preconditioner is applied, faults, lane
 occupancy, dynamic instability, solver convergence — are each omitted
 when their inputs are absent,
 so the same command serves a single-run metrics file, a trace file, an
@@ -46,6 +47,10 @@ class Summary:
         #: fused-ring fallback eligibility legs (``leg`` — budget vs
         #: platform vs missing-api, `parallel.compat._fused_fallback`)
         self.fault_legs: dict[str, int] = {}
+        #: ``block_precond`` announcements (how the step applies its block
+        #: preconditioner, once per build: `System._announce_block_precond`)
+        #: as rendered lines -> count
+        self.block_preconds: dict[str, int] = {}
         self.lane_events: dict[str, int] = {}
         self.lane_rounds: list[dict] = []
         #: admission latencies from lane admit/backfill events
@@ -124,6 +129,10 @@ class Summary:
             if rec.get("leg"):
                 leg = str(rec["leg"])
                 self.fault_legs[leg] = self.fault_legs.get(leg, 0) + 1
+        elif ev == "block_precond":
+            line = " ".join(f"{k}={rec.get(k, '?')}" for k in
+                            ("apply", "dtype", "fibers", "bodies"))
+            self.block_preconds[line] = self.block_preconds.get(line, 0) + 1
         elif ev == "flight":
             row = {k: rec.get(k) for k in rec
                    if k not in ("ev", "ts", "pid", "host")}
@@ -262,6 +271,14 @@ class Summary:
         if retraced:
             out.append("RETRACES: " + ", ".join(
                 f"{n} x{c}" for n, c in sorted(retraced.items())))
+        out.append("")
+
+    def _block_precond_section(self, out: list[str]):
+        if not self.block_preconds:
+            return
+        out.append("== block preconditioner ==")
+        out.extend(f"block_precond {line}  (builds: {n})"
+                   for line, n in sorted(self.block_preconds.items()))
         out.append("")
 
     def _fault_section(self, out: list[str]):
@@ -485,6 +502,7 @@ class Summary:
         self._span_section(out)
         self._device_phase_section(out)
         self._compile_section(out)
+        self._block_precond_section(out)
         self._fault_section(out)
         self._lane_section(out)
         self._scenario_section(out)

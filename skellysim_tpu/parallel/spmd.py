@@ -11,7 +11,8 @@ without leaving the mesh program.
 Decomposition (everything per shard, mesh size D):
 
 * fiber buckets shard along the batch axis (nf/D whole fibers per shard):
-  caches, batched LU factors, and their solves never leave the owning shard
+  caches, the block preconditioner's factors or inverses
+  (`ops.block_precond`), and their application never leave the owning shard
   — the preconditioner-locality analogue of the reference's round-robin
   fiber distribution;
 * the shell row-shards node-aligned (N/D nodes per shard): the dense
@@ -289,7 +290,8 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
 
     def prep(st, anchors=None):
         """Port of `System._prep` to the SPMD layout: all per-fiber work
-        (caches, BC/RHS assembly, LU factorization) on the owning shard;
+        (caches, BC/RHS assembly, the blocks' factorization and, in the mixed
+        tier, inversion) on the owning shard;
         explicit flows ring at resident rows, psum onto replicated rows."""
         st = system._update_plus_pinning(st)
         buckets = fiber_buckets(st.fibers)
@@ -535,10 +537,11 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
     # ----------------------------------------------------- the preconditioner
 
     def make_precond(st, caches, body_caches):
-        """Port of `System._apply_precond`: per-fiber LU solves on the
-        owning shard; shell solve = all-gather(density) + local M_inv row
-        block; the shell-first Gauss-Seidel correction rings the local
-        shell blocks at fiber rows and psums the body-row partial."""
+        """Port of `System._apply_precond`: per-fiber block solves
+        (`fc.apply_preconditioner`) on the owning shard; shell solve =
+        all-gather(density) + local M_inv row block; the shell-first
+        Gauss-Seidel correction rings the local shell blocks at fiber rows
+        and psums the body-row partial."""
         buckets = fiber_buckets(st.fibers)
         b_list = body_buckets(st.bodies)
         fib_size, shell_size, _ = system._sizes(st)
@@ -648,6 +651,8 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             for br in (body_rhs or []):
                 rhs_parts.append(br.reshape(-1))
             rhs = jnp.concatenate(rhs_parts)
+        # a shard's own blocks: the shapes are one device's
+        system._announce_block_precond(caches, body_caches)
 
         nonrep_end = fib_size + (shell_size if sharded_shell else 0)
         rdot = _make_rdot(axis, nonrep_end)

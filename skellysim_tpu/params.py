@@ -183,8 +183,9 @@ class Params:
     # MXU prefers f32/bf16):
     #   "full"  — everything in the state dtype (f64 states need a CPU or an
     #             f64-capable LU path; f32 states run anywhere)
-    #   "mixed" — f64 state/assembly/residuals, f32 Krylov loop + LU
-    #             preconditioner, iterative refinement to gmres_tol
+    #   "mixed" — f64 state/assembly/residuals, f32 Krylov loop + block
+    #             preconditioner (f32 LU, inverted once a step and applied
+    #             as a matmul: ops.block_precond), iterative refinement to gmres_tol
     #             (solver.gmres_ir); reaches the reference's 1e-10 tolerance
     #             with the hot loop at accelerator-native f32
     #   "auto"  — "mixed" exactly where it pays: f64 states on an

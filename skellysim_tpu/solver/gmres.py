@@ -544,7 +544,8 @@ def gmres_ir(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray, *,
 
       * ``matvec_lo`` / ``precond_lo`` take and return ``b.dtype`` (f64)
         vectors but may evaluate their expensive interior — the O(N^2)
-        kernel flows, the dense shell matmul, the batched LU solves — in
+        kernel flows, the dense shell matmul, the block preconditioner's
+        batched matmuls with the inverses `prep` formed — in
         f32 (see `System._apply_matvec(lo=...)`). Stiff small ops (the
         fiber 4nx4n blocks, whose rows reach ~1e7: f32 entry rounding
         injects O(1) absolute noise there) stay f64 — they are a vanishing

@@ -29,6 +29,7 @@ from ..fibers import container as fc
 from ..guard import verdict as _verdict
 from ..obs import tracer as obs_tracer
 from ..obs.compile_log import observed_jit
+from ..ops import block_precond
 from ..params import Params, REFINE_PAIR_IMPLS
 from ..periphery import periphery as peri
 from ..periphery.periphery import PeripheryShape, PeripheryState
@@ -307,6 +308,17 @@ class System:
             obs_tracer.emit("fault", kind="refine_tile_mismatch",
                             resolved="pallas_df", taken=taken)
         return taken
+
+    def _announce_block_precond(self, caches, body_caches):
+        """Trace-time (once per build, like `_announce_refine_tile`): how the
+        step applies its block preconditioner — ``inverse`` (one matmul with
+        the inverse `prep` formed; the mixed tier) or ``lu_solve`` (the full
+        tier) — read off the caches `prep` made (`ops.block_precond`), in the
+        log and as a ``block_precond`` event."""
+        fields = block_precond.describe(caches, body_caches)
+        logger.info("block_precond apply=%(apply)s dtype=%(dtype)s "
+                    "fibers=%(fibers)s bodies=%(bodies)s", fields)
+        obs_tracer.emit("block_precond", **fields)
 
     def _precision_for(self, state) -> str:
         """Resolve Params.solver_precision for one state ("full"/"mixed").
@@ -955,11 +967,12 @@ class System:
             if not rhs_parts:
                 raise ValueError("state has no implicit components to solve")
             rhs = jnp.concatenate(rhs_parts)
+        self._announce_block_precond(caches, body_caches)
 
         precision = "full" if force_full else self._precision_for(state)
         if precision == "mixed":
             # f64 state/assembly/refinement residuals; the Krylov loop's
-            # expensive interior (kernel flows, shell/body dense ops, LU
+            # expensive interior (kernel flows, shell/body dense ops, block
             # preconditioners) evaluates through f32 copies via the lo seam
             # of _apply_matvec, while stiff fiber-local ops stay f64
             lo = _cast_floats((state, caches, body_caches), jnp.float32)

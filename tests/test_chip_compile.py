@@ -166,3 +166,28 @@ def test_f64_direct_tile_compiles(one_chip):
 
     r_src, r_trg, f = _cloud(8192, 8192, (3,), jnp.float64, one_chip)
     _compile(lambda s, t, p: stokeslet_direct(s, t, p, 1.0), r_src, r_trg, f)
+
+
+@pytest.mark.parametrize("blocks,width", [(256, 256), (1, 1206)])
+def test_block_inverse_compiles(one_chip, blocks, width):
+    """The mixed tier's block preconditioner (`ops.block_precond`) at the
+    benchmark's widths — 256 fiber blocks of 256^2, the walkthrough body's
+    1,206^2: the triangular solves (XLA's `InvertDiagBlocks*` custom calls
+    on a TPU) stay where the inverse is formed, once a step; an application
+    holds none."""
+    from collections import namedtuple
+
+    from skellysim_tpu.ops import block_precond
+
+    def st(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    formed = _compile(lambda a: block_precond.factor(a, F32)[2],
+                      st((blocks, width, width), jnp.float64))
+    assert "LuDecomposition" in formed and "InvertDiagBlocks" in formed
+    stored = namedtuple("Stored", "lu piv inv")
+    applied = _compile(
+        lambda inv, x: block_precond.solve(stored(None, None, inv), x),
+        st((blocks, width, width), F32), st((blocks, width), jnp.float64))
+    assert "InvertDiagBlocks" not in applied
+    assert "triangular" not in applied.lower()

@@ -58,8 +58,9 @@ def test_mixed_coupled_solve_hits_reference_tol():
     # the preconditioner factors really are f32 (what TPU LU requires);
     # _prep returns per-bucket lists since the heterogeneous-buckets refactor
     _, caches, body_caches, _, _ = system._prep(state)
-    assert caches[0].lu.dtype == jnp.float32
-    assert body_caches[0].lu.dtype == jnp.float32
+    # (stored as the inverse formed from them once a step: ops.block_precond)
+    assert caches[0].inv.dtype == jnp.float32 and caches[0].lu is None
+    assert body_caches[0].inv.dtype == jnp.float32
     assert caches[0].A_bc.dtype == jnp.float64  # assembly stays f64
 
     new_state, solution, info = system.step(state)
