@@ -1,7 +1,7 @@
 #!/bin/bash
 # CI gate. The reference gates every change with ctest + pytest inside a
-# GPU docker image (`/root/reference/ci/Jenkinsfile:1-37`, `ci/Dockerfile`);
-# this script is the equivalent in-repo entry point (VERDICT r4 #3).
+# GPU docker image (`/root/reference/ci/Jenkinsfile:1-37` and the Dockerfile
+# beside it); this script is the equivalent in-repo entry point.
 #
 # Usage: ci/run_ci.sh [fast|full|nightly]
 #   fast    — per-commit gate: byte-compile lint + the skelly-lint static
@@ -19,7 +19,7 @@ cd "$(dirname "$0")/.."
 TIER="${1:-fast}"
 
 echo "== lint: byte-compile every source file =="
-python -m compileall -q skellysim_tpu tests scripts ci bench.py __graft_entry__.py
+python -m compileall -q skellysim_tpu tests scripts ci __graft_entry__.py
 
 echo "== lint: skelly-lint static analysis (dtype/trace/sharding) =="
 # gating in EVERY tier: a dtype leak or host sync on the hot path is exactly
@@ -75,85 +75,9 @@ echo "== obs: skelly-scope cost baselines (docs/observability.md) =="
 # program is compiled and XLA's static cost/memory analyses are checked
 # against obs/baselines/*.toml — uncovered programs, stale baselines, and
 # >tol_pct drift (regression OR improvement) all fail. Deliberate changes
-# re-baseline via `obs cost --update`. (~35 s with a warm .jax_cache —
-# the compile cache is shared with bench.py; cold runs pay ~40 s more.)
+# re-baseline via `obs cost --update`. (~35 s with a warm .jax_cache;
+# cold runs pay ~40 s more.)
 python -m skellysim_tpu.obs cost --check
-
-echo "== obs: skelly-pulse bench-history regression gate =="
-# skelly-pulse: diff the archived bench rounds (benchmarks/MULTICHIP_r*)
-# on their gated ladder metrics — a coupled-solve speedup regression
-# beyond 25% on non-downscaled rounds fails CI here instead of waiting
-# for someone to eyeball two JSONs (downscaled CPU rounds warn only;
-# skelly-roofline adds the vs-BEST-round gate, so slow multi-round drift
-# that never trips an adjacent diff still fails here). Pure JSON
-# parsing, <1 s.
-python -m skellysim_tpu.obs perf --compare benchmarks/
-
-echo "== obs: checked-in campaign manifest + headline tables =="
-# skelly-roofline: the committed CAMPAIGN round must satisfy `obs
-# campaign`'s validator (provenance keys, explicit downscale bool, gate
-# verdict), and the generated headline table in docs/performance.md must
-# match what --render-headlines derives from benchmarks/ (exit 1 = stale
-# table, the config-reference pattern). Pure JSON parsing, <1 s.
-python -m skellysim_tpu.obs campaign \
-  "$(ls benchmarks/CAMPAIGN_r*.json | sort | tail -1)"
-python bench.py --render-headlines --check
-
-echo "== bench: one-group campaign smoke (skelly-roofline) =="
-# exit-code-gated end-to-end: warm-cache pre-pass (one unprofiled flight
-# child fills .jax_cache), then `bench.py --campaign` over just the
-# flight group with every artifact path redirected — must complete on
-# the CPU box with a downscale-stamped validated manifest, a roofline
-# section (CPU peaks), the perf gate on its WARN path (rc=0), and ZERO
-# cold compiles in the campaign trace (every compile event
-# persistent-cache-served after the pre-pass). ~3 min, dominated by the
-# pre-pass's one cold compile on a cold cache (seconds when warm).
-CAMP_TMP=$(mktemp -d)
-mkdir -p "$CAMP_TMP/archive"
-cp benchmarks/*.json "$CAMP_TMP/archive/"
-BENCH_FORCE_CPU=1 BENCH_BUDGET_S=130 BENCH_PROBE_S=1 \
-  BENCH_ARCHIVE_DIR="$CAMP_TMP/warm" \
-  BENCH_TRACE_PATH="$CAMP_TMP/warm_trace.jsonl" \
-  python bench.py --group flight --out "$CAMP_TMP/warm_flight.json" \
-  || { echo "campaign warm-cache pre-pass failed" >&2; rm -rf "$CAMP_TMP"; exit 1; }
-BENCH_FORCE_CPU=1 BENCH_BUDGET_S=170 BENCH_PROBE_S=1 \
-  BENCH_ARCHIVE_DIR="$CAMP_TMP/archive" \
-  BENCH_JSON_PATH="$CAMP_TMP/BENCH.json" \
-  BENCH_MULTICHIP_PATH="$CAMP_TMP/MULTICHIP.json" \
-  BENCH_TREECODE_PATH="$CAMP_TMP/TREECODE.json" \
-  BENCH_TRACE_PATH="$CAMP_TMP/trace.jsonl" \
-  BENCH_PROFILE_ROOT="$CAMP_TMP/prof" \
-  python bench.py --campaign --campaign-groups flight \
-    > "$CAMP_TMP/line.json" \
-  || { echo "campaign smoke failed" >&2; rm -rf "$CAMP_TMP"; exit 1; }
-python - "$CAMP_TMP" <<'EOF'
-import glob, json, sys
-
-tmp = sys.argv[1]
-line = json.load(open(tmp + "/line.json"))
-camp = line.get("campaign") or {}
-assert camp.get("gate_rc") == 0, f"downscaled campaign must WARN, not fail: {camp}"
-manifest_path = sorted(glob.glob(tmp + "/archive/CAMPAIGN_r*.json"))[-1]
-doc = json.load(open(manifest_path))
-from skellysim_tpu.obs.perf import validate_campaign
-errs = validate_campaign(doc)
-assert not errs, errs
-assert doc["downscaled"] is True, "CPU smoke must be downscale-stamped"
-assert doc["groups"]["flight"]["status"] == "ok", doc["groups"]["flight"]
-roof = doc["rooflines"].get("flight") or {}
-assert roof.get("phases"), f"campaign must carry a roofline section: {roof}"
-# zero cold compiles: after the warm-cache pre-pass every compile event
-# in the campaign trace must be served from the persistent cache
-compiles = [json.loads(ln) for ln in open(tmp + "/trace.jsonl")
-            if '"compile"' in ln]
-compiles = [e for e in compiles if e.get("ev") == "compile"]
-cold = [e for e in compiles if not e.get("persistent_cache")]
-assert not cold, f"{len(cold)}/{len(compiles)} COLD compiles in the campaign"
-print(f"campaign smoke ok: manifest {manifest_path.rsplit('/', 1)[-1]} "
-      f"valid, {roof.get('classified_frac')} classified, "
-      f"{len(compiles)} cache-served compile(s), gate rc=0")
-EOF
-rm -rf "$CAMP_TMP"
 
 echo "== obs: skelly-scope telemetry smoke (2-step run -> summarize + timeline) =="
 # a real System.run with metrics+trace streams, rendered through the CLI:

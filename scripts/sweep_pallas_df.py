@@ -71,9 +71,19 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    import bench  # shared timing helper (host-fetch barrier, see bench._rate)
     import chip_smoke  # the NumPy f64 oracles of the on-chip accuracy gate
     from skellysim_tpu.ops import df_kernels, pallas_df
+
+    def rate_of(call, n_pairs, trials):
+        """pairs/s of a nullary kernel call, warm. The clock stops after a
+        host fetch of the last output: executions on one device stream are
+        ordered, so the fetch waits for every queued trial."""
+        np.asarray(call())
+        t0 = time.perf_counter()
+        for _ in range(trials):
+            out = call()
+        np.asarray(out)
+        return n_pairs * trials / (time.perf_counter() - t0)
 
     def say(**row):
         line = json.dumps(row)
@@ -117,8 +127,7 @@ def main():
                     t0 = time.perf_counter()
                     got = np.asarray(call())  # compiles
                     row["compile_s"] = round(time.perf_counter() - t0, 2)
-                    rate = bench._rate(call, n_trg * n_src,
-                                       trials=args.trials)
+                    rate = rate_of(call, n_trg * n_src, args.trials)
                     row["gpairs_per_s"] = round(rate / 1e9, 4)
                     row["ms_per_call"] = round(n_trg * n_src / rate * 1e3, 3)
                     row["rel_err"] = float(np.linalg.norm(got[sub] - ref)

@@ -537,7 +537,7 @@ class RuntimeConfig:
     """
 
     #: persistent XLA compilation cache: "auto" (default) = the package
-    #: root's `.jax_cache` (shared with bench.py and the obs cost gate),
+    #: root's `.jax_cache` (shared with the obs cost gate),
     #: "off" = disabled, anything else = an explicit directory. CLIs also
     #: take --jax-cache DIR / --no-jax-cache, which override this key.
     jax_cache: str = "auto"

@@ -3,7 +3,7 @@
 Four legs over one JSONL event format:
 
 * `obs.tracer` — nestable spans + arbitrary events; the run loop, the
-  ensemble scheduler, and bench.py all emit through the process-wide
+  ensemble scheduler and the serve loop all emit through the process-wide
   active tracer (`tracer.use` / `tracer.span` / `tracer.emit`);
 * `obs.compile_log` — `observed_jit`, a `jax.jit` twin that reports every
   fresh trace/compile as an event (System/ensemble/SPMD jits route
@@ -16,10 +16,7 @@ Four legs over one JSONL event format:
 Import-light on purpose: the obs modules themselves import jax only
 lazily (span annotations, compile observation, the cost gate), and `summarize`
 never initializes a jax backend. NOTE the *package* import still runs
-`skellysim_tpu/__init__.py`, which imports jax at module level — that is
-why bench.py's jax-avoiding parent process pins its own
-`TELEMETRY_VERSION` literal instead of importing this (tests/test_obs.py
-cross-checks the two).
+`skellysim_tpu/__init__.py`, which imports jax at module level.
 """
 
 from .tracer import TELEMETRY_VERSION, Tracer, active, emit, span, use

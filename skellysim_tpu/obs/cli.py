@@ -1,5 +1,5 @@
 """skelly-scope CLI: `python -m skellysim_tpu.obs
-<summarize|flight|cost|profile|roofline|timeline|perf|campaign>`.
+<summarize|flight|cost|profile|timeline>`.
 
 ``flight FILE [FILE...]`` renders the skelly-flight blast-radius report
 from any mix of metrics/telemetry JSONL: each faulted member's
@@ -10,7 +10,7 @@ recorder"). jax-free, torn-trailing-line tolerant.
 
 ``summarize FILE [FILE...]`` renders any mix of telemetry/metrics JSONL
 streams (run-loop metrics, `System.run(trace_path=...)` traces, ensemble
-metrics, bench traces) into per-span timings, compile events, lane
+metrics) into per-span timings, compile events, lane
 occupancy, and solver convergence stats. Pure host-side text processing —
 it never initializes a jax backend (the package import pulls the jax
 *module* in, nothing more).
@@ -22,26 +22,6 @@ op time of a ``--profile`` dump to the named_scope phase vocabulary
 ``timeline TRACE.jsonl [TRACE...] [--profile DIR] -o out.perfetto.json``
 merges telemetry spans, compile instants, and (optionally) the profiler's
 device phases into ONE Chrome-trace/Perfetto artifact (`obs.timeline`).
-
-``roofline DIR [--program P | --cost-table TOML] [--device-kind K]
-[--executions N] [--json]`` joins a profile dump's per-phase device walls
-with the program's static cost table and the audit contract's pinned
-collective bytes against the checked-in device-peak table
-(`obs/device_peaks.toml`) — achieved FLOP/s / bytes/s, arithmetic
-intensity, compute-/memory-/comms-bound verdicts, and achieved-vs-peak
-per phase (`obs.roofline`, docs/observability.md "Roofline"). Unknown
-device kinds rate as "unrated", never a crash.
-
-``perf --compare DIR [--gate PCT] [--json]`` diffs the archived bench
-rounds (``benchmarks/MULTICHIP_r*.json`` …) and exits 1 on a gated-metric
-regression on non-downscaled rounds (`obs.perf`) — the CI bench-history
-gate. Renders the full trajectory with a best-round row and gates the
-latest round against BOTH its predecessor and the best round per metric.
-
-``campaign FILE [--json]`` validates + renders a ``CAMPAIGN_rNN.json``
-manifest (`bench.py --campaign`): per-group statuses, roofline summaries,
-gate verdicts. Exit 2 on a structurally-invalid manifest, 1 when the
-recorded gate failed, 0 otherwise — the CI campaign smoke's gate.
 
 ``cost`` measures every registered auditable program's XLA cost/memory
 analysis and (``--check``) gates it against `obs/baselines/*.toml` — exit
@@ -68,7 +48,7 @@ def _bootstrap_backend():
 
     jax.config.update("jax_enable_x64", True)
     # persistent compile cache — the ONE implementation + min-compile-time
-    # threshold in utils.bootstrap, shared with bench.py and every CLI: the
+    # threshold in utils.bootstrap, shared with every CLI: the
     # cost gate compiles every registered program, and warm CI re-runs skip
     # the XLA compile seconds — tracing/lowering (which the measurements
     # come from) is unaffected, and cost/memory analyses read the same
@@ -162,55 +142,6 @@ def _cmd_profile(args) -> int:
     return 0
 
 
-def _cmd_roofline(args) -> int:
-    import json as json_mod
-
-    from . import roofline as roofline_mod
-
-    try:
-        doc = roofline_mod.roofline_report(
-            args.dir, program=args.program, cost_table=args.cost_table,
-            device_kind=args.device_kind, executions=args.executions,
-            n_devices=args.n_devices)
-    except (FileNotFoundError, KeyError) as e:
-        msg = e.args[0] if e.args else e
-        print(f"skelly-roofline: {msg}", file=sys.stderr)
-        return 2
-    if args.json:
-        print(json_mod.dumps(doc))
-    else:
-        print(roofline_mod.render_roofline(doc), end="")
-    return 0
-
-
-def _cmd_campaign(args) -> int:
-    import json as json_mod
-    import os
-
-    from . import perf as perf_mod
-
-    if not os.path.exists(args.file):
-        print(f"skelly-roofline: no such manifest: {args.file}",
-              file=sys.stderr)
-        return 2
-    try:
-        doc = perf_mod.load_campaign(args.file)
-    except Exception as e:
-        print(f"skelly-roofline: unreadable manifest: {e}", file=sys.stderr)
-        return 2
-    errors = perf_mod.validate_campaign(doc)
-    if errors:
-        for err in errors:
-            print(f"skelly-roofline: invalid manifest: {err}",
-                  file=sys.stderr)
-        return 2
-    if args.json:
-        print(json_mod.dumps(doc))
-    else:
-        print(perf_mod.render_campaign(doc), end="")
-    return 1 if (doc.get("gate") or {}).get("rc") == 1 else 0
-
-
 def _cmd_timeline(args) -> int:
     import os
 
@@ -234,39 +165,13 @@ def _cmd_timeline(args) -> int:
     return 0
 
 
-def _cmd_perf(args) -> int:
-    import json as json_mod
-
-    from . import perf as perf_mod
-
-    if not args.compare:
-        print("skelly-pulse: perf needs --compare DIR", file=sys.stderr)
-        return 2
-    try:
-        if args.json:
-            # exit-code contract shared with the text path via report_json
-            # (2 = no rounds, 1 = gated regression) — a --json CI wiring
-            # must fail exactly when the text gate would
-            doc, rc = perf_mod.report_json(args.compare,
-                                           gate_pct=args.gate)
-            print(json_mod.dumps(doc, indent=1))
-            return rc
-        report, rc = perf_mod.render_report(args.compare,
-                                            gate_pct=args.gate)
-    except FileNotFoundError as e:
-        print(f"skelly-pulse: {e}", file=sys.stderr)
-        return 2
-    print(report, end="")
-    return rc
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m skellysim_tpu.obs",
         description="skelly-scope: runtime telemetry — span/compile event "
                     "summaries, the program cost gate, device-time "
-                    "attribution, merged timelines, and the bench-history "
-                    "gate (docs/observability.md).")
+                    "attribution and merged timelines "
+                    "(docs/observability.md).")
     sub = parser.add_subparsers(dest="cmd")
 
     p_sum = sub.add_parser(
@@ -292,40 +197,6 @@ def main(argv=None) -> int:
     p_prof.add_argument("--json", action="store_true",
                         help="machine-readable report (all groupings)")
 
-    p_roof = sub.add_parser(
-        "roofline", help="per-phase roofline attribution over a --profile "
-                         "dump: achieved vs peak, AI, bound verdicts "
-                         "(docs/observability.md \"Roofline\")")
-    p_roof.add_argument("dir", metavar="DIR",
-                        help="jax.profiler.trace dump directory")
-    p_roof.add_argument("--program", default=None, metavar="NAME",
-                        help="registered program whose cost baseline "
-                             "(obs/baselines/) + audit contract size the "
-                             "flops/bytes and collective traffic")
-    p_roof.add_argument("--cost-table", default=None, metavar="TOML",
-                        help="standalone cost-table sidecar ([cost] + "
-                             "[collectives.*] max_bytes) overriding "
-                             "--program")
-    p_roof.add_argument("--device-kind", default=None, metavar="KIND",
-                        help="device kind to rate against (default: the "
-                             "dump's provenance.json sidecar; unknown "
-                             "kinds rate as 'unrated')")
-    p_roof.add_argument("--executions", type=int, default=1, metavar="N",
-                        help="timed program executions inside the "
-                             "profiling window (default 1)")
-    p_roof.add_argument("--n-devices", type=int, default=None, metavar="D",
-                        help="device lanes in the window (default: "
-                             "distinct trace pids)")
-    p_roof.add_argument("--json", action="store_true")
-
-    p_camp = sub.add_parser(
-        "campaign", help="validate + render a bench.py --campaign "
-                         "manifest (CAMPAIGN_rNN.json); exit 2 invalid, "
-                         "1 when the recorded gate failed")
-    p_camp.add_argument("file", metavar="FILE",
-                        help="path to a CAMPAIGN_rNN.json manifest")
-    p_camp.add_argument("--json", action="store_true")
-
     p_tl = sub.add_parser(
         "timeline", help="merge telemetry JSONL (+ profiler dump) into one "
                          "perfetto/chrome-trace JSON")
@@ -334,17 +205,6 @@ def main(argv=None) -> int:
                       help="profiler dump dir for the device-phase track")
     p_tl.add_argument("-o", "--out", required=True,
                       help="output path (e.g. out.perfetto.json)")
-
-    p_perf = sub.add_parser(
-        "perf", help="bench-history regression gate over archived "
-                     "<GROUP>_rNN.json rounds")
-    p_perf.add_argument("--compare", metavar="DIR",
-                        help="bench artifact directory (benchmarks/)")
-    p_perf.add_argument("--gate", type=float, default=25.0, metavar="PCT",
-                        help="regression tolerance percent on gated "
-                             "metrics (default 25; downscaled rounds "
-                             "warn instead of failing)")
-    p_perf.add_argument("--json", action="store_true")
 
     p_cost = sub.add_parser(
         "cost", help="measure every auditable program's XLA cost/memory "
@@ -369,14 +229,8 @@ def main(argv=None) -> int:
         return _cmd_flight(args)
     if args.cmd == "profile":
         return _cmd_profile(args)
-    if args.cmd == "roofline":
-        return _cmd_roofline(args)
-    if args.cmd == "campaign":
-        return _cmd_campaign(args)
     if args.cmd == "timeline":
         return _cmd_timeline(args)
-    if args.cmd == "perf":
-        return _cmd_perf(args)
     if args.cmd == "cost":
         if args.check and args.update:
             print("skelly-scope: --check and --update are mutually "

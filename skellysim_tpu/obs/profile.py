@@ -40,7 +40,7 @@ wire-format reader over the handful of field numbers involved. Unknown
 fields are skipped by wire type, so schema growth degrades to missing
 metadata (reported as unattributed time), never a crash.
 
-jax-free on purpose (gzip/json/re only): `obs profile` and `obs timeline`
+jax-free on purpose (gzip/re only): `obs profile` and `obs timeline`
 parse dumps without paying JAX backend init, like `obs summarize`.
 """
 
@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import contextlib
 import gzip
-import json
 import os
 import re
 from typing import Optional
@@ -887,25 +886,6 @@ def profile_json(trace: DeviceTrace) -> dict:
 
 # ---------------------------------------------------------- capture context
 
-def _write_profile_provenance(profile_dir: str) -> None:
-    """Drop a ``provenance.json`` sidecar next to the dump so jax-free
-    consumers (`obs roofline`) know WHICH device the capture ran on —
-    jax is live inside `profile_session`, so this is the one moment the
-    device_kind is knowable without a backend init later."""
-    try:
-        import jax
-
-        from .tracer import provenance
-
-        info = provenance()
-        info["backend"] = jax.default_backend()
-        os.makedirs(profile_dir, exist_ok=True)
-        with open(os.path.join(profile_dir, "provenance.json"), "w") as fh:
-            json.dump(info, fh)
-    except Exception:
-        pass   # a sidecar must never fail the capture it describes
-
-
 def include_scopes_in_cache_key(on: bool = True) -> bool:
     """Make `jax.named_scope` paths part of the compile-cache key; returns
     what the setting was. JAX leaves locations out of the key, so a
@@ -971,7 +951,6 @@ def profile_session(profile_dir: str):
             yield
     finally:
         include_scopes_in_cache_key(was)
-        _write_profile_provenance(str(profile_dir))
 
 
 # ------------------------------------------------- telemetry-stream bridge

@@ -4,7 +4,7 @@ One parser for every line shape the repo emits (docs/observability.md):
 
 * tracer events (``ev`` key): ``span`` / ``compile`` / ``lane`` /
   ``telemetry`` headers — from `obs.tracer` (run loop, ensemble scheduler,
-  bench groups);
+  serve loop);
 * run-loop step records (`system.METRICS_FIELDS` — no ``ev``/``event``
   key) and ensemble metrics records (``event`` = start/step/retire/...,
   `io.ensemble_io`);
@@ -435,7 +435,7 @@ class Summary:
         # dot-product psum rounds per solve (`solver.gmres.collective_rounds`
         # — iters/block_s batched Gram rounds + per-cycle residual norms):
         # the s-step ladder lever, surfaced here so a collective-count
-        # regression shows up in telemetry, not just in bench reruns
+        # regression shows up in telemetry, not just in reruns on the chip
         rounds = [int(s["collective_rounds"]) for s in self.steps
                   if "collective_rounds" in s]
         if rounds:

@@ -4,20 +4,16 @@ skelly-scope's first leg (docs/observability.md). The reference instruments
 its hot path with spdlog scope markers and one wall-clock timer around each
 GMRES solve (`solver_hydro.cpp:81-91`); this module replaces that with a
 structured event stream every surface shares: `System.run` / `_run_loop`,
-the ensemble scheduler, and `bench.py` all emit through the SAME tracer, so
-`python -m skellysim_tpu.obs summarize` renders run metrics, ensemble lane
-churn, and bench group timings from one format.
+the ensemble scheduler and the serve loop all emit through the SAME tracer,
+so `python -m skellysim_tpu.obs summarize` renders run metrics and ensemble
+lane churn from one format.
 
 Design constraints:
 
 * **Import-light.** This module imports jax only lazily (the first
   `span` asks `jax.profiler` for `TraceAnnotation`). Reaching it through
   the package still runs `skellysim_tpu/__init__.py`'s module-level
-  `import jax` — which is why `bench.py`'s parent process (which must
-  never import jax: a parent that has touched jax holds the chip its
-  children need) pins its own `TELEMETRY_VERSION` literal instead of
-  importing this module; only the bench *children* (which import jax
-  anyway) construct tracers.
+  `import jax`; importing jax initialises no backend.
 * **Near-free when inactive.** `emit()` consults the active tracer once and
   no-ops without one. `span()` always enters a
   `jax.profiler.TraceAnnotation("skelly/<path>")` — a whole span costs
@@ -54,24 +50,19 @@ import socket
 import time
 from typing import Optional
 
-#: version stamp of the event schema AND the bench artifact format
-#: (bench.py pins its own copy — it cannot import this module in the
-#: jax-free parent process; tests/test_obs.py asserts the two agree)
+#: version stamp of the event schema
 TELEMETRY_VERSION = 1
 
 
-def provenance(downscaled=None) -> dict:
-    """The self-description stamp timelines and bench artifacts share:
-    ``jax_version`` + ``device_kind`` (+ ``downscaled`` when the caller
-    states it) — skelly-pulse's answer to "which hardware/runtime
-    produced these numbers?" (bench artifacts used to hand-stamp
-    ``telemetry_version`` only).
+def provenance() -> dict:
+    """The self-description stamp of a telemetry header: ``jax_version`` +
+    ``device_kind`` — "which hardware/runtime produced these numbers?".
 
     jax-free-safe: consults ``sys.modules`` instead of importing — a
-    process that never imported jax (bench's parent) gets ``None``
-    placeholders rather than a backend init, and the tracer header stays
-    zero-cost in jax-free contexts. In a process whose backend is live
-    (every CLI/run/bench child), ``jax.devices()`` is already cached.
+    process that never imported jax gets ``None`` placeholders rather than
+    a backend init, and the tracer header stays zero-cost in jax-free
+    contexts. In a process whose backend is live (every CLI run),
+    ``jax.devices()`` is already cached.
     """
     import sys
 
@@ -86,8 +77,6 @@ def provenance(downscaled=None) -> dict:
         except Exception:
             kind = None
     info["device_kind"] = kind
-    if downscaled is not None:
-        info["downscaled"] = bool(downscaled)
     return info
 
 

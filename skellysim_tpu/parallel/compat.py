@@ -18,11 +18,6 @@ from jax import lax
 
 logger = logging.getLogger("skellysim_tpu")
 
-#: `bench.py` still imports the name from here (its rewrite is ROADMAP
-#: Queue 1 item 2); package code calls `jax.shard_map` itself
-shard_map = jax.shard_map
-
-
 def pmax(x, axis_name):
     """`lax.pmax` the chip's compiler accepts at 64 bits. It lowers a
     64-bit all-reduce for sums only ("Supported lowering only of Sum all

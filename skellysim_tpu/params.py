@@ -62,11 +62,11 @@ class Params:
     # block_s`): each Arnoldi round generates s preconditioned Krylov
     # candidates and orthogonalizes them in TWO batched Gram reductions
     # instead of 3 per iteration — under `step_spmd` that is 2 psum rounds
-    # per s iterations instead of 3s, the lever that flips the multi-chip
-    # coupled-solve ladder positive (docs/parallel.md). 1 = the sequential
+    # per s iterations instead of 3s (docs/parallel.md). 1 = the sequential
     # cycle, BITWISE identical to the pre-s-step solver (parity-pinned);
-    # 4 is the measured sweet spot on the bench scenes — larger s trades
-    # monomial-basis conditioning (f32 Krylov interior) for fewer rounds.
+    # larger s trades monomial-basis conditioning (f32 Krylov interior) for
+    # fewer rounds. No value has a chip reading: the benchmark's mesh cell
+    # runs 1.
     gmres_block_s: int = 1
     # skelly-scope convergence history: ring-buffer capacity (rows) of
     # per-restart (iters, implicit, explicit) residuals carried device-side
@@ -173,8 +173,9 @@ class Params:
     # near-field cancellation caveat — for well-separated fiber clouds),
     # "df" (double-float f32, the f64-grade accuracy tier), "pallas"
     # (fused VMEM-tile kernels, `ops.pallas_kernels` — the f32 throughput
-    # tier at scale: 53/48 Gpairs/s stokeslet/stresslet on v5e, 3.4x/8x the
-    # XLA path; f64 operands fall back to "exact"; interpret mode off-TPU),
+    # tier at scale: the Stokeslet tile takes 3.171 ms for 16,384^2 pairs
+    # on a v5e, 84.7 Gpairs/s (ledger, PR 29); f64 operands fall back to
+    # "exact"; interpret mode off-TPU),
     # or "pallas_df" (the DF arithmetic fused into Pallas tiles,
     # `ops.pallas_df` — f64-grade accuracy at VMEM-tile throughput)
     kernel_impl: str = "exact"
@@ -202,14 +203,15 @@ class Params:
     # inner (f32) GMRES tolerance per refinement sweep in "mixed" mode;
     # each sweep contracts the error by about this factor. The trade is
     # sweeps (one expensive high-precision residual matvec each) against
-    # inner iterations (cheap f32). Measured on the walkthrough scene
-    # (scripts/mixed_tune.py, r5): 1e-5 reaches 1e-10 in ~9 total inner
-    # iterations at ~2 sweeps vs 1e-4's ~12 iterations at 3 sweeps — fewer
-    # of BOTH costs; 1e-6 flips back to ~13 iterations. Hence 1e-5.
+    # inner iterations (cheap f32). On the chip the walkthrough stands on
+    # the edge between two and three sweeps at 1e-5: since PR 29 the third
+    # sweep is the rule (2.76 a step, each ~0.23 s of a 0.97 s step;
+    # PERF.md section 6, PR 29), so this stopping rule is that cell's next
+    # lever. No other value has a chip reading.
     inner_tol: float = 1e-5
     # pairwise-kernel tile for the f64 refinement residual (and prep flows)
-    # in "mixed" mode: "exact" = native f64 (fast on CPU, ~100x slower than
-    # f32 on TPUs, whose f64 is software-emulated), "df" = double-float f32
+    # in "mixed" mode: "exact" = native f64 (fast on CPU; software-emulated,
+    # and far slower than f32, on TPUs), "df" = double-float f32
     # in XLA blocks (`ops.df_kernels`, ~1e-14 relative — far beyond
     # gmres_tol needs), "pallas_df" = the same double-float arithmetic fused
     # into Pallas VMEM tiles walked in register-sized strips
@@ -240,7 +242,8 @@ class Params:
     # flow through its evaluator only when its SOURCE count reaches this
     # bound; below it the dense tile is strictly cheaper than an extra
     # FFT-grid / tree-traversal pass (a 400-node body against 640k targets
-    # is ~0.26 Gpairs — tens of ms dense, vs a full M^3 grid round-trip).
+    # is ~0.26 Gpairs, a single dense tile pass, vs a full M^3 grid
+    # round-trip).
     # Host-side static dispatch, mirroring how the reference only pays FMM
     # setup for point sets that warrant it; set to 0 to force every flow
     # through the fast evaluator (parity tests)

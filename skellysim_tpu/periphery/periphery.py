@@ -141,9 +141,8 @@ def build_shell_operator_device(nodes, normals, weights, eta: float = 1.0, *,
     operator's accuracy caps the mixed solver's achievable residual);
     ``inv_dtype`` defaults to float32 because the inverse is only ever a
     preconditioner AND TPU LuDecomposition is f32-only. Returns DEVICE
-    arrays (callers that persist to npz convert; callers that keep solving —
-    bench's scene builder — skip a pointless device->host->device round
-    trip).
+    arrays (callers that persist to npz convert; callers that keep solving
+    skip a pointless device->host->device round trip).
     """
     import jax
 

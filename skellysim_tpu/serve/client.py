@@ -7,8 +7,7 @@ subprocess for scripts/CI: it waits for the `--port-file` publish, hands
 out connected clients, and guarantees teardown.
 
 jax-free on purpose: a client drives a remote simulation service without
-paying JAX backend init (the same discipline as `bench.py`'s parent
-process).
+paying JAX backend init.
 """
 
 from __future__ import annotations

@@ -249,7 +249,7 @@ class System:
                 f"unknown precond {params.precond!r}; use 'gs' or 'jacobi'")
         # all entry-point jits route through `obs.compile_log.observed_jit`
         # (a `jax.jit` twin): with a tracer active (System.run(trace_path=),
-        # the ensemble/bench paths) every fresh trace/compile lands in the
+        # the ensemble paths) every fresh trace/compile lands in the
         # telemetry stream as a `compile` event; without one the wrapper is
         # a counter bump per call. `.trace()` passes through, so the audit
         # registry's `built_from` keeps consuming these directly.

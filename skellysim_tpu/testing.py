@@ -1,9 +1,9 @@
-"""Shared simulation fixtures for tests, benchmarks, and the driver dry-run.
+"""Shared simulation fixtures for tests and the driver dry-run.
 
 Counterpart of the reference's pytest helpers (`src/skelly_sim/testing.py:18-33`),
 adapted to the in-memory build path: one place that assembles the standard
 coupled scene (spherical periphery + one externally forced rigid body) so the
-dry-run, the ring-vs-direct tests, and the bench all measure the *same* system.
+dry-run and the ring-vs-direct tests all measure the *same* system.
 
 The shell uses uniform quadrature weights (4*pi*R^2/N on Fibonacci nodes)
 rather than the production Reeger-Fornberg weights — fixture-grade accuracy,

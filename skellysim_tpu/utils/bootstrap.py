@@ -73,8 +73,8 @@ def force_cpu_devices(n_devices: int | None = None) -> None:
                 "the environment.")
 
 
-#: one min-compile-time threshold for every cache consumer (CLIs, bench.py,
-#: the obs cost gate): trivial programs stay out of the persistent cache
+#: one min-compile-time threshold for every cache consumer (CLIs, the obs
+#: cost gate): trivial programs stay out of the persistent cache
 CACHE_MIN_COMPILE_S = 1.0
 
 
@@ -85,8 +85,8 @@ CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 def default_cache_dir() -> str:
     """``<checkout>/.jax_cache`` — the ONE default location shared by every
-    CLI, `bench.py`, and the obs cost gate, so a cold server start reuses
-    the executables a CI run or bench already compiled. A fixed path: the
+    CLI and the obs cost gate, so a cold server start reuses the
+    executables a CI run already compiled. A fixed path: the
     directory is part of the cache key, so one that moves never hits."""
     pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     return os.path.join(os.path.dirname(pkg), ".jax_cache")
@@ -96,7 +96,7 @@ def enable_compilation_cache(cache_dir: str | None = "auto") -> str | None:
     """Point JAX's persistent XLA compilation cache at ``cache_dir``.
 
     The one implementation behind every CLI's cache wiring (run, ensemble,
-    serve, listener, the obs cost gate, bench.py): compiled executables
+    serve, listener, the obs cost gate): compiled executables
     persist across processes, so a cold server start (or CI re-run) whose
     programs were compiled before skips the multi-minute XLA compiles and
     goes straight to warm admission. The persistent cache is DEFAULT-ON

@@ -4,7 +4,7 @@ Round-2 regression: the NumPy-f64 `FibMats` constants promoted every downstream
 op to f64 (`fd_fiber.py`), so a float32 `SimState` produced float64 `A_bc`/LU —
 and TPU XLA's `LuDecomposition` is f32-only, killing the on-device solve
 (BENCH_r02 tail). The suite runs with x64 enabled (conftest), exactly the
-configuration bench.py uses on the TPU, so these assertions catch any new
+configuration every run uses on the TPU, so these assertions catch any new
 f64 constant closed over f32 jit code.
 """
 

@@ -506,22 +506,6 @@ def test_scheduler_lane_events_and_no_backfill_retrace(tmp_path):
     assert "admit=2" in report and "backfill=2" in report
 
 
-# ------------------------------------------------------------- bench format
-
-def test_bench_telemetry_version_pinned():
-    """bench.py's jax-free parent pins its own TELEMETRY_VERSION literal;
-    it must track obs.tracer's (the one-format contract)."""
-    import importlib.util
-    import os
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_version_pin", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench.TELEMETRY_VERSION == TELEMETRY_VERSION
-
-
 def test_summarize_tolerates_mixed_and_garbage_lines(tmp_path):
     from skellysim_tpu.obs.summarize import summarize_files
 
@@ -841,103 +825,6 @@ def test_log_histogram_edges_and_wire():
         LogHistogram(lo=1.0, hi=0.5)
 
 
-# ------------------------------------------------ skelly-pulse: perf gate
-
-def _write_round(dirpath, group, number, doc):
-    p = os.path.join(str(dirpath), f"{group}_r{number:02d}.json")
-    with open(p, "w") as fh:
-        json.dump(doc, fh)
-    return p
-
-
-def test_perf_compare_gate_on_synthetic_rounds(tmp_path):
-    from skellysim_tpu.obs.perf import render_report
-
-    _write_round(tmp_path, "GROUPX", 1,
-                 {"solve": {"d8": {"speedup_vs_1dev": 2.0}},
-                  "rate": {"gpairs_per_s": 1.0}})
-    _write_round(tmp_path, "GROUPX", 2,
-                 {"solve": {"d8": {"speedup_vs_1dev": 1.0}},
-                  "rate": {"gpairs_per_s": 1.05}})
-    report, rc = render_report(str(tmp_path), gate_pct=25.0)
-    assert rc == 1
-    assert "REGRESSION" in report and "-50.0%" in report
-    # within the gate: passes
-    report, rc = render_report(str(tmp_path), gate_pct=60.0)
-    assert rc == 0 and "within gate" in report
-
-
-def test_perf_compare_downscaled_rounds_warn_only(tmp_path):
-    from skellysim_tpu.obs.perf import render_report
-
-    _write_round(tmp_path, "TOY", 1, {"m": {"speedup_vs_1dev": 4.0}})
-    _write_round(tmp_path, "TOY", 2, {"m": {"speedup_vs_1dev": 1.0},
-                                      "downscaled": True})
-    report, rc = render_report(str(tmp_path), gate_pct=25.0)
-    assert rc == 0
-    assert "WARN (downscaled" in report
-
-
-def test_perf_compare_skips_unparseable_rounds(tmp_path):
-    """The r01-r05 failure shells ({"rc": 124}) render as incomplete and
-    the diff picks the latest two PARSEABLE rounds."""
-    from skellysim_tpu.obs.perf import render_report, scan_rounds
-
-    _write_round(tmp_path, "G", 1, {"rc": 124, "ok": False})
-    _write_round(tmp_path, "G", 2, {"m": {"speedup_vs_1dev": 1.0}})
-    _write_round(tmp_path, "G", 3, {"m": {"speedup_vs_1dev": 2.0}})
-    rounds = scan_rounds(str(tmp_path))["g"]
-    assert [r.parseable for r in rounds] == [False, True, True]
-    report, rc = render_report(str(tmp_path), gate_pct=25.0)
-    assert rc == 0
-    assert "incomplete" in report
-    assert "diff r02 -> r03" in report
-    # a single parseable round: trajectory only, nothing to diff
-    two = tmp_path / "single"
-    two.mkdir()
-    _write_round(two, "G", 1, {"m": {"speedup_vs_1dev": 1.0}})
-    report, rc = render_report(str(two), gate_pct=25.0)
-    assert rc == 0 and "nothing to diff" in report
-
-
-def test_perf_real_benchmarks_trajectory():
-    """Acceptance pin: `obs perf --compare benchmarks/` renders the
-    r02..r08 multichip trajectory (r01, a record of a backend that is
-    gone, was deleted in PR 22) and the gate passes on the checked-in
-    (downscaled) rounds."""
-    from skellysim_tpu.obs.perf import render_report
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    report, rc = render_report(os.path.join(repo, "benchmarks"))
-    assert rc == 0
-    assert "== multichip trajectory (7 round(s)) ==" in report
-    for label in ("r02", "r07", "r08"):
-        assert label in report
-    assert "diff r07 -> r08" in report
-    assert "coupled_spmd.d8.speedup_vs_1dev: 0.44 -> 0.63" in report
-    # the vs-best column engages on the full history (r06 still holds the
-    # matvec.d4 best on the oversubscribed virtual mesh)
-    assert "best 3@r06" in report
-
-
-def test_perf_cli_exit_codes(tmp_path, capsys):
-    from skellysim_tpu.obs.cli import main
-
-    _write_round(tmp_path, "G", 1, {"m": {"speedup_vs_1dev": 2.0}})
-    _write_round(tmp_path, "G", 2, {"m": {"speedup_vs_1dev": 1.0}})
-    assert main(["perf", "--compare", str(tmp_path)]) == 1
-    assert main(["perf", "--compare", str(tmp_path), "--gate", "60"]) == 0
-    capsys.readouterr()  # drain the text reports before the JSON one
-    assert main(["perf", "--compare", str(tmp_path), "--json"]) == 1
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["groups"]["g"]["diff"]["metrics"][0]["regressed"]
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    assert main(["perf", "--compare", str(empty)]) == 2
-    assert main(["perf", "--compare", str(tmp_path / "nope")]) == 2
-    assert main(["perf"]) == 2
-
-
 # ------------------------------- skelly-pulse: provenance + summarize extras
 
 def test_tracer_header_carries_provenance():
@@ -950,8 +837,7 @@ def test_tracer_header_carries_provenance():
     assert header["ev"] == "telemetry"
     assert header["jax_version"] == jax.__version__
     assert header["device_kind"]  # "cpu" on the test platform
-    assert provenance(downscaled=True)["downscaled"] is True
-    assert "downscaled" not in provenance()
+    assert provenance()["device_kind"] == header["device_kind"]
 
 
 def test_summarize_multifile_source_columns(tmp_path):
