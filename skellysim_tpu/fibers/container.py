@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import block_precond, kernels
+from ..ops import block_df, block_precond, kernels
 from . import fd_fiber
 from .fd_fiber import FiberScalars
 from .matrices import FibMats, get_mats, padded_rt_mats, typed
@@ -83,6 +83,20 @@ class FiberGroup(NamedTuple):
                             self.radius, self.penalty, self.beta_tstep, self.v_growth)
 
 
+class FiberDFWords(NamedTuple):
+    """The dense matrices of `matvec` / `apply_fiber_force` as (hi, lo)
+    float32 word pairs (`ops.block_df.split_words`), for the double-float
+    tile: the per-fiber blocks split once a step where `update_rhs_and_bc`
+    forms them, the resolution's shared matrices once a build (static
+    constants split on the host at trace time; runtime node-capacity mats
+    are data and split with the blocks)."""
+
+    A_bc: tuple       # 2 x [nf, 4n, 4n -> lanes]
+    force_op: tuple   # 2 x [nf, 3n, 4n -> lanes]
+    P_down: tuple     # 2 x [4n-14 -> 8s, 4n -> lanes]
+    D1: tuple         # 2 x [n, n -> lanes]
+
+
 class FiberCaches(NamedTuple):
     """Per-step derived quantities (`update_cache_variables` + BC application)."""
 
@@ -105,6 +119,10 @@ class FiberCaches(NamedTuple):
     lu: jnp.ndarray | None
     piv: jnp.ndarray | None
     inv: jnp.ndarray | None
+    #: double-float words of the dense matrices, where the lo operator of
+    #: the mixed tier multiplies through the tile (`System._fiber_ops_for`);
+    #: None wherever the float64 ``dot`` serves
+    df: FiberDFWords | None = None
 
 
 def make_group(x, lengths, bending_rigidity, radius, *, eta=None,
@@ -219,7 +237,7 @@ def update_cache(group: FiberGroup, dt, eta) -> FiberCaches:
 @jax.named_scope("fiber")
 def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
                       v_on_fibers, f_total, f_ext,
-                      precond_dtype=None) -> FiberCaches:
+                      precond_dtype=None, df_words: bool = False) -> FiberCaches:
     """Assemble BC-applied A/RHS and the batched block preconditioner.
 
     Mirrors the prep sequence of `System::prep_state_for_solver`
@@ -229,6 +247,9 @@ def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
     stores each block's inverse, formed here once a step, in the factors'
     place; None keeps the LU factors in the state dtype
     (`ops.block_precond`). A/RHS stay in the state dtype either way.
+    ``df_words`` also leaves the dense matrices as double-float words
+    (`FiberDFWords`) for callers that pass ``df=True`` to `matvec` /
+    `apply_fiber_force`; the float64 blocks stay for everyone else.
     """
     mats = group.mats
     sc = group.scalars()
@@ -251,7 +272,13 @@ def update_rhs_and_bc(group: FiberGroup, caches: FiberCaches, dt, eta,
     RHS_bc = jnp.where(group.active[:, None], RHS_bc, 0.0)
 
     lu, piv, inv = block_precond.factor(A_bc, precond_dtype)
-    return caches._replace(A_bc=A_bc, RHS=RHS_bc, lu=lu, piv=piv, inv=inv)
+    df = None
+    if df_words:
+        split = block_df.split_words
+        df = FiberDFWords(A_bc=split(A_bc), force_op=split(caches.force_op),
+                          P_down=split(mats.P_down), D1=split(mats.D1))
+    return caches._replace(A_bc=A_bc, RHS=RHS_bc, lu=lu, piv=piv, inv=inv,
+                           df=df)
 
 
 def weighted_forces(group: FiberGroup, forces) -> jnp.ndarray:
@@ -511,23 +538,88 @@ def flow_multi_local(buckets, caches_list, forces_list, r_loc, r_rep, eta, *,
     return v_loc, v_rep
 
 
-@jax.named_scope("fiber")
-def apply_fiber_force(group: FiberGroup, caches: FiberCaches, x_all) -> jnp.ndarray:
-    """Solution -> force density on nodes, [nf, n, 3] (`apply_fiber_force`, `:272-287`)."""
-    f = jnp.einsum("fij,fj->fi", caches.force_op, x_all)  # [nf, 3n]
+def _df_product(words, x, n_rows):
+    """``words`` (a `FiberDFWords` field) times the rows of ``x`` through
+    the double-float tile: compiled for a TPU, interpreted on a CPU (the
+    tests' oracle runs), as every Pallas tile of the package."""
+    return block_df.block_matvec_df(
+        words, x, n_rows=n_rows, interpret=jax.default_backend() == "cpu")
+
+
+def apply_fiber_force(group: FiberGroup, caches: FiberCaches, x_all,
+                      df: bool = False) -> jnp.ndarray:
+    """Solution -> force density on nodes, [nf, n, 3] (`apply_fiber_force`, `:272-287`).
+
+    ``df=True`` (the lo operator of the mixed tier) multiplies through the
+    double-float words where ``caches`` holds them; the result is float64
+    either way."""
     n = group.n_nodes
-    return jnp.stack([f[:, :n], f[:, n:2 * n], f[:, 2 * n:]], axis=-1)
+    with jax.named_scope("fiber"), jax.named_scope("force"):
+        if df and caches.df is not None:
+            f = _df_product(caches.df.force_op, x_all, 3 * n)
+        else:
+            f = jnp.einsum("fij,fj->fi", caches.force_op, x_all)  # [nf, 3n]
+        return jnp.stack([f[:, :n], f[:, n:2 * n], f[:, 2 * n:]], axis=-1)
 
 
-@jax.named_scope("fiber")
-def matvec(group: FiberGroup, caches: FiberCaches, x_all, v_fib, v_boundary) -> jnp.ndarray:
-    """Block-diagonal fiber matvec [nf, 4n] (`matvec`, `:216-234`)."""
+def matvec(group: FiberGroup, caches: FiberCaches, x_all, v_fib, v_boundary,
+           df: bool = False) -> jnp.ndarray:
+    """Block-diagonal fiber matvec [nf, 4n] (`matvec`, `:216-234`).
+
+    ``df=True`` (the lo operator of the mixed tier) runs the three dense
+    products of `fd_fiber.matvec` through the double-float words where
+    ``caches`` holds them (`_matvec_df`); without them, and for every other
+    caller, the float64 ``dot``."""
+    with jax.named_scope("fiber"), jax.named_scope("matvec"):
+        if df and caches.df is not None:
+            res = _matvec_df(group, caches, x_all, v_fib, v_boundary)
+        else:
+            mats = group.mats
+            sc = group.scalars()
+            res = jax.vmap(
+                lambda A, xv, v, vb, xs, s, pp: fd_fiber.matvec(A, xv, v, vb, xs, s, mats, pp)
+            )(caches.A_bc, x_all, v_fib, v_boundary, caches.xs, sc, group.plus_pinned)
+        return jnp.where(group.active[:, None], res, x_all)
+
+
+def _matvec_df(group: FiberGroup, caches: FiberCaches, x_all, v, v_boundary):
+    """`fd_fiber.matvec` over the whole batch with its dense products
+    (``D1 @ sum(xs * v)``, ``P_down @ vT``, ``A_bc @ x``) in double-float
+    words: the same terms in the same order, everything between the
+    products in the vectors' float64. ``D1`` is applied unscaled and the
+    per-fiber ``2 / length_prev`` after it."""
+    words = caches.df
     mats = group.mats
-    sc = group.scalars()
-    res = jax.vmap(
-        lambda A, xv, v, vb, xs, s, pp: fd_fiber.matvec(A, xv, v, vb, xs, s, mats, pp)
-    )(caches.A_bc, x_all, v_fib, v_boundary, caches.xs, sc, group.plus_pinned)
-    return jnp.where(group.active[:, None], res, x_all)
+    n = group.n_nodes
+    bc_start = 4 * n - 14
+    xs = caches.xs
+    nm = getattr(mats, "node_mask", None)
+    if nm is not None:
+        v = jnp.where(nm[None, :, None], v, 0.0)
+    e_last = getattr(mats, "e_last", None)
+    if e_last is None:
+        def last(a):
+            return a[:, -1]
+    else:
+        def last(a):
+            # the last LIVE node, as a masked sum: no float64 ``dot``
+            return jnp.sum(a * e_last.astype(a.dtype)[None, :, None], axis=1)
+
+    vT_tension = (2.0 / group.length_prev)[:, None] * _df_product(
+        words.D1, jnp.sum(xs * v, axis=2), n)
+    vT = jnp.concatenate([v[..., 0], v[..., 1], v[..., 2], vT_tension],
+                         axis=1)
+    vT_in = jnp.pad(_df_product(words.P_down, vT, bc_start),
+                    ((0, 0), (0, 14)))
+
+    res = _df_product(words.A_bc, x_all, 4 * n) - vT_in
+    res = res.at[:, bc_start + 3].add(jnp.sum(v[:, 0] * xs[:, 0], axis=-1))
+    res = res.at[:, bc_start + 10].add(
+        jnp.where(group.plus_pinned,
+                  jnp.sum(last(v) * last(xs), axis=-1), 0.0))
+    if v_boundary is not None:
+        res = res.at[:, bc_start:bc_start + 7].add(v_boundary)
+    return res
 
 
 @jax.named_scope("fiber")

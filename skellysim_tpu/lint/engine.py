@@ -47,7 +47,8 @@ HOT_PATH_DIRS = ("ops", "solver", "fibers", "bodies", "periphery", "parallel",
 #: declared mixed-precision seams: files whose whole point is explicit
 #: hi/lo dtype surgery (double-float kernels). dtype-discipline's
 #: hardcoded-dtype check does not apply there.
-DTYPE_SEAM_FILES = ("ops/df_kernels.py", "ops/pallas_df.py")
+DTYPE_SEAM_FILES = ("ops/df_kernels.py", "ops/pallas_df.py",
+                    "ops/block_df.py")
 
 PRAGMA_RE = re.compile(
     r"#\s*skelly-lint:\s*(ignore|ignore-function)\[([^\]]*)\]"

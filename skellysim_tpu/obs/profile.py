@@ -75,6 +75,10 @@ PHASE_SCOPES = frozenset({
     # operators, nested under whatever phase calls them: every pair-sum
     # evaluation (ops/kernels.py), the shell, the fiber blocks, the bodies
     "pair", "shell", "fiber", "body",
+    # the two dense products below ``fiber`` (fibers/container.py `matvec`
+    # / `apply_fiber_force`); ``fiber`` without either is the block
+    # preconditioner's application and `prep`'s assembly
+    "matvec", "force",
 })
 
 #: the operator scopes: every solve applies at least one of them, so a

@@ -27,6 +27,16 @@ def _fmt_s(v: float) -> str:
     return f"{v:.4f}"
 
 
+#: events a build announces once, and how `render` prints them: event ->
+#: (section title, the fields of its line, in order)
+ANNOUNCEMENTS = {
+    "block_precond": ("block preconditioner",
+                      ("apply", "dtype", "fibers", "bodies")),
+    "fiber_ops": ("fiber operators",
+                  ("apply", "dtype", "fibers", "fallback")),
+}
+
+
 class Summary:
     """Accumulator over parsed JSONL records."""
 
@@ -47,10 +57,10 @@ class Summary:
         #: fused-ring fallback eligibility legs (``leg`` — budget vs
         #: platform vs missing-api, `parallel.compat._fused_fallback`)
         self.fault_legs: dict[str, int] = {}
-        #: ``block_precond`` announcements (how the step applies its block
-        #: preconditioner, once per build: `System._announce_block_precond`)
-        #: as rendered lines -> count
-        self.block_preconds: dict[str, int] = {}
+        #: once-a-build announcements (`ANNOUNCEMENTS`: how the step applies
+        #: its block preconditioner, how the Krylov loop's operator
+        #: multiplies the fiber blocks) as event -> rendered line -> count
+        self.announcements: dict[str, dict[str, int]] = {}
         self.lane_events: dict[str, int] = {}
         self.lane_rounds: list[dict] = []
         #: admission latencies from lane admit/backfill events
@@ -129,10 +139,11 @@ class Summary:
             if rec.get("leg"):
                 leg = str(rec["leg"])
                 self.fault_legs[leg] = self.fault_legs.get(leg, 0) + 1
-        elif ev == "block_precond":
-            line = " ".join(f"{k}={rec.get(k, '?')}" for k in
-                            ("apply", "dtype", "fibers", "bodies"))
-            self.block_preconds[line] = self.block_preconds.get(line, 0) + 1
+        elif ev in ANNOUNCEMENTS:
+            line = " ".join(f"{k}={rec.get(k, '?')}"
+                            for k in ANNOUNCEMENTS[ev][1])
+            seen = self.announcements.setdefault(ev, {})
+            seen[line] = seen.get(line, 0) + 1
         elif ev == "flight":
             row = {k: rec.get(k) for k in rec
                    if k not in ("ev", "ts", "pid", "host")}
@@ -273,13 +284,14 @@ class Summary:
                 f"{n} x{c}" for n, c in sorted(retraced.items())))
         out.append("")
 
-    def _block_precond_section(self, out: list[str]):
-        if not self.block_preconds:
-            return
-        out.append("== block preconditioner ==")
-        out.extend(f"block_precond {line}  (builds: {n})"
-                   for line, n in sorted(self.block_preconds.items()))
-        out.append("")
+    def _announcement_sections(self, out: list[str]):
+        for ev, (title, _) in ANNOUNCEMENTS.items():
+            if ev not in self.announcements:
+                continue
+            out.append(f"== {title} ==")
+            out.extend(f"{ev} {line}  (builds: {n})" for line, n
+                       in sorted(self.announcements[ev].items()))
+            out.append("")
 
     def _fault_section(self, out: list[str]):
         if not self.faults:
@@ -502,7 +514,7 @@ class Summary:
         self._span_section(out)
         self._device_phase_section(out)
         self._compile_section(out)
-        self._block_precond_section(out)
+        self._announcement_sections(out)
         self._fault_section(out)
         self._lane_section(out)
         self._scenario_section(out)

@@ -359,7 +359,9 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             v_fib = v_loc[off:off + nfn].reshape(g.n_fibers, g.n_nodes, 3)
             new_caches.append(fc.update_rhs_and_bc(
                 g, c, st.dt, p.eta, v_fib, mo + ex, ex,
-                precond_dtype=precond_dtype))
+                precond_dtype=precond_dtype,
+                df_words=system._fiber_ops_for(
+                    st, precision, g)[0] == "df_tile"))
             off += nfn
         caches = new_caches
 
@@ -410,7 +412,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                 x_fibs.append(x[off:off + size].reshape(g.n_fibers,
                                                         4 * g.n_nodes))
                 off += size
-            fws = [fc.apply_fiber_force(g, c, xf)
+            fws = [fc.apply_fiber_force(g, c, xf, df=lo is not None)
                    for g, c, xf in zip(buckets, caches, x_fibs)]
             fl, fp = fc.flow_multi_local(
                 f_buckets, f_caches, [fw.astype(lo_dtype) for fw in fws],
@@ -505,7 +507,8 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                     g.n_fibers, g.n_nodes, 3).astype(hi)
                 vb = (v_boundaries[i] if v_boundaries is not None
                       else jnp.zeros((g.n_fibers, 7), dtype=hi))
-                res.append(fc.matvec(g, c, xf, v_fib, vb).reshape(-1))
+                res.append(fc.matvec(g, c, xf, v_fib, vb,
+                                     df=lo is not None).reshape(-1))
                 off += nfn
             if has_shell:
                 if sharded_shell:
@@ -607,7 +610,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                     # fiber rows of A at (0, y_shell, 0): pure coupling term
                     x_fib = x_fib - fc.matvec(
                         g, c, jnp.zeros_like(x_fib), v_fib,
-                        jnp.zeros((g.n_fibers, 7), dtype=x.dtype))
+                        jnp.zeros((g.n_fibers, 7), dtype=x.dtype), df=True)
                     off_v += nfn
                 res.append(fc.apply_preconditioner(g, c, x_fib).reshape(-1))
                 off += size
@@ -653,6 +656,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             rhs = jnp.concatenate(rhs_parts)
         # a shard's own blocks: the shapes are one device's
         system._announce_block_precond(caches, body_caches)
+        system._announce_fiber_ops(st, precision)
 
         nonrep_end = fib_size + (shell_size if sharded_shell else 0)
         rdot = _make_rdot(axis, nonrep_end)

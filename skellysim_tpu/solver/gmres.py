@@ -548,8 +548,10 @@ def gmres_ir(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray, *,
         batched matmuls with the inverses `prep` formed — in
         f32 (see `System._apply_matvec(lo=...)`). Stiff small ops (the
         fiber 4nx4n blocks, whose rows reach ~1e7: f32 entry rounding
-        injects O(1) absolute noise there) stay f64 — they are a vanishing
-        fraction of the flops;
+        injects O(1) absolute noise there) stay float64-grade: the float64
+        ``dot`` where the backend has one, double-float (hi, lo f32) words
+        through the fused tile of `ops.block_df` on a TPU, which would
+        emulate that ``dot`` at a hundredth of the rate;
       * ``matvec_hi`` is the exact f64 operator — used once per refinement
         sweep for the true residual r = b - A x;
       * iterative refinement: solve A d = r with the cheap operator to

@@ -29,7 +29,7 @@ from ..fibers import container as fc
 from ..guard import verdict as _verdict
 from ..obs import tracer as obs_tracer
 from ..obs.compile_log import observed_jit
-from ..ops import block_precond
+from ..ops import block_df, block_precond
 from ..params import Params, REFINE_PAIR_IMPLS
 from ..periphery import periphery as peri
 from ..periphery.periphery import PeripheryShape, PeripheryState
@@ -319,6 +319,58 @@ class System:
         logger.info("block_precond apply=%(apply)s dtype=%(dtype)s "
                     "fibers=%(fibers)s bodies=%(bodies)s", fields)
         obs_tracer.emit("block_precond", **fields)
+
+    #: tests and `scripts/fiber_ops_parity.py` only: "df_tile" takes the
+    #: double-float tile off the TPU too (interpret mode there) and for a
+    #: bucket of any size, "f64_dot" keeps the ``dot`` on a TPU; None
+    #: follows the backend
+    _fiber_ops = None
+
+    def _fiber_ops_for(self, state, precision: str,
+                       group) -> tuple[str, str]:
+        """``(apply, fallback)``: how the Krylov loop's operator multiplies
+        the blocks of the fiber bucket ``group`` (`fc.matvec` /
+        `fc.apply_fiber_force`) in a solve of ``state`` at ``precision``.
+        ``"df_tile"`` — the fused double-float tile of
+        `ops.block_df` — in the lo operator of the mixed tier on a TPU,
+        whose float64 ``dot`` is emulated, for a bucket that fills a grid
+        step of the tile; ``"f64_dot"`` everywhere else, with the reason.
+        Follows the backend as `_refine_impl` does; the hi operator never
+        takes the tile."""
+        if precision != "mixed":
+            return "f64_dot", "full_tier"
+        if state.time.dtype != jnp.float64:
+            return "f64_dot", "float32_state"
+        if self._fiber_ops is not None:
+            return self._fiber_ops, ("-" if self._fiber_ops == "df_tile"
+                                     else "forced")
+        if jax.default_backend() != "tpu":
+            return "f64_dot", f"backend_{jax.default_backend()}"
+        if not block_df.fills_a_step(
+                group.n_fibers, 4 * group.n_nodes, 4 * group.n_nodes):
+            return "f64_dot", "small_bucket"
+        return "df_tile", "-"
+
+    def _announce_fiber_ops(self, state, precision: str):
+        """Trace-time (once per build, like `_announce_block_precond`): the
+        `_fiber_ops_for` of every fiber bucket of this solve (on a mesh: of
+        one device's) and the blocks it multiplies, in the log and as a
+        ``fiber_ops`` event; buckets that differ join with ``+``."""
+        buckets = fiber_buckets(state.fibers)
+        taken = [self._fiber_ops_for(state, precision, g) for g in buckets]
+        applies = sorted({a for a, _ in taken})
+        reasons = sorted({r for _, r in taken if r != "-"})
+        fields = dict(
+            apply="+".join(applies) or "-",
+            dtype="+".join("float32x2" if a == "df_tile"
+                           else str(buckets[0].x.dtype)
+                           for a in applies) or "-",
+            fibers="+".join(f"{g.n_fibers}x{4 * g.n_nodes}x{4 * g.n_nodes}"
+                            for g in buckets) or "-",
+            fallback="+".join(reasons) or ("-" if buckets else "no_fibers"))
+        logger.info("fiber_ops apply=%(apply)s dtype=%(dtype)s "
+                    "fibers=%(fibers)s fallback=%(fallback)s", fields)
+        obs_tracer.emit("fiber_ops", **fields)
 
     def _precision_for(self, state) -> str:
         """Resolve Params.solver_precision for one state ("full"/"mixed").
@@ -669,7 +721,10 @@ class System:
                 v_fib = v_all[off:off + nfn].reshape(g.n_fibers, g.n_nodes, 3)
                 new_caches.append(fc.update_rhs_and_bc(
                     g, c, state.dt, p.eta, v_fib, mo + ex, ex,
-                    precond_dtype=precond_dtype))
+                    precond_dtype=precond_dtype,
+                    # beside the float64 blocks, for the Krylov loop's operator
+                    df_words=self._fiber_ops_for(
+                        state, precision, g)[0] == "df_tile"))
                 off += nfn
             caches = new_caches
         if state.shell is not None:
@@ -691,9 +746,13 @@ class System:
         flows and the well-scaled shell/body dense ops — i.e. all the flops —
         are evaluated through it, while the stiff fiber-local ops (A_bc rows
         reach ~1e7, so f32 entry rounding injects O(1) absolute noise) and the
-        fiber-body link conditions stay in the ``x_flat`` dtype. This is the
-        cheap operator `gmres_ir` iterates with; exactness is restored by the
-        f64 refinement residuals.
+        fiber-body link conditions stay float64-grade in the ``x_flat``
+        dtype: the float64 ``dot`` where the backend has one, and on a TPU
+        (which emulates it) the double-float words `prep` left in the caches
+        through the fused tile of `ops.block_df` (`_fiber_ops_for`; same
+        2^-48-class products, float64 vectors). This is the cheap operator
+        `gmres_ir` iterates with; exactness is restored by the f64
+        refinement residuals, which never take the tile.
 
         ``flow_impl`` overrides the pairwise tile for the flows (the mixed
         solver's f64 residual matvec passes the double-float tile).
@@ -726,7 +785,7 @@ class System:
                 x_fibs.append(x_flat[off:off + size].reshape(g.n_fibers,
                                                              4 * g.n_nodes))
                 off += size
-            fws = [fc.apply_fiber_force(g, c, xf)
+            fws = [fc.apply_fiber_force(g, c, xf, df=lo is not None)
                    for g, c, xf in zip(buckets, caches, x_fibs)]
             v_all = v_all + self._fiber_flow(f_state, f_caches, r_all,
                                              [fw.astype(lo_dtype) for fw in fws],
@@ -797,7 +856,8 @@ class System:
                                                  3).astype(hi_dtype)
             vb = (v_boundaries[i] if v_boundaries is not None
                   else jnp.zeros((g.n_fibers, 7), dtype=hi_dtype))
-            res.append(fc.matvec(g, c, xf, v_fib, vb).reshape(-1))
+            res.append(fc.matvec(g, c, xf, v_fib, vb,
+                                 df=lo is not None).reshape(-1))
             off += nfn
         if shell is not None:
             v_shell = v_all[nf_nodes:nf_nodes + ns_nodes]
@@ -881,7 +941,7 @@ class System:
                 # fiber rows of A at (0, y_shell, 0): pure coupling term
                 x_fib = x_fib - fc.matvec(
                     g, c, jnp.zeros_like(x_fib), v_fib,
-                    jnp.zeros((g.n_fibers, 7), dtype=x_flat.dtype))
+                    jnp.zeros((g.n_fibers, 7), dtype=x_flat.dtype), df=True)
                 off_v += nfn
             res.append(fc.apply_preconditioner(g, c, x_fib).reshape(-1))
             off += size
@@ -970,11 +1030,13 @@ class System:
         self._announce_block_precond(caches, body_caches)
 
         precision = "full" if force_full else self._precision_for(state)
+        self._announce_fiber_ops(state, precision)
         if precision == "mixed":
             # f64 state/assembly/refinement residuals; the Krylov loop's
             # expensive interior (kernel flows, shell/body dense ops, block
             # preconditioners) evaluates through f32 copies via the lo seam
-            # of _apply_matvec, while stiff fiber-local ops stay f64
+            # of _apply_matvec, while stiff fiber-local ops stay
+            # float64-grade (f64 dot, or double-float words on a TPU)
             lo = _cast_floats((state, caches, body_caches), jnp.float32)
             # hi residual flows go through the refinement tile (df on
             # accelerators); state must be f64 for the df split to pay off

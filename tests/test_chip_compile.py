@@ -191,3 +191,55 @@ def test_block_inverse_compiles(one_chip, blocks, width):
         st((blocks, width, width), F32), st((blocks, width), jnp.float64))
     assert "InvertDiagBlocks" not in applied
     assert "triangular" not in applied.lower()
+
+
+@pytest.mark.parametrize("blocks,rows,cols,shared,vmapped", [
+    (256, 256, 256, False, False), (256, 192, 256, False, False),
+    (256, 248, 256, True, False), (256, 64, 128, True, False),
+    (1, 256, 256, False, False), (16, 256, 256, False, True)])
+def test_block_df_tile_compiles(one_chip, blocks, rows, cols, shared, vmapped):
+    """The double-float block matvec (`ops.block_df`) at the fiber cells'
+    shapes — 256 blocks of `A_bc` 256^2 and of the force operator 192 x 256,
+    the shared `P_down` (242 rows, split to 248) and `D1` (64 columns, split
+    to 128 lanes) — the walkthrough's one block, and under a `vmap` of two
+    members as the ensemble calls it: Mosaic takes the strips, the 128 x 128
+    transposes and the sublane rolls."""
+    from skellysim_tpu.ops import block_df
+
+    def st(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lead = (2,) if vmapped else ()
+    m_shape = lead + ((rows, cols) if shared else (blocks, rows, cols))
+
+    def apply(hi, lo, x):
+        return block_df.block_matvec_df((hi, lo), x, n_rows=rows)
+
+    text = _compile(jax.vmap(apply) if vmapped else apply,
+                    st(m_shape, F32), st(m_shape, F32),
+                    st(lead + (blocks, cols), jnp.float64))
+    assert "tpu_custom_call" in text
+
+
+def test_block_df_tile_compiles_inside_the_mesh_step(topo):
+    """The tile where `step_spmd` calls it: inside a `shard_map` over the
+    fiber axis of four described chips, 256 blocks of 256^2 a chip."""
+    from skellysim_tpu.ops import block_df
+    from skellysim_tpu.parallel.mesh import FIBER_AXIS
+
+    n_dev, blocks = 4, 256
+    mesh = Mesh(topo.devices[:n_dev], (FIBER_AXIS,))
+    sharded = NamedSharding(mesh, P(FIBER_AXIS))
+
+    def st(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
+
+    def local(hi, lo, x):
+        return block_df.block_matvec_df((hi, lo), x, n_rows=256)
+
+    step = jax.shard_map(local, mesh=mesh, in_specs=P(FIBER_AXIS),
+                         out_specs=P(FIBER_AXIS))
+    text = _compile(step, st((n_dev * blocks, 256, 256), F32),
+                    st((n_dev * blocks, 256, 256), F32),
+                    st((n_dev * blocks, 256), jnp.float64))
+    assert "tpu_custom_call" in text
