@@ -5,8 +5,8 @@ roofline is measured against. The counts are of the mathematics, not of an
 implementation: the same number whichever tile evaluates the pairs.
 
 Stokeslet, one source-target pair (u += f/r + d (d.f)/r^3, the 1/(8 pi eta)
-factor applied once per target), counted as `bench.py` counts it
-(`STOKESLET_FLOPS_PER_PAIR`, the one figure taken from there):
+factor applied once per target), counted operation by operation
+(`STOKESLET_FLOPS_PER_PAIR`):
 
     d = r_t - r_s                     3 sub
     r2 = d.d                          3 mul + 2 add            = 5

@@ -196,16 +196,26 @@ def snapshot(state, geometry: bool = False) -> dict:
         snap["geometry"] = {
             "shell": {"nodes": f64(state.shell.nodes),
                       "normals": f64(state.shell.normals),
-                      "weights": f64(state.shell.weights)},
-            "bodies": {"nodes_ref": f64(bodies.nodes_ref),
-                       "normals_ref": f64(bodies.normals_ref),
-                       "weights": f64(bodies.weights),
-                       "external_force": f64(bodies.external_force),
-                       "external_torque": f64(bodies.external_torque)}}
+                      "weights": f64(state.shell.weights)}}
+        if bodies is not None:      # a shell may stand with fibers alone
+            snap["geometry"]["bodies"] = {
+                "nodes_ref": f64(bodies.nodes_ref),
+                "normals_ref": f64(bodies.normals_ref),
+                "weights": f64(bodies.weights),
+                "external_force": f64(bodies.external_force),
+                "external_torque": f64(bodies.external_torque)}
     fibers = state.fibers
     if fibers is not None:
         groups = fibers if isinstance(fibers, (tuple, list)) and not hasattr(
             fibers, "x") else (fibers,)
+        # what a reference needs to write a fiber's boundary rows: small,
+        # so their copies are started here and land while `x` is fetched
+        # (a fetch of its own each would add a host round trip each a step)
+        flags = ("minus_clamped", "plus_pinned", "binding_body",
+                 "binding_site", "active")
+        for g in groups:
+            for k in flags:
+                getattr(g, k).copy_to_host_async()
         snap["fibers"] = [{
             "x": np.asarray(g.x, dtype=np.float64),
             "tension": np.asarray(g.tension, dtype=np.float64),
@@ -213,6 +223,7 @@ def snapshot(state, geometry: bool = False) -> dict:
             "bending_rigidity": np.asarray(g.bending_rigidity, np.float64),
             "radius": np.asarray(g.radius, dtype=np.float64),
             "force_scale": np.asarray(g.force_scale, dtype=np.float64),
+            **{k: np.asarray(getattr(g, k)) for k in flags},
         } for g in groups]
     bodies = getattr(state, "bodies", None)
     if bodies is not None and hasattr(bodies, "position"):

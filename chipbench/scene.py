@@ -1,20 +1,25 @@
 """Configuration data + ``--seed`` -> a scene directory the program can run.
 
-A configuration (``chipbench/configs/<name>.json``) is data: ``params``
-overrides by the TOML schema's field names, a fiber generator spec,
-``bodies`` and ``periphery``. This module turns it into the program's own
+A configuration (``chipbench/configs/<name>.json``) is data, and its four
+blocks are the config schema's own field names, passed on by name:
+``params`` (a dotted key walks into a nested block), ``periphery`` (its
+``shape`` picks the config class, an ``envelope`` sub-block is set key by
+key), ``bodies`` (`Body`'s fields) and ``fibers`` (`Fiber`'s fields beside
+the keys of the generator that lays them). A key that is no field raises
+`KeyError` naming it. This module turns the data into the program's own
 config dataclasses, saves the TOML where `builder.build_simulation` reads
 it, and makes sure the precompute files (`python -m skellysim_tpu.precompute`,
 which upstream users run ONCE per geometry) exist in the benchmark's cache
 directory, keyed by every periphery and body field.
 
-The scene is the same as `chip_smoke.py` ran (PR 22); the construction is
-copied, not imported: the yardstick may not depend on a file a later PR can
-edit.
+The walkthrough's scene is the same as `chip_smoke.py` ran (PR 22); the
+construction is copied, not imported: the yardstick may not depend on a
+file a later PR can edit.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import json
 import os
@@ -68,54 +73,104 @@ def _fixed(spec: dict, cfg: dict, seed: int):
             (d / np.linalg.norm(d))[None, :])
 
 
-FIBER_GENERATORS = {"uniform_box": _uniform_box, "fixed": _fixed}
+def _on_periphery(spec: dict, config, fibers: list) -> None:
+    """``n_fibers`` fibers with their minus ends on the shell, pointing
+    inward, laid by the program's own toolkit call as
+    `examples/ellipsoid/gen_config.py:30` and `examples/oocyte/gen_config.py:43`
+    make it (their ``rng`` of seed 100 is the spec's ``scene_seed``).
+    ``--seed`` does not move this scene, as in `_fixed`: the shell's node
+    set has no mirror image that the precomputed operator would share, and
+    a fresh draw re-rolls the GMRES iteration counts. The seed reaches the
+    program as its own RNG seed (`build_config`)."""
+    config.periphery.move_fibers_to_surface(
+        fibers, ds_min=spec["ds_min"],
+        rng=np.random.default_rng(int(spec["scene_seed"])), verbose=False)
+
+
+#: generator -> (function, the keys of a ``fibers`` block that are its own
+#: and not `Fiber`'s). These return (origins, directions) of straight fibers
+FIBER_GENERATORS = {"uniform_box": (_uniform_box, {"scene_seed"}),
+                    "fixed": (_fixed, {"origin", "direction"})}
+#: ... and these lay the `Fiber`s themselves, on the config's own geometry
+FIBER_LAYOUTS = {"on_periphery": (_on_periphery,
+                                  {"n_fibers", "ds_min", "scene_seed"})}
+#: ``periphery.shape`` -> the config class of `skellysim_tpu.config`
+PERIPHERY_CONFIGS = {"sphere": "ConfigSpherical",
+                     "ellipsoid": "ConfigEllipsoidal",
+                     "revolution": "ConfigRevolution"}
 
 
 # ------------------------------------------------------------------- building
 
+def _set_fields(obj, block: dict, where: str) -> None:
+    """Every key of ``block`` set by name on the schema dataclass ``obj``; a
+    dotted key (``dynamic_instability.n_nodes``) walks into the nested one,
+    and a table of the schema (a periphery's ``envelope``, which also holds
+    the free parameters of its height expression) is filled key by key."""
+    for key, value in block.items():
+        *path, leaf = key.split(".")
+        target = obj
+        for name in path:
+            target = getattr(target, name, None)
+        if not (dataclasses.is_dataclass(target) and leaf in
+                {f.name for f in dataclasses.fields(target)}):
+            raise KeyError(f"{where}.{key} is not a field of the config schema")
+        if isinstance(getattr(target, leaf), dict):
+            getattr(target, leaf).update(value)
+        else:
+            setattr(target, leaf, value)
+
+
+def _field_values(cls, block: dict, where: str, but=frozenset()) -> dict:
+    """The keys of ``block`` that are fields of the schema dataclass ``cls``
+    (a fiber's ``x`` is the generator's to lay), for ``cls(**values)``; any
+    other key that is not in ``but`` is refused."""
+    names = {f.name for f in dataclasses.fields(cls)} - {"x"}
+    unknown = sorted(block.keys() - names - but)
+    if unknown:
+        raise KeyError(f"{where}.{unknown[0]} is not a field of the config "
+                       "schema")
+    return {k: v for k, v in block.items() if k in names}
+
+
 def build_config(cfg: dict, seed: int):
     """The program's config object for this configuration and seed."""
-    from skellysim_tpu.config import Body, Config, ConfigSpherical, Fiber
+    from skellysim_tpu import config as schema
 
     peri = cfg.get("periphery")
     if peri is None:
-        config = Config()
-    elif peri.get("shape", "sphere") == "sphere":
-        config = ConfigSpherical()
-        config.periphery.n_nodes = int(peri["n_nodes"])
-        config.periphery.radius = float(peri["radius"])
+        config = schema.Config()
     else:
-        raise ValueError(f"periphery shape {peri.get('shape')!r}: only "
-                         "'sphere' has a builder here")
-    for key, value in cfg.get("params", {}).items():
-        if not hasattr(config.params, key):
-            raise KeyError(f"params.{key} is not a field of the config schema")
-        setattr(config.params, key, value)
+        peri = dict(peri)
+        config = getattr(schema, PERIPHERY_CONFIGS[peri.pop("shape",
+                                                            "sphere")])()
+        _set_fields(config.periphery, peri, "periphery")
+    _set_fields(config.params, cfg.get("params", {}), "params")
     # the program's own RNG (dynamic instability, off in these scenes) is
     # seeded from --seed: it is stored in every frame and moves no node
     config.params.seed = int(seed) % (2**31 - 1)
 
-    config.bodies = []
-    for b in cfg.get("bodies", []):
-        config.bodies.append(Body(
-            position=list(b.get("position", [0.0, 0.0, 0.0])),
-            shape=b.get("shape", "sphere"), radius=float(b["radius"]),
-            n_nodes=int(b["n_nodes"]),
-            external_force=list(b.get("external_force", [0.0, 0.0, 0.0]))))
+    config.bodies = [schema.Body(**_field_values(schema.Body, b,
+                                                 f"bodies[{i}]"))
+                     for i, b in enumerate(cfg.get("bodies", []))]
 
     config.fibers = []
     spec = cfg.get("fibers")
     if spec:
-        origins, directions = FIBER_GENERATORS[spec["generator"]](
-            spec, cfg, seed)
-        for x0, d in zip(origins, directions):
-            fib = Fiber(n_nodes=int(spec["n_nodes"]),
-                        length=float(spec["length"]),
-                        bending_rigidity=float(spec["bending_rigidity"]),
-                        radius=float(spec.get("radius", 0.0125)),
-                        force_scale=float(spec.get("force_scale", 0.0)))
-            fib.fill_node_positions(x0, d)
-            config.fibers.append(fib)
+        lays = spec["generator"] in FIBER_LAYOUTS
+        generate, own = (FIBER_LAYOUTS if lays
+                         else FIBER_GENERATORS)[spec["generator"]]
+        values = _field_values(schema.Fiber, spec, "fibers",
+                               but={"generator"} | own)
+        if lays:
+            config.fibers = [schema.Fiber(**values)
+                             for _ in range(int(spec["n_fibers"]))]
+            generate(spec, config, config.fibers)
+        else:
+            for x0, d in zip(*generate(spec, cfg, seed)):
+                fib = schema.Fiber(**values)
+                fib.fill_node_positions(x0, d)
+                config.fibers.append(fib)
     return config
 
 
