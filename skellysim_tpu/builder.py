@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import logging
 import os
+import time
 
 import numpy as np
 import jax
@@ -275,7 +276,7 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
                        "build_simulation; using the direct evaluator on one "
                        "device (set params.mesh_devices to the device count "
                        "to run on a mesh)")
-    shell, shape = (None, None)
+    shell, shape, shell_precompute = None, None, None
     if getattr(config, "periphery", None) is not None:
         # mixed mode gets an f32 M_inv, halving the shell preconditioner's
         # HBM; one policy shared with System._precision_for
@@ -284,8 +285,14 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
         mixed = resolve_precision(params.solver_precision,
                                   dtype == jnp.float64) == "mixed"
         pdt = jnp.float32 if mixed else None
+        t_load = time.perf_counter()
         shell, shape = build_periphery(config.periphery, config_dir, dtype,
                                        precond_dtype=pdt)
+        # the npz read and the hand-over to the device; the upload itself is
+        # not waited for here, a first step is what waits for it
+        shell_precompute = {
+            "file": os.path.basename(config.periphery.precompute_file),
+            "load_s": time.perf_counter() - t_load}
 
     fibers = build_fibers(config.fibers, dtype)
     if fibers is not None and mesh is not None:
@@ -300,6 +307,7 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
         fibers = pad_for_mesh(fibers, mesh.size)
 
     system = System(params, shell_shape=shape, mesh=mesh)
+    system.shell_precompute = shell_precompute
     state = system.make_state(
         fibers=fibers,
         points=build_point_sources(config.point_sources, dtype),

@@ -34,6 +34,10 @@ ANNOUNCEMENTS = {
                       ("apply", "dtype", "fibers", "bodies")),
     "fiber_ops": ("fiber operators",
                   ("apply", "dtype", "fibers", "fallback")),
+    "periphery": ("periphery",
+                  ("shape", "nodes", "operator", "operator_dtype",
+                   "operator_bytes", "m_inv", "m_inv_dtype", "m_inv_bytes",
+                   "f64_product", "row_block", "precompute", "load_s")),
 }
 
 

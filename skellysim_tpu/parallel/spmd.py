@@ -656,6 +656,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             rhs = jnp.concatenate(rhs_parts)
         # a shard's own blocks: the shapes are one device's
         system._announce_block_precond(caches, body_caches)
+        system._announce_periphery(st)
         system._announce_fiber_ops(st, precision)
 
         nonrep_end = fib_size + (shell_size if sharded_shell else 0)

@@ -260,6 +260,26 @@ def matvec(shell: PeripheryState, x, v_on_shell):
 _F64_ROW_BLOCK = 2048
 
 
+def _row_blocked(op) -> bool:
+    return op.dtype == jnp.float64 and op.shape[0] > 2 * _F64_ROW_BLOCK
+
+
+def describe(shell: PeripheryState) -> dict:
+    """What a run holds of a shell and how `_apply_operator` multiplies its
+    float64 operator, for `System._announce_periphery` (shapes and dtypes
+    only: a traced state serves)."""
+    out = {"nodes": shell.n_nodes}
+    for name, m in (("operator", shell.stresslet_plus_complementary),
+                    ("m_inv", shell.M_inv)):
+        dtype = jnp.dtype(m.dtype)
+        out.update({name: f"{m.shape[0]}x{m.shape[1]}",
+                    name + "_dtype": dtype.name,
+                    name + "_bytes": m.size * dtype.itemsize})
+    blocked = _row_blocked(shell.stresslet_plus_complementary)
+    return dict(out, f64_product="row_blocks" if blocked else "whole",
+                row_block=_F64_ROW_BLOCK if blocked else 0)
+
+
 @jax.named_scope("shell")
 def _apply_operator(op, x):
     """``op @ x``; a large float64 operator goes in row blocks.
@@ -272,7 +292,7 @@ def _apply_operator(op, x):
     last block starts early enough to stay in range, so trailing rows may
     be computed twice — to the same values."""
     rows = op.shape[0]
-    if op.dtype != jnp.float64 or rows <= 2 * _F64_ROW_BLOCK:
+    if not _row_blocked(op):
         return op @ x
     block = _F64_ROW_BLOCK
 

@@ -372,6 +372,35 @@ class System:
                     "fibers=%(fibers)s fallback=%(fallback)s", fields)
         obs_tracer.emit("fiber_ops", **fields)
 
+    #: where the shell's stored operators came from, and the seconds it took
+    #: to read them and hand them to the device: `builder.build_simulation`
+    #: says (a shell handed to `make_state` by anyone else has no file)
+    shell_precompute = None
+
+    def _announce_periphery(self, state):
+        """Trace-time (once per build, like `_announce_block_precond`): what
+        this solve holds of a shell — shape, nodes, the stored operator and
+        `M_inv` (shape, dtype, bytes), the precompute file they were read
+        from and in how many seconds, and how the float64 operator is
+        multiplied (`periphery._apply_operator`: ``row_blocks`` of
+        ``row_block`` rows, or ``whole``) — in the log and as a
+        ``periphery`` event. Silent without a shell."""
+        if state.shell is None:
+            return
+        src = self.shell_precompute or {}
+        fields = dict(
+            shape=self.shell_shape.kind if self.shell_shape else "generic",
+            **peri.describe(state.shell),
+            precompute=src.get("file", "-"),
+            load_s=round(src.get("load_s", 0.0), 3))
+        logger.info(
+            "periphery shape=%(shape)s nodes=%(nodes)d "
+            "operator=%(operator)s %(operator_dtype)s %(operator_bytes)dB "
+            "m_inv=%(m_inv)s %(m_inv_dtype)s %(m_inv_bytes)dB "
+            "f64_product=%(f64_product)s row_block=%(row_block)d "
+            "precompute=%(precompute)s load_s=%(load_s).3f", fields)
+        obs_tracer.emit("periphery", **fields)
+
     def _precision_for(self, state) -> str:
         """Resolve Params.solver_precision for one state ("full"/"mixed").
 
@@ -1028,6 +1057,7 @@ class System:
                 raise ValueError("state has no implicit components to solve")
             rhs = jnp.concatenate(rhs_parts)
         self._announce_block_precond(caches, body_caches)
+        self._announce_periphery(state)
 
         precision = "full" if force_full else self._precision_for(state)
         self._announce_fiber_ops(state, precision)
