@@ -66,7 +66,8 @@ def _normalize(out, state, *, dt_used, retries):
         cycles=jnp.asarray(info.cycles, dtype=jnp.int32),
         health=jnp.asarray(info.health, dtype=jnp.int32),
         dt_used=jnp.asarray(dt_used, dtype=state.dt.dtype),
-        guard_retries=jnp.asarray(retries, dtype=jnp.int32))
+        guard_retries=jnp.asarray(retries, dtype=jnp.int32),
+        gram_rows=jnp.asarray(info.gram_rows, dtype=jnp.int32))
     return new_state, x, info
 
 

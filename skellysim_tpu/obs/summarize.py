@@ -458,6 +458,16 @@ class Summary:
             out.append(f"collective rounds/solve: mean "
                        f"{sum(rounds) / len(rounds):.1f}  max {max(rounds)}"
                        f"  total {sum(rounds)}")
+        # basis rows a Gram pass contracted (`GmresResult.gram_rows`, two
+        # passes an iteration): restart + 1 where a pass walks the whole
+        # basis, the chunk-padded live rows where it walks those
+        per_pass = [int(s["gram_rows"]) / (2 * int(s["iters"]))
+                    for s in self.steps
+                    if "gram_rows" in s and int(s["iters"]) > 0]
+        if per_pass:
+            out.append(f"gram rows/pass: mean "
+                       f"{sum(per_pass) / len(per_pass):.1f}  "
+                       f"max {max(per_pass):.1f}")
         rt = [float(s["residual_true"]) for s in self.steps
               if s.get("residual_true") is not None]
         if rt:

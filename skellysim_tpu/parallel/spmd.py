@@ -774,7 +774,8 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             # buffer stays None in the mesh program (a replicated [N,3]
             # carry per shard buys nothing over the single-chip history)
             cycles=jnp.asarray(result.cycles, dtype=jnp.int32),
-            health=health, dt_used=st.dt, guard_retries=jnp.int32(0))
+            health=health, dt_used=st.dt, guard_retries=jnp.int32(0),
+            gram_rows=jnp.asarray(result.gram_rows, dtype=jnp.int32))
         return new_state, (tuple(sol_fibs), sol_shell, sol_body), info
 
     # -------------------------------------------------------------- assembly
@@ -790,7 +791,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                                 fiber_error=0.0, residual_true=0.0,
                                 loss_of_accuracy=False, refines=0,
                                 cycles=0, history=None, health=0,
-                                dt_used=0.0, guard_retries=0))
+                                dt_used=0.0, guard_retries=0, gram_rows=0))
     # check_vma off on this one program: with it on, the GMRES while_loop's
     # carries (H, cs, sn — initialised unvarying, fed by psum'd dots the
     # checker sees as varying over `fib`) fail to trace until each initial

@@ -81,25 +81,109 @@ class GmresResult(NamedTuple):
     #: like every other carry. 0 = healthy. Plain int default for the same
     #: import-time reason as ``refines``.
     health: int | jnp.ndarray = 0
+    #: int32, basis rows the solve's Gram passes contracted, chunk-padded
+    #: (`_icgs`: two passes an iteration over the chunks that hold the live
+    #: rows) — the run-loop metrics field ``gram_rows``; rows a pass =
+    #: ``gram_rows / (2 * iters)``, against ``restart + 1`` for a product
+    #: over the whole basis. Plain int default as for ``refines``.
+    gram_rows: int | jnp.ndarray = 0
 
 
-def _icgs(V, w, k, n_restart, rdot):
-    """Two-pass classical Gram-Schmidt of w against V[:k+1] (rows are basis vectors).
+#: basis rows one trip of a live-row loop contracts (`_live_chunk`). The
+#: Krylov basis is allocated at ``restart + 1`` rows and a cycle fills the
+#: first ``k + 1`` — a dozen of 101 in the shipped scenes — so every product
+#: over the basis walks ``ceil(live / _GRAM_CHUNK)`` chunks instead of all
+#: rows. Fixed from a sweep on the chip (PERF.md §6, PR 35: a step of the
+#: 256-fiber cell takes 0.3964 / 0.3994 / 0.4423 s at 8 / 16 / 32; 16 keeps
+#: one collective round a pass on a mesh while a cycle holds up to 16 rows).
+_GRAM_CHUNK = 16
 
-    Uses a mask over the fixed-size basis so the loop stays shape-static.
-    ``rdot(V, w)`` computes the batch of basis dot products — under the SPMD
-    solver this is the one collective (a `psum`) per orthogonalization pass.
+
+def _chunk_rows(rows: int) -> int:
+    """Rows a chunk of a ``rows``-row basis holds (a basis shorter than
+    `_GRAM_CHUNK` is one chunk)."""
+    return min(_GRAM_CHUNK, rows)
+
+
+def _live_chunk(V, c, live):
+    """(start, rows [start, start + size) of V, keep) for chunk ``c`` of a
+    walk over V's first ``live`` rows in steps of ``size = _chunk_rows``. The
+    last chunk of a basis whose row count is no multiple of ``size`` starts
+    early instead of reading past the end; ``keep`` masks off the rows the
+    previous chunk already covered, and the rows from ``live`` on."""
+    size = _chunk_rows(V.shape[0])
+    start = jnp.minimum(c * size, V.shape[0] - size)
+    idx = start + jnp.arange(size, dtype=jnp.int32)
+    return (start, lax.dynamic_slice_in_dim(V, start, size, axis=0),
+            (idx >= c * size) & (idx < live))
+
+
+def _icgs(V, w, k, rdot):
+    """Two-pass classical Gram-Schmidt of w against V[:k+1] (rows are basis
+    vectors); returns (w, h, basis rows contracted).
+
+    Only the live rows are contracted: each pass walks
+    ``ceil((k + 1) / _GRAM_CHUNK)`` chunks of the fixed-size basis (a trip
+    count from the replicated ``k``, so the loop stays shape-static and, on a
+    mesh, every shard takes the same number of collective rounds). Every dot
+    of a pass is taken against the pass's INCOMING ``w`` — the projections
+    accumulate beside it and are subtracted after the walk — so this is ICGS
+    on the same values as one masked product over all rows, less the rows
+    that are exact zeros. ``rdot(Vc, w)`` computes a chunk's dot products —
+    under the SPMD solver one collective (a `psum`) per chunk.
     """
-    keep = jnp.arange(n_restart + 1, dtype=jnp.int32) <= k
-    h = jnp.zeros(n_restart + 1, dtype=w.dtype)
+    size = _chunk_rows(V.shape[0])
+    n_chunks = (k + size) // size            # ceil((k + 1) / size)
+    h = jnp.zeros(V.shape[0], dtype=w.dtype)
     for _ in range(2):
-        # select, not multiply: 0 * inf = NaN would poison the masked
-        # rows if a dot overflowed (docs/audit.md "Masking discipline");
-        # bitwise identical to the product for finite dots
-        proj = jnp.where(keep, rdot(V, w), 0.0)   # [m+1] masked <v_i, w>
-        w = w - proj @ V
-        h = h + proj
-    return w, h
+        def chunk(c, carry):
+            h, acc = carry
+            start, Vc, keep = _live_chunk(V, c, k + 1)
+            # select, not multiply: 0 * inf = NaN would poison the masked
+            # rows if a dot overflowed (docs/audit.md "Masking discipline");
+            # bitwise identical to the product for finite dots
+            proj = jnp.where(keep, rdot(Vc, w), 0.0)
+            # a row another chunk covers gets proj == 0 added here
+            h = lax.dynamic_update_slice_in_dim(
+                h, lax.dynamic_slice_in_dim(h, start, size) + proj, start,
+                axis=0)
+            return h, acc + proj @ Vc
+
+        h, acc = lax.fori_loop(0, n_chunks, chunk, (h, jnp.zeros_like(w)))
+        w = w - acc
+    return w, h, 2 * size * n_chunks
+
+
+def _back_substitute(H, g, k):
+    """Solve the leading k x k triangle of the Givens-rotated Hessenberg
+    ``H`` ([m + 1, m]) against ``g``: ``y`` ([m]) with zeros from row k on.
+    Runs the k live rows only, last to first — a row from k on would write
+    a zero into a zero."""
+    m = H.shape[1]
+
+    def back_sub(i, y):
+        j = m - 1 - i
+        hjj = H[j, j]
+        rhs = g[j] - jnp.dot(H[j, :], y)
+        return y.at[j].set(rhs / jnp.where(hjj != 0.0, hjj, 1.0))
+
+    return lax.fori_loop(m - k, m, back_sub, jnp.zeros(m, dtype=H.dtype))
+
+
+def _combine_live(y, V, k):
+    """``y @ V[:m]`` for a ``y`` ([m]) that is zero from row k on,
+    contracting only the chunks of V ([m + 1, n]) that hold the k live
+    rows."""
+    size = _chunk_rows(V.shape[0])
+    y = jnp.pad(y, (0, V.shape[0] - y.shape[0]))
+
+    def chunk(c, acc):
+        start, Vc, keep = _live_chunk(V, c, k)
+        yc = jnp.where(keep, lax.dynamic_slice_in_dim(y, start, size), 0.0)
+        return acc + yc @ Vc
+
+    return lax.fori_loop(0, (k + size - 1) // size, chunk,
+                         jnp.zeros(V.shape[1], dtype=V.dtype))
 
 
 def _reductions(rdot):
@@ -209,8 +293,9 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
 
     def arnoldi_cycle(x0, r0):
         """One restart cycle from x0 with precomputed residual r0 = b - A x0;
-        returns (x, implicit_resid, inner_iters, breakdown=False — only the
-        s-step cycle has a Cholesky-ridge breakdown path)."""
+        returns (x, implicit_resid, inner_iters, basis rows the Gram passes
+        contracted, breakdown=False — only the s-step cycle has a
+        Cholesky-ridge breakdown path)."""
         beta = _norm(r0)
         safe_beta = jnp.where(beta > 0.0, beta, 1.0)
 
@@ -221,17 +306,17 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
         g0 = jnp.zeros(m + 1, dtype=dtype).at[0].set(beta)
 
         def cond(state):
-            k, _, _, _, _, _, done = state
+            k, *_, done = state
             return (k < m) & ~done
 
         def body(state):
-            k, V, H, cs, sn, g, done = state
+            k, V, H, cs, sn, g, gram, done = state
             # skelly-pulse phase scopes (obs/profile.py): metadata-only —
             # the compiled program, contracts, and baselines are unchanged
             with jax.named_scope("arnoldi"):
                 w = matvec(M(V[k]))
             with jax.named_scope("gram"):
-                w, h = _icgs(V, w, k, m, rdot)
+                w, h, contracted = _icgs(V, w, k, rdot)
                 h_norm = _norm(w)
                 h = h.at[k + 1].set(h_norm)
                 V = V.at[k + 1].set(w / jnp.where(h_norm > 0.0, h_norm, 1.0))
@@ -255,26 +340,15 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
                 H = H.at[:, k].set(h)
 
             done = jnp.abs(g[k + 1]) <= tol_abs
-            return k + 1, V, H, cs, sn, g, done
+            return k + 1, V, H, cs, sn, g, gram + contracted, done
 
-        k, V, H, cs, sn, g, done = lax.while_loop(
-            cond, body, (jnp.int32(0), V0, H0, cs0, sn0, g0, beta <= tol_abs))
+        k, V, H, cs, sn, g, gram, done = lax.while_loop(
+            cond, body, (jnp.int32(0), V0, H0, cs0, sn0, g0, jnp.int32(0),
+                         beta <= tol_abs))
 
-        # solve the k x k triangular system via masked back-substitution
-        idx = jnp.arange(m, dtype=jnp.int32)
-        active = idx < k
-
-        def back_sub(i, y):
-            j = m - 1 - i
-            hjj = H[j, j]
-            rhs = g[j] - jnp.dot(H[j, :], y)
-            yj = jnp.where(active[j], rhs / jnp.where(hjj != 0.0, hjj, 1.0), 0.0)
-            return y.at[j].set(yj)
-
-        y = lax.fori_loop(0, m, back_sub, jnp.zeros(m, dtype=dtype))
-        dx = M(y @ V[:m])
+        dx = M(_combine_live(_back_substitute(H, g, k), V, k))
         resid = jnp.abs(g[jnp.minimum(k, m)]) / safe_b_norm
-        return x0 + dx, resid, k, jnp.asarray(False)
+        return x0 + dx, resid, k, gram, jnp.asarray(False)
 
     def arnoldi_cycle_block(x0, r0):
         """Communication-avoiding restart cycle (``block_s`` > 1).
@@ -327,7 +401,7 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
             return (k < m) & ~rest[-1]
 
         def body(state):
-            k, V, Hr, H, cs, sn, g, brk, done = state
+            k, V, Hr, H, cs, sn, g, gram, brk, done = state
 
             # ---- s preconditioned matvec powers (one matvec per trip)
             def gen(j, P):
@@ -431,35 +505,24 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
                     done = done | (~done & ~col_ok) \
                         | (acc & (jnp.abs(g[j + 1]) <= tol_abs))
                     prev_e = e_t
-            return k + accepted, V, Hr, H, cs, sn, g, brk, done
+            # both batched Gram reductions contract the whole masked basis
+            return (k + accepted, V, Hr, H, cs, sn, g,
+                    gram + jnp.int32(2 * (m + 1)), brk, done)
 
-        k, V, Hr, H, cs, sn, g, brk, done = lax.while_loop(
+        k, V, Hr, H, cs, sn, g, gram, brk, done = lax.while_loop(
             cond, body, (jnp.int32(0), V0, Hr0, H0, cs0, sn0, g0,
-                         jnp.asarray(False), beta <= tol_abs))
+                         jnp.int32(0), jnp.asarray(False), beta <= tol_abs))
 
-        # identical masked back-substitution to the sequential cycle
-        idx = jnp.arange(m, dtype=jnp.int32)
-        active = idx < k
-
-        def back_sub(i, y):
-            j = m - 1 - i
-            hjj = H[j, j]
-            rhs = g[j] - jnp.dot(H[j, :], y)
-            yj = jnp.where(active[j], rhs / jnp.where(hjj != 0.0, hjj, 1.0),
-                           0.0)
-            return y.at[j].set(yj)
-
-        y = lax.fori_loop(0, m, back_sub, jnp.zeros(m, dtype=dtype))
-        dx = M(y @ V[:m])
+        dx = M(_combine_live(_back_substitute(H, g, k), V, k))
         resid = jnp.abs(g[jnp.minimum(k, m)]) / safe_b_norm
-        return x0 + dx, resid, k, brk
+        return x0 + dx, resid, k, gram, brk
 
     cycle = arnoldi_cycle if block_s == 1 else arnoldi_cycle_block
 
     def outer_cond(state):
         (x, r, resid_true, prev_true, resid_impl, total_iters, cycles,
-         hist, health) = state
-        del x, r, cycles, hist, health
+         gram_rows, hist, health) = state
+        del x, r, cycles, gram_rows, hist, health
         # acceptance on the EXPLICIT residual: with restarts + a right
         # preconditioner the implicit (Givens) residual drifts from the true
         # one, and Belos' loss-of-accuracy warning (`solver_hydro.cpp:85-92`)
@@ -472,8 +535,9 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
         return (resid_true > tol) & (total_iters < maxiter) & ~stalled
 
     def outer_body(state):
-        x, r, resid_true, _, _, total_iters, cycles, hist, health = state
-        x, resid_impl, k, brk = cycle(x, r)
+        (x, r, resid_true, _, _, total_iters, cycles, gram_rows, hist,
+         health) = state
+        x, resid_impl, k, gram, brk = cycle(x, r)
         r = b - matvec(x)
         prev_true = resid_true
         resid_true = _norm(r) / safe_b_norm
@@ -498,7 +562,7 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
                              resid_true])
             hist = hist.at[lax.rem(cycles, jnp.int32(history))].set(row)
         return (x, r, resid_true, prev_true, resid_impl, total_iters + k,
-                cycles + 1, hist, health)
+                cycles + 1, gram_rows + gram, hist, health)
 
     x0 = jnp.zeros_like(b)
     init_resid = jnp.where(b_norm > 0.0, jnp.array(jnp.inf, dtype=dtype), jnp.array(0.0, dtype=dtype))
@@ -508,11 +572,11 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
     # with x = 0) — the exact silent-poisoning mode the health word
     # exists to surface, so stamp it at entry
     health0 = nonfinite_word(b_norm)
-    (x, _, resid_true, _, resid_impl, iters, cycles, hist,
+    (x, _, resid_true, _, resid_impl, iters, cycles, gram_rows, hist,
      health) = lax.while_loop(
         outer_cond, outer_body,
         (x0, b, init_resid, init_resid, init_resid, jnp.int32(0),
-         jnp.int32(0), hist0, health0))
+         jnp.int32(0), jnp.int32(0), hist0, health0))
     # iteration budget exhausted without reaching tol = stagnation too
     # (the "burns the full restart budget with no escalation" mode)
     health = health | jnp.where((resid_true > tol) & (resid_impl > tol)
@@ -524,7 +588,7 @@ def gmres(matvec: Callable, b: jnp.ndarray, *, precond: Callable | None = None,
                        converged=(resid_true <= tol) | (resid_impl <= tol),
                        residual_true=resid_true, cycles=cycles,
                        history=hist if history > 0 else None,
-                       health=health)
+                       health=health, gram_rows=gram_rows)
 
 
 @partial(jax.jit, static_argnames=("matvec_hi", "matvec_lo", "precond_lo",
@@ -575,12 +639,12 @@ def gmres_ir(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray, *,
     safe_b_norm = jnp.where(b_norm > 0.0, b_norm, 1.0)
 
     def cond(state):
-        x, r, r_rel, outer, total, hist, health = state
-        del x, r, hist, health
+        x, r, r_rel, outer, total, gram_rows, hist, health = state
+        del x, r, gram_rows, hist, health
         return (r_rel > tol) & (outer < max_refine)
 
     def body(state):
-        x, r, _, outer, total, hist, health = state
+        x, r, _, outer, total, gram_rows, hist, health = state
         d = gmres(matvec_lo, r, precond=M, tol=inner_tol,
                   restart=restart, maxiter=maxiter, rdot=rdot,
                   block_s=block_s)
@@ -605,16 +669,17 @@ def gmres_ir(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray, *,
             row = jnp.stack([(total + d.iters).astype(b.dtype), d.residual,
                              r_rel])
             hist = hist.at[lax.rem(outer, jnp.int32(history))].set(row)
-        return x, r, r_rel, outer + 1, total + d.iters, hist, health
+        return (x, r, r_rel, outer + 1, total + d.iters,
+                gram_rows + d.gram_rows, hist, health)
 
     x0 = jnp.zeros_like(b)
     init_rel = jnp.where(b_norm > 0.0, jnp.asarray(jnp.inf, dtype=b.dtype),
                          jnp.asarray(0.0, dtype=b.dtype))
     hist0 = jnp.full((max(history, 0), 3), jnp.nan, dtype=b.dtype)
     health0 = nonfinite_word(b_norm)
-    x, _, r_rel, outers, iters, hist, health = lax.while_loop(
-        cond, body, (x0, b, init_rel, jnp.int32(0), jnp.int32(0), hist0,
-                     health0))
+    x, _, r_rel, outers, iters, gram_rows, hist, health = lax.while_loop(
+        cond, body, (x0, b, init_rel, jnp.int32(0), jnp.int32(0),
+                     jnp.int32(0), hist0, health0))
     # refinement budget exhausted above tol = stagnation (each sweep
     # should contract by ~inner_tol; when it doesn't, more sweeps won't
     # help — the escalation ladder's cue to change the program instead)
@@ -626,32 +691,42 @@ def gmres_ir(matvec_hi: Callable, matvec_lo: Callable, b: jnp.ndarray, *,
                        converged=r_rel <= tol, residual_true=r_rel,
                        refines=outers, cycles=outers,
                        history=hist if history > 0 else None,
-                       health=health)
+                       health=health, gram_rows=gram_rows)
 
 
 def collective_rounds(iters, cycles, block_s: int = 1,
-                      restart: int | None = None) -> int:
+                      restart: int | None = None,
+                      gram_rows: int | None = None) -> int:
     """Dot-product collective rounds one solve paid through the ``rdot``
     seam — the quantity the s-step cycle exists to shrink, surfaced as the
     run-loop metrics field ``collective_rounds`` and summed/meaned by
     `obs summarize` (docs/observability.md).
 
-    Sequential (``block_s=1``): 3 reductions per inner iteration (two ICGS
-    Gram passes + the new column's norm). s-step: 2 batched Gram reductions
-    per round of ``s`` iterations. Both plus 2 per restart boundary (the
-    entry-residual norm and the explicit-residual norm). For `gmres_ir`
-    results ``cycles`` counts refinement SWEEPS, not the inner solver's
-    restart cycles — pass ``restart`` (the caller's `Params.gmres_restart`)
-    so boundaries are floored at ``ceil(iters / restart)`` and an inner
-    restart blow-up still moves the metric. A (tight) lower bound, not an
-    exact trace count; host-side bookkeeping only — never traced."""
+    Sequential (``block_s=1``): per inner iteration, one reduction for each
+    chunk of live basis rows in each of the two ICGS Gram passes (`_icgs`:
+    ``ceil((k + 1) / _GRAM_CHUNK)`` chunks a pass at basis row ``k``) plus
+    the new column's norm. With the solve's ``gram_rows`` the chunk count is
+    exact (``gram_rows / chunk``); without it, the floor of one chunk a
+    pass, 3 per iteration. s-step: 2 batched Gram reductions per round of
+    ``s`` iterations. Both plus 2 per restart boundary (the entry-residual
+    norm and the explicit-residual norm). For `gmres_ir` results ``cycles``
+    counts refinement SWEEPS, not the inner solver's restart cycles — pass
+    ``restart`` (the caller's `Params.gmres_restart`) so boundaries are
+    floored at ``ceil(iters / restart)`` and an inner restart blow-up still
+    moves the metric. A (tight) lower bound, not an exact trace count;
+    host-side bookkeeping only — never traced."""
     iters, cycles = int(iters), int(cycles)
     boundaries = cycles
     if restart:
         boundaries = max(boundaries, -(-iters // max(int(restart), 1)))
-    if block_s <= 1:
-        return 3 * iters + 2 * boundaries
-    return 2 * (-(-iters // block_s)) + 2 * boundaries
+    if block_s > 1:
+        return 2 * (-(-iters // block_s)) + 2 * boundaries
+    if gram_rows is None:
+        gram = 2 * iters
+    else:
+        gram = int(gram_rows) // _chunk_rows(
+            int(restart) + 1 if restart else _GRAM_CHUNK)
+    return gram + iters + 2 * boundaries
 
 
 def history_rows(history, cycles) -> list:

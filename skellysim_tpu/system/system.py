@@ -98,7 +98,8 @@ def _rewrap_fibers(fibers, new_buckets: tuple):
 #: tests/test_cli_pipeline.py). Resumed runs are segmented by a marker line
 #: {"resume": true, "t": ...} that `cli.run(resume=True)` appends first.
 METRICS_FIELDS = ("step", "t", "dt", "iters", "gmres_cycles",
-                  "collective_rounds", "residual", "residual_true",
+                  "collective_rounds", "gram_rows", "residual",
+                  "residual_true",
                   "fiber_error", "accepted", "refines", "loss_of_accuracy",
                   "health", "guard_retries", "nucleations", "catastrophes",
                   "active_fibers", "wall_s", "wall_ms", "gmres_history",
@@ -168,6 +169,9 @@ class StepInfo(NamedTuple):
     dt_used: float | jnp.ndarray = 0.0
     #: guard-ladder retries this trial paid (0 with the ladder off)
     guard_retries: int | jnp.ndarray = 0
+    #: basis rows the solve's Gram passes contracted
+    #: (`solver.gmres.GmresResult.gram_rows`; the metrics field `gram_rows`)
+    gram_rows: int | jnp.ndarray = 0
 
 
 def solution_from_state(state: SimState):
@@ -1172,7 +1176,8 @@ class System:
                                              > 10.0 * p.gmres_tol)),
                         refines=result.refines, cycles=result.cycles,
                         history=result.history, health=health,
-                        dt_used=state.dt, guard_retries=jnp.int32(0))
+                        dt_used=state.dt, guard_retries=jnp.int32(0),
+                        gram_rows=result.gram_rows)
         return new_state, result.x, info
 
     # -------------------------------------------------------- velocity field
@@ -1740,6 +1745,7 @@ class System:
                 with span("fetch_info"):
                     iters = int(info.iters)
                     cycles = int(info.cycles)
+                    gram_rows = int(info.gram_rows)
                     refines = int(info.refines)
                     residual_true = float(info.residual_true)
                     converged = bool(info.converged)
@@ -1811,7 +1817,9 @@ class System:
                             # mixed-precision inner restarts still register)
                             "collective_rounds": collective_rounds(
                                 iters, cycles, p.gmres_block_s,
-                                restart=p.gmres_restart),
+                                restart=p.gmres_restart,
+                                gram_rows=gram_rows),
+                            "gram_rows": gram_rows,
                             "residual": residual,
                             "residual_true": residual_true,
                             "fiber_error": fiber_error, "accepted": accept,

@@ -243,3 +243,29 @@ def test_block_df_tile_compiles_inside_the_mesh_step(topo):
                     st((n_dev * blocks, 256, 256), F32),
                     st((n_dev * blocks, 256), jnp.float64))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_gmres_live_row_walk_compiles(topo, n_dev):
+    """The Krylov loop's chunk walk at the fiber cells' size (16,384 float64
+    unknowns a chip, a basis of 101 rows): a `dynamic-slice` of the basis
+    under a trip count computed from `k`, and on four chips the `psum` of
+    `parallel.spmd._make_rdot` INSIDE that loop."""
+    from skellysim_tpu.parallel.mesh import FIBER_AXIS
+    from skellysim_tpu.parallel.spmd import _make_rdot
+    from skellysim_tpu.solver import gmres
+
+    rows = 16384
+    mesh = Mesh(topo.devices[:n_dev], (FIBER_AXIS,))
+    sharded = NamedSharding(mesh, P(FIBER_AXIS))
+
+    def local(d, b):
+        return gmres(lambda v: d * v, b, tol=1e-5, restart=100, maxiter=1000,
+                     rdot=_make_rdot(FIBER_AXIS, rows)).x
+
+    step = jax.shard_map(local, mesh=mesh, in_specs=P(FIBER_AXIS),
+                         out_specs=P(FIBER_AXIS), check_vma=False)
+    st = jax.ShapeDtypeStruct((n_dev * rows,), jnp.float64, sharding=sharded)
+    text = _compile(step, st, st)
+    assert "dynamic-slice" in text
+    assert ("all-reduce" in text) == (n_dev > 1)

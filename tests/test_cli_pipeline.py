@@ -95,6 +95,8 @@ def test_cli_metrics_file_schema_pinned(tmp_path):
         assert rec["accepted"] and rec["residual"] < 1e-8
         assert rec["residual_true"] < 1e-7
         assert rec["refines"] >= 0 and rec["loss_of_accuracy"] is False
+        # two Gram passes an iteration, each over at least one chunk of rows
+        assert rec["gram_rows"] >= 2 * rec["iters"] > 0
     # trial-step index: contiguous from 0 within one run
     assert [rec["step"] for rec in lines] == list(range(len(lines)))
 

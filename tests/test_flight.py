@@ -292,7 +292,7 @@ def test_spmd_ring_matches_single_chip():
 
 def _metrics_line(member=None, flight=None, **over):
     rec = {"step": 0, "t": 0.1, "dt": 0.01, "iters": 3, "gmres_cycles": 1,
-           "collective_rounds": 11, "residual": 1e-11,
+           "collective_rounds": 11, "gram_rows": 96, "residual": 1e-11,
            "residual_true": 1e-11, "fiber_error": 1e-9, "accepted": True,
            "refines": 0, "loss_of_accuracy": False, "health": 0,
            "guard_retries": 0, "nucleations": 0, "catastrophes": 0,

@@ -1,10 +1,18 @@
 """GMRES solver tests against dense numpy solves."""
 
-import numpy as np
+import importlib
 
+import numpy as np
+import pytest
+
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from skellysim_tpu.solver import gmres
+
+#: the module (the package re-exports the function under the same name)
+gmres_mod = importlib.import_module("skellysim_tpu.solver.gmres")
 
 
 def _system(n, seed, cond_boost=0.0):
@@ -195,6 +203,15 @@ def test_collective_rounds_formula():
     # iterations across only 2 sweeps at restart=30) still moves the metric
     assert collective_rounds(300, 2, 1, restart=30) == 3 * 300 + 2 * 10
     assert collective_rounds(10, 2, 1, restart=100) == 3 * 10 + 2 * 2
+    # with the solve's gram_rows the Gram rounds are exact: one reduction a
+    # chunk of live rows a pass. 20 iterations of one cycle walk 1 chunk a
+    # pass while k + 1 <= C and 2 after
+    C = gmres_mod._GRAM_CHUNK
+    rows = sum(2 * C * (-(-(k + 1) // C)) for k in range(C + 4))
+    assert (collective_rounds(C + 4, 1, 1, restart=100, gram_rows=rows)
+            == 2 * C + 4 * 4 + (C + 4) + 2)
+    # a basis shorter than a chunk is one chunk of restart + 1 rows
+    assert collective_rounds(3, 1, 1, restart=4, gram_rows=3 * 2 * 5) == 11
 
 
 def test_gmres_ir_block_reaches_tol():
@@ -214,3 +231,153 @@ def test_gmres_ir_block_reaches_tol():
     assert bool(res.converged)
     explicit = np.linalg.norm(A @ np.asarray(res.x) - b) / np.linalg.norm(b)
     assert explicit <= 1e-9
+
+
+# --------------------------------------------- live-row basis products
+
+def _twin_icgs(V, w, k, n_restart, rdot):
+    """The full-basis orthogonalisation the live-row walk replaced, verbatim:
+    one masked product over all ``n_restart + 1`` rows a pass."""
+    keep = jnp.arange(n_restart + 1, dtype=jnp.int32) <= k
+    h = jnp.zeros(n_restart + 1, dtype=w.dtype)
+    for _ in range(2):
+        proj = jnp.where(keep, rdot(V, w), 0.0)   # [m+1] masked <v_i, w>
+        w = w - proj @ V
+        h = h + proj
+    return w, h
+
+
+def _twin_back_substitute(H, g, k):
+    """The m-trip masked back-substitution the live-row one replaced,
+    verbatim."""
+    m = H.shape[1]
+    dtype = H.dtype
+    idx = jnp.arange(m, dtype=jnp.int32)
+    active = idx < k
+
+    def back_sub(i, y):
+        j = m - 1 - i
+        hjj = H[j, j]
+        rhs = g[j] - jnp.dot(H[j, :], y)
+        yj = jnp.where(active[j], rhs / jnp.where(hjj != 0.0, hjj, 1.0), 0.0)
+        return y.at[j].set(yj)
+
+    return lax.fori_loop(0, m, back_sub, jnp.zeros(m, dtype=dtype))
+
+
+def _full_basis_twin(monkeypatch):
+    """Route `gmres` through the twins above: every basis product over all
+    rows. `gmres` is jitted on its (static) callables, so a solve with a
+    fresh matvec traces the patched helpers."""
+    monkeypatch.setattr(
+        gmres_mod, "_icgs", lambda V, w, k, rdot: (
+            *_twin_icgs(V, w, k, V.shape[0] - 1, rdot), 2 * V.shape[0]))
+    monkeypatch.setattr(gmres_mod, "_back_substitute", _twin_back_substitute)
+    monkeypatch.setattr(gmres_mod, "_combine_live",
+                        lambda y, V, k: y @ V[:y.shape[0]])
+
+
+def _shift_system(n, k, seed):
+    """A = blockdiag(cyclic shift of order k, I): the minimal polynomial of
+    A is x^k - 1, so GMRES from a generic b makes little progress for k - 1
+    iterations and is exact at the k-th — a cycle that ends at k."""
+    A = np.eye(n)
+    A[:k, :k] = np.roll(np.eye(k), 1, axis=0)
+    return A, np.random.default_rng(seed).standard_normal(n)
+
+
+def _live_cases():
+    C = gmres_mod._GRAM_CHUNK
+    cases = [pytest.param(("shift", k), id=f"k={name}")
+             for name, k in (("1", 1), ("C-1", C - 1), ("C", C),
+                             ("C+1", C + 1), ("2C", 2 * C))]
+    return cases + [pytest.param(("restart", 25), id="restart=25")]
+
+
+@pytest.mark.parametrize("case", _live_cases())
+def test_gmres_live_rows_match_full_basis_twin(case, monkeypatch):
+    """The live-row walk is the full-basis solver less the zero rows: `x`,
+    `iters`, `residual` agree with the twin to 1e-13 for cycles that end
+    below, at and above a chunk boundary and for full cycles with restarts
+    (a basis of 26 rows: the last chunk starts early); the Gram passes
+    contract no more than the chunk-padded live rows, and `gram_rows`
+    is what a counting rdot saw."""
+    C = gmres_mod._GRAM_CHUNK
+    kind, arg = case
+    if kind == "shift":
+        n = 3 * C
+        A, b = _shift_system(n, arg, seed=arg)
+        kw = dict(tol=1e-10, restart=2 * C + 8, maxiter=200)
+    else:
+        A, b = _system(100, 3, cond_boost=-0.6)
+        kw = dict(tol=1e-10, restart=arg, maxiter=400)
+    A, b = jnp.asarray(A), jnp.asarray(b)
+
+    seen = []
+
+    def rdot(Av, w):
+        if Av.ndim == 2:          # a Gram product (norms pass a vector)
+            jax.debug.callback(lambda: seen.append(Av.shape[0]))
+        return Av @ w
+
+    live = gmres(lambda v: A @ v, b, rdot=rdot, **kw)
+    jax.effects_barrier()
+    assert bool(live.converged)
+    if kind == "shift":
+        assert int(live.iters) == arg and int(live.cycles) == 1
+        # two passes an iteration over ceil((j + 1) / C) chunks of C rows
+        assert len(seen) == sum(2 * (-(-(j + 1) // C)) for j in range(arg))
+    else:
+        assert int(live.cycles) > 1 and int(live.iters) > arg
+    assert set(seen) == {min(C, kw["restart"] + 1)}
+    assert int(live.gram_rows) == sum(seen)
+    assert sum(seen) < 2 * int(live.iters) * (kw["restart"] + 1)
+
+    _full_basis_twin(monkeypatch)
+    twin = gmres(lambda v: A @ v, b, **kw)
+    assert int(twin.gram_rows) == 2 * int(twin.iters) * (kw["restart"] + 1)
+    assert int(live.iters) == int(twin.iters)
+    scale = float(jnp.linalg.norm(twin.x))
+    assert float(jnp.linalg.norm(live.x - twin.x)) <= 1e-13 * scale
+    # a residual that is exact to roundoff (the shift systems': a few eps
+    # of ||b||) has no digits to agree on: the floor is absolute
+    np.testing.assert_allclose(float(live.residual), float(twin.residual),
+                               rtol=1e-13, atol=1e-15)
+
+
+@pytest.mark.parametrize("k", [0, 1, 7, 30])
+def test_back_substitution_bitwise_equals_full_trip_twin(k):
+    """Skipping the rows from k on changes no bit of `y`."""
+    m = 30
+    rng = np.random.default_rng(k)
+    H = jnp.asarray(np.triu(rng.standard_normal((m + 1, m)))
+                    + 3.0 * np.eye(m + 1, m))
+    g = jnp.asarray(rng.standard_normal(m + 1))
+    y = gmres_mod._back_substitute(H, g, jnp.int32(k))
+    assert np.array_equal(np.asarray(y),
+                          np.asarray(_twin_back_substitute(H, g, jnp.int32(k))))
+    assert not np.asarray(y)[k:].any()
+
+
+def test_gmres_vmap_members_with_different_live_rows():
+    """Under vmap the chunk walk runs to the longest member's count with
+    masked carries: each member keeps its solo `x`, `iters` and `gram_rows`
+    (beside test_ensemble.py::test_gmres_vmap_masked_convergence)."""
+    C = gmres_mod._GRAM_CHUNK
+    n = 3 * C
+    ks = (3, C + 4)
+    systems = [_shift_system(n, k, seed=k) for k in ks]
+    As = jnp.stack([jnp.asarray(A) for A, _ in systems])
+    bs = jnp.stack([jnp.asarray(b) for _, b in systems])
+
+    def solve(A, b):
+        return gmres(lambda v: A @ v, b, tol=1e-10, restart=2 * C + 8,
+                     maxiter=200)
+
+    batched = jax.jit(jax.vmap(solve))(As, bs)
+    for i, k in enumerate(ks):
+        solo = solve(As[i], bs[i])
+        assert int(solo.iters) == k == int(batched.iters[i])
+        assert int(batched.gram_rows[i]) == int(solo.gram_rows)
+        np.testing.assert_allclose(np.asarray(batched.x[i]),
+                                   np.asarray(solo.x), rtol=1e-13, atol=1e-13)

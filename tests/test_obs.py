@@ -431,6 +431,9 @@ def test_run_metrics_and_trace_render_through_summarize(tmp_path):
         # last ring row's explicit residual is the step's residual_true
         assert hist[-1][2] == pytest.approx(rec["residual_true"])
         assert hist[-1][0] == rec["iters"]
+        # the Gram passes walk the live rows of the basis, not all of it
+        assert 0 < rec["gram_rows"] < 2 * rec["iters"] * (
+            system.params.gmres_restart + 1)
 
     evs = [json.loads(ln) for ln in open(t)]
     kinds = [e["ev"] for e in evs]
@@ -448,6 +451,9 @@ def test_run_metrics_and_trace_render_through_summarize(tmp_path):
                     "== solver convergence =="):
         assert section in report
     assert "run/step" in report and "system.solve" in report
+    rows = [r["gram_rows"] / (2 * r["iters"]) for r in recs]
+    assert (f"gram rows/pass: mean {sum(rows) / 2:.1f}  max {max(rows):.1f}"
+            in report)
 
 
 @pytest.mark.slow
