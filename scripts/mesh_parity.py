@@ -10,6 +10,19 @@ first chip. Not a benchmark: single readings on the host clock.
     chiprun --chips 4 -- python scripts/mesh_parity.py [--steps 3] [--seed N]
     chiprun -- python scripts/mesh_parity.py --devices 1 --steps 16
 
+A side that costs four chips' time while one works is better run in a call
+of its own: ``--save TAG`` keeps a side's positions and solutions in
+``chiprun_out/mesh_parity_TAG_d<n>.npz``, and ``--compare D4.npz D1.npz``
+holds two such files to the gate, off the chip (NumPy only):
+
+    chiprun -- python scripts/mesh_parity.py --devices 1 --save ellipsoid \
+        --config chipbench/configs/ellipsoid_mesh4.json
+    chiprun --chips 4 -- python scripts/mesh_parity.py --devices 4 \
+        --save ellipsoid --config chipbench/configs/ellipsoid_mesh4.json
+    python scripts/mesh_parity.py --compare \
+        chiprun_out/mesh_parity_ellipsoid_d4.npz \
+        chiprun_out/mesh_parity_ellipsoid_d1.npz
+
 Every line it prints is kept in ``chiprun_out/mesh_parity.jsonl``.
 """
 
@@ -77,6 +90,55 @@ def run_side(cfg, seed, n_dev, steps, workdir):
     return out
 
 
+def compare(four, one, tol, gate) -> bool:
+    """Steps of the mesh run against the one-device run's: positions and
+    solution within ``gate``, explicit residuals under ``tol``; a line a
+    step, then the last step's seconds side by side."""
+    import numpy as np
+
+    ok = True
+    for i, (a, b) in enumerate(zip(four, one)):
+        n = b["solution"].shape[0]
+        gap_x = float(np.abs(a["x"] - b["x"]).max() / np.abs(b["x"]).max())
+        gap_s = float(np.linalg.norm(a["solution"][:n] - b["solution"])
+                      / np.linalg.norm(b["solution"]))
+        good = (gap_x <= gate and gap_s <= gate
+                and a["row"]["residual_true"] <= tol
+                and b["row"]["residual_true"] <= tol)
+        ok &= good
+        emit(step=i, positions=gap_x, solution=gap_s, gate=gate,
+             residual_true=[a["row"]["residual_true"],
+                            b["row"]["residual_true"]],
+             iters=[a["row"]["iters"], b["row"]["iters"]], ok=good,
+             seconds_d4=round(a["seconds"], 4),
+             seconds_d1=round(b["seconds"], 4))
+    # the last step both sides took: steady on both
+    last = min(len(four), len(one)) - 1
+    d4, d1 = four[last]["seconds"], one[last]["seconds"]
+    emit(d1_step_s=d1, d4_step_s=d4, speedup=d1 / d4,
+         efficiency=d1 / d4 / 4, ok=ok)
+    return ok
+
+
+def save_side(path, side):
+    import numpy as np
+
+    np.savez(path, x=np.stack([s["x"] for s in side]),
+             solution=np.stack([s["solution"] for s in side]),
+             seconds=np.array([s["seconds"] for s in side]),
+             rows=json.dumps([s["row"] for s in side]))
+
+
+def load_side(path):
+    import numpy as np
+
+    with np.load(path) as z:
+        rows = json.loads(str(z["rows"]))
+        return [{"x": x, "solution": sol, "seconds": float(sec), "row": row}
+                for x, sol, sec, row in zip(z["x"], z["solution"],
+                                            z["seconds"], rows)]
+
+
 def require_four_chips():
     import jax
 
@@ -99,12 +161,24 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=2147510101)
     ap.add_argument("--config", default=os.path.join(
         ROOT, "chipbench", "configs", "free_fibers_mesh4.json"))
+    ap.add_argument("--save", metavar="TAG",
+                    help="keep each side in chiprun_out/mesh_parity_TAG_"
+                         "d<n>.npz")
+    ap.add_argument("--compare", nargs=2, metavar=("D4", "D1"),
+                    help="two saved sides against the gate; nothing runs")
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    cfg = json.load(open(args.config))
+    tol = float(cfg["params"].get("gmres_tol", 1e-8))
+    if args.compare:
+        from chip_smoke import MESH_PARITY_GATE
+
+        emit(compare=args.compare, config=os.path.basename(args.config))
+        four, one = (load_side(p) for p in args.compare)
+        return 0 if compare(four, one, tol, MESH_PARITY_GATE) else 1
 
     import jax
-    import numpy as np
 
     jax.config.update("jax_enable_x64", True)
     from chip_smoke import MESH_PARITY_GATE
@@ -114,37 +188,17 @@ def main(argv=None) -> int:
     devs = require_four_chips() if max(sides) > 1 else jax.devices()
     emit(start="mesh_parity", device=devs[0].device_kind, count=len(devs),
          cache=enable_compilation_cache("auto"), seed=args.seed)
-    cfg = json.load(open(args.config))
-    tol = float(cfg["params"]["gmres_tol"])
     with tempfile.TemporaryDirectory(prefix="mesh_parity_") as work:
         steps = [int(n) for n in args.steps.split(",")]
         steps = steps * len(sides) if len(steps) == 1 else steps
         ran = {n: run_side(cfg, args.seed, n, k, work)
                for n, k in zip(sides, steps)}
+    for n, side in ran.items() if args.save else ():
+        save_side(os.path.join(os.path.dirname(OUT),
+                               f"mesh_parity_{args.save}_d{n}.npz"), side)
     if set(ran) != {4, 1}:
         return 0
-    four, one = ran[4], ran[1]
-    ok = True
-    for i, (a, b) in enumerate(zip(four, one)):
-        n = b["solution"].shape[0]
-        gap_x = float(np.abs(a["x"] - b["x"]).max() / np.abs(b["x"]).max())
-        gap_s = float(np.linalg.norm(a["solution"][:n] - b["solution"])
-                      / np.linalg.norm(b["solution"]))
-        good = (gap_x <= MESH_PARITY_GATE and gap_s <= MESH_PARITY_GATE
-                and a["row"]["residual_true"] <= tol
-                and b["row"]["residual_true"] <= tol)
-        ok &= good
-        emit(step=i, positions=gap_x, solution=gap_s, gate=MESH_PARITY_GATE,
-             residual_true=[a["row"]["residual_true"],
-                            b["row"]["residual_true"]], ok=good,
-             seconds_d4=round(a["seconds"], 4),
-             seconds_d1=round(b["seconds"], 4))
-    # the last step both sides took: steady on both
-    last = min(len(four), len(one)) - 1
-    d4, d1 = four[last]["seconds"], one[last]["seconds"]
-    emit(d1_step_s=d1, d4_step_s=d4, speedup=d1 / d4,
-         efficiency=d1 / d4 / 4, ok=ok)
-    return 0 if ok else 1
+    return 0 if compare(ran[4], ran[1], tol, MESH_PARITY_GATE) else 1
 
 
 if __name__ == "__main__":

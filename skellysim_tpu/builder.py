@@ -178,19 +178,32 @@ def build_bodies(cfg_bodies: list, config_dir: str, dtype,
     return groups[0] if len(groups) == 1 else tuple(groups)
 
 
-def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None):
+def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None,
+                    mesh=None):
     """(PeripheryState, PeripheryShape) from config + precompute npz.
 
     ``precond_dtype`` stores M_inv (the preconditioner) at a lower precision
     — the mixed solver only ever applies it in f32, so keeping an f64 copy
-    would waste (3N)^2 * 8 bytes of HBM."""
+    would waste (3N)^2 * 8 bytes of HBM. With a ``mesh`` that divides the
+    shell's nodes, every leaf goes from the host's arrays straight to the
+    shards the mesh step holds it in (the reference's Scatterv'd rows,
+    `periphery.cpp:408-442`): no device ever holds the whole operator, and
+    `System.run`'s placement finds each leaf where it belongs. A shell the
+    mesh does not divide loads whole, and the run loop refuses it in
+    words."""
     data = _load_npz(os.path.join(config_dir, cfg_periphery.precompute_file),
                      "periphery")
+    put = None
+    if mesh is not None:
+        from .parallel.mesh import rows_to_shards, shell_divides
+
+        if shell_divides(len(data["nodes"]), mesh.size, "spmd"):
+            put = rows_to_shards(mesh)
     state = peri.make_state(data["nodes"], data["normals"],
                             data["quadrature_weights"],
                             data["stresslet_plus_complementary"],
                             data["M_inv"], dtype=dtype,
-                            precond_dtype=precond_dtype)
+                            precond_dtype=precond_dtype, put=put)
     shape_name = getattr(cfg_periphery, "shape", "sphere")
     if shape_name == "sphere":
         shape = peri.PeripheryShape(kind="sphere", radius=cfg_periphery.radius)
@@ -287,7 +300,7 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
         pdt = jnp.float32 if mixed else None
         t_load = time.perf_counter()
         shell, shape = build_periphery(config.periphery, config_dir, dtype,
-                                       precond_dtype=pdt)
+                                       precond_dtype=pdt, mesh=mesh)
         # the npz read and the hand-over to the device; the upload itself is
         # not waited for here, a first step is what waits for it
         shell_precompute = {

@@ -37,7 +37,8 @@ ANNOUNCEMENTS = {
     "periphery": ("periphery",
                   ("shape", "nodes", "operator", "operator_dtype",
                    "operator_bytes", "m_inv", "m_inv_dtype", "m_inv_bytes",
-                   "f64_product", "row_block", "precompute", "load_s")),
+                   "f64_product", "row_block", "chips", "rows_per_chip",
+                   "precompute", "load_s")),
 }
 
 

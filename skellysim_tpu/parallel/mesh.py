@@ -76,23 +76,77 @@ def shard_ensemble(ens, mesh: Mesh):
         ens)
 
 
-#: shell placement schema, by PeripheryState FIELD NAME: the two O(n_nodes^2)
+#: shell placement schema, by PeripheryState FIELD NAME, under GSPMD
+#: (`shard_state` + `System.step`): the two O(n_nodes^2)
 #: dense operators row-shard (the analogue of the reference's Scatterv'd shell
 #: rows, `periphery.cpp:408-442`, whose matvec becomes all-gather(density) +
 #: local row-block GEMV, `periphery.cpp:21-47`); every other shell leaf
-#: (nodes/normals/weights/density — all O(n_nodes)) replicates.
+#: (nodes/normals/weights/density — all O(n_nodes)) replicates. The mesh
+#: step (`parallel.spmd`) divides EVERY shell leaf by rows: `shell_specs`
+#: is the one table both programs, `shard_state` and the loader read.
 SHELL_ROW_SHARDED_FIELDS = ("stresslet_plus_complementary", "M_inv")
 
+#: the programs a placed state is for: GSPMD's `System.step`, and the mesh
+#: step `System.step_spmd` that `System.run` steps on a mesh
+STEPS = ("gspmd", "spmd")
 
-def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False):
+
+def shell_specs(shell, step: str, *, sharded: bool = True):
+    """Where each leaf of a `PeripheryState` lives on a mesh, as a
+    `PeripheryState` of `PartitionSpec`s (None for an absent leaf): THE
+    table. ``step="gspmd"``: the dense operators' rows over the fiber axis,
+    the O(n_nodes) vectors replicated. ``step="spmd"``: every leaf by its
+    leading axis — nodes/normals [N, 3], weights [N], density [3N], the
+    operators' rows — node-aligned, so a chip holds N/D nodes and their
+    3N/D rows of everything. ``sharded=False``: the whole shell replicated
+    (the ``allow_replicated_shell`` opt-in of both programs)."""
+    if step not in STEPS:
+        raise ValueError(f"step {step!r}: one of {STEPS}")
+    by_rows = () if not sharded else (
+        shell._fields if step == "spmd" else SHELL_ROW_SHARDED_FIELDS)
+    return type(shell)(*[
+        None if leaf is None else P(FIBER_AXIS) if name in by_rows else P()
+        for name, leaf in zip(shell._fields, shell)])
+
+
+def shell_divides(n_nodes: int, mesh_size: int, step: str) -> bool:
+    """Whether the table can divide a shell of ``n_nodes`` over the mesh:
+    GSPMD needs whole rows a device, the mesh step whole NODES (a node's
+    three density components never straddle shards)."""
+    return (n_nodes if step == "spmd" else 3 * n_nodes) % mesh_size == 0
+
+
+def rows_to_shards(mesh: Mesh):
+    """``put(host_array, dtype)`` -> a device array divided by rows over the
+    mesh (the "spmd" column of `shell_specs`), each shard cut from the HOST
+    array and sent to its own device: no device ever holds more than its
+    share (a `jax.device_put` of a device-resident whole would, until the
+    whole is dropped)."""
+    sharding = NamedSharding(mesh, P(FIBER_AXIS))
+
+    def put(host, dtype):
+        host = np.asarray(host)
+        return jax.make_array_from_callback(
+            host.shape, sharding,
+            lambda index: np.asarray(host[index], dtype=dtype))
+    return put
+
+
+def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False,
+                step: str = "gspmd"):
     """Place a SimState on the mesh, schema-driven off the field names.
 
     - ``fibers``: every leaf of a bucket is [n_fibers]-leading by
       construction (`fibers.container.FiberGroup`), so the whole bucket
       shards along the fiber axis when the mesh divides its fiber count
       (and replicates as a unit otherwise);
-    - ``shell``: per-field spec table (`SHELL_ROW_SHARDED_FIELDS`) — the
-      dense operators row-shard, the O(n_nodes) vectors replicate;
+    - ``shell``: per-field spec table (`shell_specs`) for the program the
+      state is for — ``step="gspmd"`` (`System.step`): the dense operators
+      row-shard, the O(n_nodes) vectors replicate; ``step="spmd"`` (the
+      mesh step, what `System.run` places): every leaf by rows, which is
+      what that program takes and returns, so a placed state is never
+      re-sharded at the program's door (a state in the other layout is a
+      second argument signature to `jit`: a second compile of the step);
     - everything else (time/dt scalars, bodies, point/background sources):
       replicated, the analogue of the reference's rank-0 body ownership.
 
@@ -103,14 +157,14 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False):
     names, not shapes, now decide.
 
     pjit rejects uneven shardings, so the shell rows can only distribute when
-    the mesh size divides 3*n_nodes. Anything else raises: silently
+    the mesh size divides 3*n_nodes (n_nodes for the mesh step). Anything
+    else raises: silently
     replicating an O(n_nodes^2) matrix per device turns the expected O(N/D)
     footprint into D copies of the full operator, an OOM a user would only
     find with a profiler. Pass ``allow_replicated_shell=True`` to opt in for
     small shells.
     """
     fib_sharding = NamedSharding(mesh, P(FIBER_AXIS))
-    row_sharding = NamedSharding(mesh, P(FIBER_AXIS, None))
     rep_sharding = NamedSharding(mesh, P())
 
     from ..fibers.container import FiberGroup, as_buckets
@@ -132,14 +186,12 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False):
 
     shell = state.shell
     if shell is not None:
-        rows = shell.M_inv.shape[0]
-        if rows % mesh.size == 0:
-            big = row_sharding
-        elif allow_replicated_shell:
-            big = rep_sharding
-        else:
+        divides = shell_divides(shell.n_nodes, mesh.size, step)
+        if not divides and not allow_replicated_shell:
+            rows = (f"shell n_nodes ({shell.n_nodes})" if step == "spmd" else
+                    f"shell operator rows (3*n_nodes = {3 * shell.n_nodes})")
             raise ValueError(
-                f"shell operator rows (3*n_nodes = {rows}) are not divisible "
+                f"{rows} are not divisible "
                 f"by the mesh size ({mesh.size}), so the O(n_nodes^2) dense "
                 "operators cannot be row-sharded and would be fully replicated "
                 "on every device. Pick a shell n_nodes that is a multiple of "
@@ -147,13 +199,12 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False):
                 "the per-device memory cost.")
         # place the O(n^2) operators straight to their final sharding (never
         # replicate them first — peak per-device memory would be the full
-        # matrix)
+        # matrix); a leaf that is there already is handed back as it is
+        specs = shell_specs(shell, step, sharded=divides)
         shell = type(shell)(*[
             leaf if leaf is None else
-            jax.device_put(jax.numpy.asarray(leaf),
-                           big if name in SHELL_ROW_SHARDED_FIELDS else
-                           rep_sharding)
-            for name, leaf in zip(shell._fields, shell)])
+            jax.device_put(jax.numpy.asarray(leaf), NamedSharding(mesh, spec))
+            for leaf, spec in zip(shell, specs)])
 
     rest = jax.tree_util.tree_map(
         rep, state._replace(fibers=None, shell=None))

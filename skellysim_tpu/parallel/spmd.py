@@ -66,7 +66,7 @@ from ..solver import gmres, gmres_ir
 from ..system.system import (SimState, StepInfo, _cast_floats, _rewrap_bodies,
                              _rewrap_fibers, body_buckets, fiber_buckets)
 from .compat import pmax
-from .mesh import FIBER_AXIS
+from .mesh import FIBER_AXIS, shell_divides, shell_specs
 
 
 class SpmdSolution(NamedTuple):
@@ -104,7 +104,7 @@ def spmd_shell_mode(state: SimState, mesh: Mesh, *,
                 "are free)")
     if state.shell is None:
         return "none"
-    if state.shell.n_nodes % mesh.size == 0:
+    if shell_divides(state.shell.n_nodes, mesh.size, "spmd"):
         return "sharded"
     if allow_replicated_shell:
         return "replicated"
@@ -128,17 +128,13 @@ def _state_specs(state: SimState, shell_mode: str) -> SimState:
                    for g in buckets)
     fib_spec = (placed[0] if isinstance(state.fibers, fc.FiberGroup)
                 else placed)
-    shell_spec = None
-    if state.shell is not None:
-        if shell_mode == "sharded":
-            # every shell leaf is leading-axis sharded: nodes/normals [N, 3],
-            # weights [N], density [3N], and the dense operators' ROWS;
-            # absent optional fields (node_mask) are empty subtrees
-            shell_spec = type(state.shell)(
-                *[None if leaf is None else P(FIBER_AXIS)
-                  for leaf in state.shell])
-        else:
-            shell_spec = rep(state.shell)
+    # the shell's leaves by `mesh.shell_specs`, the table `shard_state` and
+    # the builder's loader place them by: every leaf leading-axis sharded
+    # (nodes/normals [N, 3], weights [N], density [3N], the dense
+    # operators' ROWS), or the whole shell replicated
+    shell_spec = (None if state.shell is None else
+                  shell_specs(state.shell, "spmd",
+                              sharded=shell_mode == "sharded"))
     return SimState(time=P(), dt=P(), fibers=fib_spec,
                     points=rep(state.points), background=rep(state.background),
                     shell=shell_spec, bodies=rep(state.bodies),
@@ -656,7 +652,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             rhs = jnp.concatenate(rhs_parts)
         # a shard's own blocks: the shapes are one device's
         system._announce_block_precond(caches, body_caches)
-        system._announce_periphery(st)
+        system._announce_periphery(st, chips=n_dev if sharded_shell else 1)
         system._announce_fiber_ops(st, precision)
 
         nonrep_end = fib_size + (shell_size if sharded_shell else 0)

@@ -173,17 +173,23 @@ def build_shell_operator_device(nodes, normals, weights, eta: float = 1.0, *,
 
 
 def make_state(nodes, normals, weights, operator, M_inv, dtype=jnp.float64,
-               precond_dtype=None) -> PeripheryState:
+               precond_dtype=None, put=None) -> PeripheryState:
     """``precond_dtype`` stores M_inv (the preconditioner — accuracy does not
-    matter) in a lower precision, halving its HBM footprint in mixed mode."""
+    matter) in a lower precision, halving its HBM footprint in mixed mode.
+    ``put(host_array, dtype)`` hands each leaf to the device(s) (default:
+    the whole of it to the default device; a mesh run's builder divides
+    every leaf by rows, `parallel.mesh.rows_to_shards`)."""
+    if put is None:
+        def put(a, dt):
+            return jnp.asarray(a, dtype=dt)
     N = len(nodes)
     return PeripheryState(
-        nodes=jnp.asarray(nodes, dtype=dtype),
-        normals=jnp.asarray(normals, dtype=dtype),
-        weights=jnp.asarray(weights, dtype=dtype),
-        M_inv=jnp.asarray(M_inv, dtype=precond_dtype or dtype),
-        stresslet_plus_complementary=jnp.asarray(operator, dtype=dtype),
-        density=jnp.zeros(3 * N, dtype=dtype),
+        nodes=put(nodes, dtype),
+        normals=put(normals, dtype),
+        weights=put(weights, dtype),
+        M_inv=put(M_inv, precond_dtype or dtype),
+        stresslet_plus_complementary=put(operator, dtype),
+        density=put(np.zeros(3 * N), dtype),
     )
 
 
@@ -264,20 +270,25 @@ def _row_blocked(op) -> bool:
     return op.dtype == jnp.float64 and op.shape[0] > 2 * _F64_ROW_BLOCK
 
 
-def describe(shell: PeripheryState) -> dict:
+def describe(shell: PeripheryState, chips: int = 1) -> dict:
     """What a run holds of a shell and how `_apply_operator` multiplies its
     float64 operator, for `System._announce_periphery` (shapes and dtypes
-    only: a traced state serves)."""
-    out = {"nodes": shell.n_nodes}
+    only: a traced state serves). ``shell`` is what ONE chip holds: the
+    whole, or inside the mesh step its share of a shell divided by rows over
+    ``chips`` devices — the shell's own numbers are then the share's times
+    ``chips``, and the product's policy is the share's (it is the share's
+    rows that `_apply_operator` sees there)."""
+    out = {"nodes": shell.n_nodes * chips}
     for name, m in (("operator", shell.stresslet_plus_complementary),
                     ("m_inv", shell.M_inv)):
         dtype = jnp.dtype(m.dtype)
-        out.update({name: f"{m.shape[0]}x{m.shape[1]}",
+        out.update({name: f"{m.shape[0] * chips}x{m.shape[1]}",
                     name + "_dtype": dtype.name,
-                    name + "_bytes": m.size * dtype.itemsize})
+                    name + "_bytes": m.size * dtype.itemsize * chips})
     blocked = _row_blocked(shell.stresslet_plus_complementary)
     return dict(out, f64_product="row_blocks" if blocked else "whole",
-                row_block=_F64_ROW_BLOCK if blocked else 0)
+                row_block=_F64_ROW_BLOCK if blocked else 0, chips=chips,
+                rows_per_chip=shell.stresslet_plus_complementary.shape[0])
 
 
 @jax.named_scope("shell")

@@ -381,20 +381,23 @@ class System:
     #: says (a shell handed to `make_state` by anyone else has no file)
     shell_precompute = None
 
-    def _announce_periphery(self, state):
+    def _announce_periphery(self, state, chips: int = 1):
         """Trace-time (once per build, like `_announce_block_precond`): what
         this solve holds of a shell — shape, nodes, the stored operator and
-        `M_inv` (shape, dtype, bytes), the precompute file they were read
-        from and in how many seconds, and how the float64 operator is
-        multiplied (`periphery._apply_operator`: ``row_blocks`` of
-        ``row_block`` rows, or ``whole``) — in the log and as a
-        ``periphery`` event. Silent without a shell."""
+        `M_inv` (shape, dtype, bytes), over how many chips their rows are
+        divided and how many rows a chip holds, the precompute file they
+        were read from and in how many seconds, and how the float64 operator
+        is multiplied (`periphery._apply_operator`: ``row_blocks`` of
+        ``row_block`` rows, or ``whole``; on a mesh, of a chip's rows) — in
+        the log and as a ``periphery`` event. The mesh step calls it inside
+        its `shard_map`, where ``state`` is one chip's share of ``chips``.
+        Silent without a shell."""
         if state.shell is None:
             return
         src = self.shell_precompute or {}
         fields = dict(
             shape=self.shell_shape.kind if self.shell_shape else "generic",
-            **peri.describe(state.shell),
+            **peri.describe(state.shell, chips),
             precompute=src.get("file", "-"),
             load_s=round(src.get("load_s", 0.0), 3))
         logger.info(
@@ -402,6 +405,7 @@ class System:
             "operator=%(operator)s %(operator_dtype)s %(operator_bytes)dB "
             "m_inv=%(m_inv)s %(m_inv_dtype)s %(m_inv_bytes)dB "
             "f64_product=%(f64_product)s row_block=%(row_block)d "
+            "chips=%(chips)d rows_per_chip=%(rows_per_chip)d "
             "precompute=%(precompute)s load_s=%(load_s).3f", fields)
         obs_tracer.emit("periphery", **fields)
 
@@ -1698,14 +1702,17 @@ class System:
         if self.mesh is not None:
             # a System with a mesh steps the mesh program (`step_spmd`:
             # the same (new_state, solution, StepInfo) triple, so the
-            # loop's body stays one body); the state is placed at entry,
-            # `bucketize` and a `run(max_steps=1)` re-entry hand over leaves
-            # that are not (placing a placed leaf is a no-op)
+            # loop's body stays one body); the state is placed at entry as
+            # that program takes and returns it (`shard_state`'s "spmd"
+            # column), so every step of the loop and of a re-entry sees one
+            # argument signature: `bucketize` hands over leaves that are not
+            # placed, the builder's shell and a `run(max_steps=1)` re-entry
+            # leaves that are (placing a placed leaf is a no-op)
             step_fn, clock = self._mesh_step(rng, donate_ok)
             with span("place_state", devices=self.mesh.size):
                 from ..parallel import shard_state
 
-                state = shard_state(state, self.mesh)
+                state = shard_state(state, self.mesh, step="spmd")
 
         def clock_read(st):
             # the loop's clock, as the host holds it: two scalar fetches
@@ -1880,8 +1887,15 @@ class System:
             # `fused_ring_fallback` faults: `parallel.ring._ring_or_fused`)
             from ..parallel import FIBER_AXIS
 
+            # ... and a shell's leaves are divided by rows, as `place_state`
+            # left them (`shard_state` refuses a shell it cannot divide)
+            shell = state.shell
             obs_tracer.emit("mesh", devices=self.mesh.size, axis=FIBER_AXIS,
-                            step="spmd")
+                            step="spmd",
+                            shell="none" if shell is None else "rows",
+                            shell_rows_per_chip=(
+                                0 if shell is None
+                                else shell.solution_size // self.mesh.size))
         return state
 
 

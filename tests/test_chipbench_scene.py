@@ -70,3 +70,96 @@ def test_ellipsoid_256_is_the_examples_own_construction(tmp_path):
     assert (_scene_tests._saved(scene.build_config(cfg, 11),
                                 tmp_path / "ours.toml")
             == _scene_tests._saved(theirs, tmp_path / "theirs.toml"))
+
+
+# ------------------------------------------- `ellipsoid_mesh4` (PR 36)
+
+#: sha256 of `build_config(ellipsoid_mesh4, seed).save(path)`'s file, recorded
+#: when the configuration was added (PR 36)
+ELLIPSOID_MESH4_PINS = {
+    0: "190f841b8d1bbedf9dc504ff680d021ed80622ddd060fcb027f74dfb65f2605f",
+    5: "c3fd8ecc0d00d06fbe5602e83e007c11fb67daf21f6b12d58370c6d2969e8f8c",
+    2147531004:
+        "e12baddc51c805b40c2e153425eaa3c5c9fbc409169b761c18f6ae182ec01b0d",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(ELLIPSOID_MESH4_PINS))
+def test_ellipsoid_mesh4_builds_the_toml_it_was_added_with(tmp_path, seed):
+    import scene
+
+    cfg = _scene_tests._configuration("ellipsoid_mesh4")
+    toml = _scene_tests._saved(scene.build_config(cfg, seed),
+                               tmp_path / "c.toml")
+    assert hashlib.sha256(toml).hexdigest() == ELLIPSOID_MESH4_PINS[seed]
+    # the one-chip twin's shell, to the key: its precompute is a cache hit
+    assert scene.precompute_key(cfg) == ELLIPSOID_256_PRECOMPUTE_KEY
+
+
+def test_ellipsoid_mesh4_is_ellipsoid_256_on_a_mesh_of_four(tmp_path):
+    """Every key as the one-chip twin's but the fiber count and
+    ``params.mesh_devices``; the TOML is `examples/ellipsoid/gen_config.py`'s
+    own construction at 1,024 fibers with the mesh asked for."""
+    import scene
+
+    cfg = _scene_tests._configuration("ellipsoid_mesh4")
+    twin = _scene_tests._configuration("ellipsoid_256")
+    assert cfg["reduced"] == ["n_fibers"] and cfg["published"] == {
+        "n_fibers": 2000}
+    assert cfg["params"] == dict(twin["params"], mesh_devices=4)
+    assert cfg["fibers"] == dict(twin["fibers"], n_fibers=1024)
+    assert cfg["periphery"] == twin["periphery"] and cfg["bodies"] == []
+    assert cfg["reference"] == twin["reference"] == "clamped_shell_step"
+    assert set(cfg["limits"]) == set(twin["limits"])
+    assert "correct_cannot_see" not in cfg["guarantees"]
+    assert cfg["guarantees"]["gmres_tol"] == cfg["limits"]["ref_residual"]
+    theirs = _scene_tests._example_construction("ellipsoid_toy", cfg, 11)
+    assert len(theirs.fibers) == 1024 and theirs.periphery.n_nodes == 8000
+    theirs.params.t_final = cfg["params"]["t_final"]
+    theirs.params.mesh_devices = 4
+    assert (_scene_tests._saved(scene.build_config(cfg, 11),
+                                tmp_path / "ours.toml")
+            == _scene_tests._saved(theirs, tmp_path / "theirs.toml"))
+
+
+def test_ellipsoid_mesh_toy_builds_on_four_devices_and_steps(tmp_path,
+                                                             monkeypatch):
+    """The toy cut of `ellipsoid_mesh4` (`toy/ellipsoid_mesh_toy.json`)
+    through `run.build` -> `System.run`, as the window drives it: the mesh
+    step on four of the forced CPU devices, the shell's rows divided from
+    the builder on, one build of the mesh program."""
+    import json
+
+    import jax
+    import run
+    import scene
+
+    jax.config.update("jax_enable_x64", True)       # as `run.Cell` does
+    monkeypatch.setattr(scene, "CACHE_DIR", str(tmp_path / "cache"))
+    cfg = _scene_tests._toy("ellipsoid_mesh_toy")
+    assert cfg["params"]["mesh_devices"] == 4
+    flat = _scene_tests._toy("ellipsoid_toy")
+    assert cfg["fibers"] == flat["fibers"]
+    assert cfg["periphery"] == flat["periphery"]
+    system, state, rng, writer, _, info = run.build(
+        cfg, 2**31 + 9, str(tmp_path / "scene"))
+    assert info["precompute"] == "miss" and system.mesh.size == 4
+    rows_a_chip = 3 * cfg["periphery"]["n_nodes"] // 4
+    for leaf in (state.shell.stresslet_plus_complementary,
+                 state.shell.M_inv):
+        assert {s.data.shape[0] for s in leaf.addressable_shards} == {
+            rows_a_chip}
+    metrics_path = str(tmp_path / "metrics.jsonl")
+    for _ in range(2):
+        state = system.run(state, writer=writer.write_frame, rng=rng,
+                           metrics_path=metrics_path, max_steps=1)
+    writer.close()
+    with open(metrics_path) as fh:
+        rows = [json.loads(line) for line in fh]
+    assert len(rows) == 2 and len(system._spmd_steps) == 1
+    assert all(r["accepted"] and r["health"] == 0
+               and r["residual_true"] <= 1e-8 for r in rows)
+    assert all(r["fiber_error"] > 0 for r in rows)  # the fibers carry force
+    snap = run.snapshot(state)
+    assert snap["shell_density"].shape == (3 * cfg["periphery"]["n_nodes"],)
+    assert len(state.shell.density.sharding.device_set) == 4
