@@ -11,7 +11,8 @@ user calls — config dataclasses -> `skellysim_tpu.precompute.main` ->
   7 iterations), four steps;
 * `examples/free_fibers_10k` cut to 1,024 fibers x 64 nodes (65,536 nodes,
   ~4.3 Gpairs per matvec — the pair-tile hot loop one fiber never enters),
-  two steps, once with the default tile and once with kernel_impl="pallas";
+  two steps, once with kernel_impl="exact" (XLA's tile, named: the default
+  "auto" is the Pallas tile on a chip) and once with kernel_impl="pallas";
 
 and checks, not only prints: every step's explicit residual <= gmres_tol,
 the body moves along its force as slowly as the wall makes it, and the
@@ -466,7 +467,7 @@ def _fiber_positions(reader, i: int):
 
 
 def run_fibers(sz: dict, workdir: str, seed: int) -> None:
-    """The free-fiber scene, default tile then Pallas tile, and the two
+    """The free-fiber scene, XLA's exact tile then Pallas tile, and the two
     compared: flows on the scene's own nodes and the positions the two
     runs end at."""
     import jax.numpy as jnp
@@ -518,7 +519,7 @@ def run_fibers(sz: dict, workdir: str, seed: int) -> None:
             moved = ends["exact"] - x0
             err = rel_err(ends["pallas"] - x0, moved)
             info["displacement_norm"] = float(np.linalg.norm(moved))
-            check(err < 1e-5, "pallas run vs default run: fiber "
+            check(err < 1e-5, "pallas run vs exact run: fiber "
                   "displacements agree to 1e-5", err=err)
         rng = np.random.default_rng(seed)
         r = jnp.asarray(x0.reshape(-1, 3), jnp.float32)
@@ -565,9 +566,9 @@ def run_mesh(sz: dict, seed: int) -> None:
         u_f = kernels.stokeslet_direct(r, r, f, 1.0)
         u_S = kernels.stresslet_direct(r, r, S, 1.0)
         # "exact" is the ppermute ring around the XLA tile: the f32 pair
-        # flows of the mesh step's default inner operator, held against the
-        # one-device step's (the step parity below converges both sides to
-        # one f64 system and cannot see an inner operator that is off)
+        # flows of the mesh step's inner operator under that name, held
+        # against the one-device step's (the step parity below converges both
+        # sides to one f64 system and cannot see an inner operator that is off)
         for impl in ("exact", "pallas"):
             e1 = rel_err(ring_stokeslet(r, r, f, 1.0, mesh=mesh, impl=impl),
                          u_f)

@@ -26,7 +26,14 @@ SECTIONS = [
      "Global simulation parameters (reference `Params`, "
      "`include/params.hpp:7-67`). The TPU-specific knobs at the bottom have "
      "no reference analogue; see `skellysim_tpu/params.py` for the runtime "
-     "semantics of each."),
+     "semantics of each. Three of them default to `'auto'` and follow what "
+     "the code can observe: `solver_precision` (mixed for a float64 state on "
+     "an accelerator, full elsewhere), `refine_pair_impl` (the Pallas "
+     "double-float tile on a TPU, the XLA double-float blocks on another "
+     "accelerator, native float64 on a CPU) and `kernel_impl` (the fused "
+     "Pallas tile on a TPU for pair sums whose operands are not float64, "
+     "XLA's `'exact'` tile everywhere else; `'exact'`, `'mxu'`, `'df'`, "
+     "`'pallas'` and `'pallas_df'` ask for one tile by name)."),
     (schema.DynamicInstability, "[dynamic_instability]",
      "Microtubule nucleation/catastrophe dynamics "
      "(`params.hpp:21-35`); active when `nucleation_rate > 0`."),

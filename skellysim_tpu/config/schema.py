@@ -214,7 +214,7 @@ class Params:
     periodic_box: list = field(default_factory=list)
     # target relative accuracy of the spectral Ewald evaluator
     spectral_tol: float = 1e-6
-    kernel_impl: str = "exact"
+    kernel_impl: str = "auto"
     refine_pair_impl: str = "auto"
     ewald_min_sources: int = 2048
     # coupled-solve preconditioner: "gs" (block Gauss-Seidel, shell-first

@@ -30,6 +30,10 @@ def _fmt_s(v: float) -> str:
 #: events a build announces once, and how `render` prints them: event ->
 #: (section title, the fields of its line, in order)
 ANNOUNCEMENTS = {
+    "pair_tile": ("pair tile (Krylov loop)",
+                  ("impl", "requested", "backend", "dtype")),
+    "refine_tile": ("refinement tile (f64 residual and prep flows)",
+                    ("impl", "requested", "backend")),
     "block_precond": ("block preconditioner",
                       ("apply", "dtype", "fibers", "bodies")),
     "fiber_ops": ("fiber operators",

@@ -234,18 +234,30 @@ def oseen_block(trg, src, density, eta, reg, epsilon_distance):
     return jnp.einsum("ts,sk->tk", fr, density) + jnp.einsum("ts,tsk->tk", gr * df, d)
 
 
-def pallas_impl_for(impl: str, *arrays) -> str:
-    """Resolve ``impl="pallas"`` against the pallas tier's dtype contract.
+def resolve_impl(impl: str, *operands) -> str:
+    """The f32 pair tile a seam runs for the name it was handed and the
+    operands (arrays or dtypes) it holds — the ONE resolver every seam that
+    takes a tile name calls before it compares the name (`stokeslet_direct`
+    / `stresslet_direct` here, the ring evaluator in `parallel.ring`), so
+    none of them sees ``"auto"`` and the contract cannot drift between them.
 
-    The pallas tier is f32-only: any f64 operand (full-precision solves,
-    mixed-mode refinement flows that resolve to a concrete impl name)
-    downgrades to the exact XLA path, mirroring how the f64 accuracy tier
-    stays off the MXU tiles. One predicate shared by the direct seam here
-    and the ring evaluator (`parallel.ring`) so the contract cannot drift
-    between them. Non-pallas names pass through untouched.
+    ``"auto"`` (`Params.kernel_impl`'s default) follows what the code can
+    observe, as ``solver_precision="auto"`` and ``refine_pair_impl="auto"``
+    do: the fused Pallas tile on a TPU when no operand is float64 (the
+    mixed tier's f32 interior, an f32 state), the exact XLA tile everywhere
+    else (a CPU, another accelerator, the full tier's f64 flows) — silently,
+    that is the rule and not a fallback.
+
+    ``"pallas"`` BY NAME is f32-only too: any f64 operand downgrades to the
+    exact XLA path, and says so. Every other name passes through untouched.
     """
-    if impl == "pallas" and any(jnp.asarray(a).dtype == jnp.float64
-                                for a in arrays):
+    if impl not in ("auto", "pallas"):
+        return impl
+    f64 = any(jnp.result_type(a) == jnp.float64 for a in operands)
+    if impl == "auto":
+        return ("pallas" if jax.default_backend() == "tpu" and not f64
+                else "exact")
+    if f64:
         # never silent: this runs at trace time, so it says so once per
         # build — in the log and as a ``fault`` event for `obs summarize`
         # (like `parallel.compat._fused_fallback` does for the ring)
@@ -270,6 +282,8 @@ def stokeslet_direct(r_src, r_trg, f_src, eta, *, block_size: int = 4096,
     ``_SRC_CHUNK_THRESHOLD`` are scanned in ``source_block`` chunks so peak
     memory stays O(block_size * source_block) at BASELINE scale (640k nodes).
 
+    ``impl`` names the tile (`resolve_impl`: ``"auto"`` is ``"pallas"`` on
+    a TPU for operands that are not float64 and ``"exact"`` everywhere else).
     ``impl="mxu"`` selects the matmul-form tile (`stokeslet_block_mxu`) that
     moves the O(N^2 * 3) contractions onto the MXU — see its numerics caveat
     and per-source-block recentering. ``impl="df"`` evaluates in double-float
@@ -299,11 +313,12 @@ def stokeslet_direct(r_src, r_trg, f_src, eta, *, block_size: int = 4096,
             r_src, r_trg, f_src, eta, block_size=min(block_size, 1024),
             source_block=source_block or 4096)
         return u.astype(r_trg.dtype)  # see the pallas_df branch
-    impl = pallas_impl_for(impl, r_trg, r_src, f_src)
+    impl = resolve_impl(impl, r_trg, r_src, f_src)
     if impl == "pallas":
         # fused VMEM-tile kernel (`ops.pallas_kernels`); Mosaic lowering on
-        # real TPUs (measured ~53 Gpairs/s vs ~15 for the XLA path on v5e),
-        # interpret mode on CPU (tests / fallback).
+        # real TPUs (84.7 Gpairs/s in the step against 13.7-19.2 for the XLA
+        # path on a v5e; ledger, PR 29 and PR 36), interpret mode on CPU
+        # (tests / fallback).
         from .pallas_kernels import stokeslet_pallas
 
         return stokeslet_pallas(r_src, r_trg, f_src, eta,
@@ -346,7 +361,7 @@ def stresslet_direct(r_dl, r_trg, f_dl, eta, *, block_size: int = 4096,
             r_dl, r_trg, f_dl, eta, block_size=min(block_size, 1024),
             source_block=source_block or 4096)
         return u.astype(r_trg.dtype)  # see stokeslet_direct's pallas_df branch
-    impl = pallas_impl_for(impl, r_trg, r_dl, f_dl)
+    impl = resolve_impl(impl, r_trg, r_dl, f_dl)
     if impl == "pallas":
         # see `stokeslet_direct`'s pallas branch
         from .pallas_kernels import stresslet_pallas

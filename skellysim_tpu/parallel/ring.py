@@ -35,7 +35,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..obs import tracer as obs_tracer
 from ..ops.kernels import (DEFAULT_EPS, DEFAULT_REG, oseen_block,
-                           pallas_impl_for, stokeslet_block,
+                           resolve_impl, stokeslet_block,
                            stokeslet_block_mxu, stresslet_block,
                            stresslet_block_mxu)
 from .compat import fused_ring_mode
@@ -195,8 +195,9 @@ def ring_flow_local(kind: str, impl: str, r_trg, src, payload, eta, *,
 
     ``kind`` picks the kernel ("stokeslet" payload [n, 3] forces,
     "stresslet" payload [n, 3, 3]); ``impl`` any of the tile names
-    (exact/mxu/pallas/df/pallas_df — pallas falls back per
-    `ops.kernels.pallas_impl_for`, interpret-mode unrolling per
+    (auto/exact/mxu/pallas/df/pallas_df — auto follows the backend and
+    the operands' dtype, pallas falls back on f64 operands, both per
+    `ops.kernels.resolve_impl`; interpret-mode unrolling per
     `_pallas_interpret`). ``ring=True`` accumulates over the rotating
     source blocks (targets stay resident — every shard's targets see all
     sources after n_dev-1 `ppermute` hops); ``ring=False`` evaluates ONE
@@ -235,7 +236,7 @@ def ring_flow_local(kind: str, impl: str, r_trg, src, payload, eta, *,
         # seam contract: DF accumulates f64, callers get the target dtype
         return (u * scale).astype(r_trg.dtype)
 
-    impl = pallas_impl_for(impl, r_trg, src, payload)
+    impl = resolve_impl(impl, r_trg, src, payload)
     block = _ring_block(impl, exact_block, mxu_block, pallas_name)
     scale = 1.0 / (8.0 * math.pi * eta)
     if ring:
@@ -258,7 +259,7 @@ def ring_stokeslet(r_src, r_trg, f_src, eta, *, mesh: Mesh,
     shard's spatial extent.
     """
     spec = P(axis_name)
-    impl = pallas_impl_for(impl, r_trg, r_src, f_src)
+    impl = resolve_impl(impl, r_trg, r_src, f_src)
     block = _ring_block(impl, stokeslet_block, stokeslet_block_mxu,
                         "stokeslet_pallas_block")
     return _ring_eval(block, mesh, axis_name, (spec, spec, spec),
@@ -273,7 +274,7 @@ def ring_stresslet(r_dl, r_trg, f_dl, eta, *, mesh: Mesh,
     """Ring-parallel stresslet (double-layer) sum
     (`ops.kernels.stresslet_direct`); ``f_dl`` is [n_src, 3, 3]."""
     spec = P(axis_name)
-    impl = pallas_impl_for(impl, r_trg, r_dl, f_dl)
+    impl = resolve_impl(impl, r_trg, r_dl, f_dl)
     block = _ring_block(impl, stresslet_block, stresslet_block_mxu,
                         "stresslet_pallas_block")
     return _ring_eval(block, mesh, axis_name,

@@ -61,6 +61,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..bodies import bodies as bd
 from ..fibers import container as fc
+from ..ops import kernels
 from ..periphery import periphery as peri
 from ..solver import gmres, gmres_ir
 from ..system.system import (SimState, StepInfo, _cast_floats, _rewrap_bodies,
@@ -219,7 +220,9 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
     # mixed f64: prep flows AND the refinement-residual matvec both run
     # through the refinement tile (System._prep / _solve_impl semantics)
     refine = precision == "mixed" and is_f64
-    prep_impl = hi_impl = (system._refine_impl if refine else p.kernel_impl)
+    prep_impl = hi_impl = (
+        system._refine_impl if refine
+        else kernels.resolve_impl(p.kernel_impl, state.time.dtype))
     if precision == "mixed":
         system._announce_refine_tile(hi_impl)
     precond_dtype = jnp.float32 if precision == "mixed" else None
@@ -654,6 +657,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
         system._announce_block_precond(caches, body_caches)
         system._announce_periphery(st, chips=n_dev if sharded_shell else 1)
         system._announce_fiber_ops(st, precision)
+        system._announce_pair_tile(st, precision)
 
         nonrep_end = fib_size + (shell_size if sharded_shell else 0)
         rdot = _make_rdot(axis, nonrep_end)

@@ -167,18 +167,29 @@ class Params:
     # looser default does not cap the converged residual — and at f32 the
     # dense tile's own rounding is ~1e-6 on big sums anyway
     tree_tol: float = 1e-4
-    # pairwise-kernel tile implementation: "exact" (displacement-tensor form,
-    # the reference's semantics bit-for-bit), "mxu" (matmul form — the
+    # pairwise-kernel tile implementation of the f32 pair sums (the Krylov
+    # loop's interior in the mixed tier, every pair sum of an f32 state):
+    # "auto" (the DEFAULT, PR 37) follows what the code can observe, as
+    # solver_precision and refine_pair_impl do: "pallas" on a TPU for
+    # operands that are not float64, "exact" everywhere else (a CPU, another
+    # accelerator, the full tier's f64 flows) — resolved at the kernel seam
+    # (`ops.kernels.resolve_impl`), so a default-config run on a TPU does
+    # not pay for XLA's HBM-bound pair sums. The explicit names: "exact"
+    # (displacement-tensor form, the reference's semantics bit-for-bit;
+    # XLA's fusions, 6-16 Gpairs/s on a v5e), "mxu" (matmul form — the
     # O(N^2*3) contractions ride the MXU; see kernels.stokeslet_block_mxu's
     # near-field cancellation caveat — for well-separated fiber clouds),
     # "df" (double-float f32, the f64-grade accuracy tier), "pallas"
     # (fused VMEM-tile kernels, `ops.pallas_kernels` — the f32 throughput
     # tier at scale: the Stokeslet tile takes 3.171 ms for 16,384^2 pairs
-    # on a v5e, 84.7 Gpairs/s (ledger, PR 29); f64 operands fall back to
-    # "exact"; interpret mode off-TPU),
+    # on a v5e, 84.7 Gpairs/s (ledger, PR 29); the stresslet tile 2.73 ms
+    # for a shell's 8,000 x 16,384 pairs, 48 Gpairs/s on the host clock,
+    # where XLA's "exact" takes 21.96 ms (my chip run, PR 37);
+    # f64 operands fall back to "exact", with a warning and a fault event;
+    # interpret mode off-TPU),
     # or "pallas_df" (the DF arithmetic fused into Pallas tiles,
     # `ops.pallas_df` — f64-grade accuracy at VMEM-tile throughput)
-    kernel_impl: str = "exact"
+    kernel_impl: str = "auto"
     # solver precision strategy (no reference analogue — the reference is
     # f64-everywhere on CPU; TPU XLA's LuDecomposition is f32-only and the
     # MXU prefers f32/bf16):

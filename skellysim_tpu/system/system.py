@@ -29,7 +29,7 @@ from ..fibers import container as fc
 from ..guard import verdict as _verdict
 from ..obs import tracer as obs_tracer
 from ..obs.compile_log import observed_jit
-from ..ops import block_df, block_precond
+from ..ops import block_df, block_precond, kernels
 from ..params import Params, REFINE_PAIR_IMPLS
 from ..periphery import periphery as peri
 from ..periphery.periphery import PeripheryShape, PeripheryState
@@ -214,13 +214,13 @@ class System:
             raise ValueError(
                 f"unknown solver_precision {params.solver_precision!r}; "
                 "use 'full', 'mixed', or 'auto'")
-        if params.kernel_impl not in ("exact", "mxu", "df", "pallas",
+        if params.kernel_impl not in ("auto", "exact", "mxu", "df", "pallas",
                                       "pallas_df"):
             # the kernel seam's else-branch would silently run "exact" for a
             # typo'd name — reject at construction like the other knobs
             raise ValueError(
                 f"unknown kernel_impl {params.kernel_impl!r}; "
-                "use 'exact', 'mxu', 'df', 'pallas', or 'pallas_df'")
+                "use 'auto', 'exact', 'mxu', 'df', 'pallas', or 'pallas_df'")
         if params.pair_evaluator == "spectral":
             if len(params.periodic_box) not in (2, 3) or any(
                     L <= 0 for L in params.periodic_box):
@@ -311,6 +311,31 @@ class System:
                            "f64 flows take the %r tile", taken)
             obs_tracer.emit("fault", kind="refine_tile_mismatch",
                             resolved="pallas_df", taken=taken)
+        return taken
+
+    def _announce_pair_tile(self, state, precision: str) -> str:
+        """Trace-time (once per build, like `_announce_refine_tile`): the
+        tile the Krylov loop's pair sums take — `ops.kernels.resolve_impl`
+        of `Params.kernel_impl` for the loop's dtype (float32 in the mixed
+        tier, the state's in the full one), the answer every pair seam of
+        the loop comes to — in the log and as a ``pair_tile`` event; a run
+        that resolved to the Pallas tile (the name's answer for f32
+        operands) and whose loop takes any other says so as a ``fault``."""
+        requested = self.params.kernel_impl
+        backend = jax.default_backend()
+        dtype = jnp.dtype(jnp.float32 if precision == "mixed"
+                          else state.time.dtype)
+        taken = kernels.resolve_impl(requested, dtype)
+        logger.info("pair_tile impl=%s requested=%s backend=%s dtype=%s",
+                    taken, requested, backend, dtype)
+        obs_tracer.emit("pair_tile", impl=taken, requested=requested,
+                        backend=backend, dtype=str(dtype))
+        if (taken != "pallas"
+                and kernels.resolve_impl(requested, jnp.float32) == "pallas"):
+            logger.warning("kernel_impl resolved to 'pallas' but the loop's "
+                           "%s pair sums take the %r tile", dtype, taken)
+            obs_tracer.emit("fault", kind="pair_tile_mismatch",
+                            resolved="pallas", taken=taken)
         return taken
 
     def _announce_block_precond(self, caches, body_caches):
@@ -1069,6 +1094,7 @@ class System:
 
         precision = "full" if force_full else self._precision_for(state)
         self._announce_fiber_ops(state, precision)
+        self._announce_pair_tile(state, precision)
         if precision == "mixed":
             # f64 state/assembly/refinement residuals; the Krylov loop's
             # expensive interior (kernel flows, shell/body dense ops, block
@@ -1080,7 +1106,7 @@ class System:
             # accelerators); state must be f64 for the df split to pay off
             hi_impl = self._announce_refine_tile(
                 self._refine_impl if state.time.dtype == jnp.float64
-                else p.kernel_impl)
+                else kernels.resolve_impl(p.kernel_impl, state.time.dtype))
             with jax.named_scope("gmres"):
                 result = gmres_ir(
                     # hi residual matvec: dense (no ewald plan) regardless

@@ -124,6 +124,58 @@ def test_pallas_df_ring_compiles_on_four_chips(topo, monkeypatch):
     assert "tpu_custom_call" in text
 
 
+# a chip's share of `ellipsoid_mesh4.run`: the fibers' Stokeslet from 16,384
+# sources a ring block onto its 16,384 fiber + 2,000 shell rows, the shell's
+# double layer from 2,000 sources a block onto its 16,384 fiber nodes; then
+# the one-chip sums of `ellipsoid_256.run` and of the walkthrough
+@pytest.mark.parametrize("kind,payload_tail,src_rows,trg_rows", [
+    ("stokeslet", (3,), 16384, 18384), ("stresslet", (3, 3), 2000, 16384)])
+def test_auto_ring_takes_the_pallas_tile_on_four_chips(
+        topo, monkeypatch, kind, payload_tail, src_rows, trg_rows):
+    """`ring_flow_local(impl="auto")`, as the mesh step calls it inside its
+    `shard_map`, told that the described chips are a TPU: f32 operands take
+    the Mosaic tile in the `ppermute` ring (the shapes are past the fused
+    ring's budget)."""
+    from skellysim_tpu.parallel.mesh import FIBER_AXIS
+    from skellysim_tpu.parallel.ring import ring_flow_local
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_dev = 4
+    mesh = Mesh(topo.devices[:n_dev], (FIBER_AXIS,))
+    sharded = NamedSharding(mesh, P(FIBER_AXIS))
+    r_src, _, pay = _cloud(n_dev * src_rows, 0, payload_tail, F32, sharded)
+    r_trg = jax.ShapeDtypeStruct((n_dev * trg_rows, 3), F32, sharding=sharded)
+    spec = P(FIBER_AXIS)
+
+    def flow(trg, src, p):
+        return jax.shard_map(
+            lambda t, s, q: ring_flow_local(kind, "auto", t, s, q, 1.0,
+                                            axis_name=FIBER_AXIS,
+                                            n_dev=n_dev),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(trg, src,
+                                                                    p)
+
+    text = _compile(flow, r_trg, r_src, pay)
+    assert "tpu_custom_call" in text and "collective-permute" in text
+
+
+@pytest.mark.parametrize("kind,payload_tail,n_src,n_trg", [
+    ("stokeslet", (3,), 16384, 24384), ("stresslet", (3, 3), 8000, 16384),
+    ("stresslet", (3, 3), 6000, 464)])
+def test_auto_direct_seam_takes_the_pallas_tile(one_chip, monkeypatch, kind,
+                                                payload_tail, n_src, n_trg):
+    """`kernels.*_direct(impl="auto")` on f32 operands, the backend read as
+    a TPU: the Mosaic tile, at sizes that are no multiples of it."""
+    from skellysim_tpu.ops import kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = getattr(kernels, f"{kind}_direct")
+    r_src, r_trg, pay = _cloud(n_src, n_trg, payload_tail, F32, one_chip)
+    text = _compile(lambda s, t, p: fn(s, t, p, 1.0, impl="auto"), r_src,
+                    r_trg, pay)
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("kind,payload_tail", [("stokeslet", (3,)),
                                                ("stresslet", (3, 3))])
 def test_fused_ring_compiles_on_four_chips(topo, kind, payload_tail):
@@ -161,7 +213,7 @@ def test_df_direct_tile_compiles(one_chip):
 
 def test_f64_direct_tile_compiles(one_chip):
     """The native-f64 exact tile (emulated on the chip) at 8,192^2 — the
-    tile `pallas_impl_for` swaps in for f64 operands."""
+    tile `resolve_impl` swaps in for f64 operands."""
     from skellysim_tpu.ops.kernels import stokeslet_direct
 
     r_src, r_trg, f = _cloud(8192, 8192, (3,), jnp.float64, one_chip)
