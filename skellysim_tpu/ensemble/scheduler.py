@@ -485,7 +485,6 @@ class EnsembleScheduler:
                 "catastrophes": int(fetched["catastrophes"][lane]),
                 "active_fibers": int(fetched["active_fibers"][lane]),
                 "wall_s": round(wall_s, 4),
-                "wall_ms": round(wall_s * 1e3, 3),
                 "gmres_history": history_rows(
                     hist[lane] if hist is not None else None,
                     fetched["cycles"][lane]),

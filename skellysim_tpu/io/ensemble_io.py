@@ -20,7 +20,7 @@ from .trajectory import TrajectoryWriter
 
 #: keys of an ``event == "step"`` record, one per member trial step — the
 #: sequential METRICS_FIELDS (system.system) plus the ensemble coordinates.
-#: `wall_s`/`wall_ms` are the BATCHED round's wall time, shared by every
+#: `wall_s` is the BATCHED round's wall time, shared by every
 #: lane of that round — `round` is the shared-round id consumers must
 #: dedupe wall sums by (`obs.summarize` does); `gmres_cycles`/
 #: `gmres_history` are per member (docs/observability.md)
@@ -29,8 +29,8 @@ ENSEMBLE_STEP_FIELDS = ("event", "member", "lane", "round", "step", "t",
                         "residual_true", "fiber_error", "accepted",
                         "refines", "loss_of_accuracy", "health",
                         "guard_retries", "nucleations", "catastrophes",
-                        "active_fibers", "wall_s", "wall_ms",
-                        "gmres_history", "flight")
+                        "active_fibers", "wall_s", "gmres_history",
+                        "flight")
 
 #: keys of an ``event == "start"`` record (member entered a lane);
 #: ``queue_wait_s`` is the admission latency (queue entry -> lane seat) —

@@ -12,8 +12,8 @@ One parser for every line shape the repo emits (docs/observability.md):
 
 The report's sections — per-span timings, compile events, how the block
 preconditioner is applied, faults, lane
-occupancy, dynamic instability, solver convergence — are each omitted
-when their inputs are absent,
+occupancy, dynamic instability, solver convergence, the run loop's step
+records — are each omitted when their inputs are absent,
 so the same command serves a single-run metrics file, a trace file, an
 ensemble metrics file, or all of them at once.
 """
@@ -501,11 +501,11 @@ class Summary:
         # count separately
         walls: dict = {}
         for i, s in enumerate(self.steps):
-            if "wall_ms" not in s:
+            if "wall_s" not in s:
                 continue
             key = (("round", s.get("_stream", 0), s["round"])
                    if "round" in s else ("step", 0, i))
-            walls[key] = float(s["wall_ms"])
+            walls[key] = float(s["wall_s"]) * 1e3
         if walls:
             vals = list(walls.values())
             label = ("batched-round wall"
@@ -521,6 +521,60 @@ class Summary:
                              for it, im, ex in last[-4:])
             out.append(f"last step's restart history "
                        f"(iters implicit/explicit): {rows}")
+        out.append("")
+
+    def _run_loop_section(self, out: list[str]):
+        """Where the host's time of a step went, from the metrics rows
+        ALONE (`obs.step_record`: ``loop_s``, ``host_ms``, ``slow``; no
+        trace file needed): every leaf span of the run loop over every
+        step, the serial host part of a step, and the slow steps with what
+        they were slow in."""
+        recs = [s for s in self.steps if "loop_s" in s
+                and isinstance(s.get("host_ms"), dict)]
+        if not recs:
+            return
+        out.append("== run loop ==")
+        loops = sorted(float(s["loop_s"]) for s in recs)
+        total_ms = sum(loops) * 1e3
+
+        def rank(vals, pct):    # nearest rank of a sorted list
+            return vals[max(0, -(-pct * len(vals) // 100) - 1)]
+
+        out.append(f"steps: {len(recs)}  loop: total {sum(loops):.3f}s  "
+                   f"p50 {rank(loops, 50) * 1e3:.3f}ms  "
+                   f"p99 {rank(loops, 99) * 1e3:.3f}ms  "
+                   f"max {loops[-1] * 1e3:.3f}ms")
+        by_leaf: dict[str, list[float]] = {}
+        for s in recs:
+            for leaf, ms in s["host_ms"].items():
+                by_leaf.setdefault(leaf, []).append(float(ms))
+        rows = [("span", "n", "p50_ms", "p99_ms", "max_ms", "share")]
+        for leaf in sorted(by_leaf, key=lambda k: -sum(by_leaf[k])):
+            vals = sorted(by_leaf[leaf])
+            rows.append((leaf, str(len(vals)), f"{rank(vals, 50):.3f}",
+                         f"{rank(vals, 99):.3f}", f"{vals[-1]:.3f}",
+                         f"{sum(vals) / total_ms:.2%}" if total_ms else "-"))
+        widths = [max(len(r[i]) for r in rows) for i in range(len(rows[0]))]
+        out.extend("  ".join(c.ljust(w) for c, w in zip(r, widths)).rstrip()
+                   for r in rows)
+        # what the host does in series with the device: the step's loop
+        # less the enqueue and the wait on the device
+        serial = sorted(float(s["loop_s"]) * 1e3
+                        - float(s["host_ms"].get("dispatch", 0.0))
+                        - float(s["host_ms"].get("wait", 0.0)) for s in recs)
+        out.append(f"loop - dispatch - wait a step: p50 "
+                   f"{rank(serial, 50):.3f}ms  max {serial[-1]:.3f}ms")
+        slow = [s for s in recs if s.get("slow")]
+        out.append(f"slow steps: {len(slow)}")
+        for s in slow:
+            sl = s["slow"]
+            counters = "  ".join(
+                f"{k}={v}" for k, v in (sl.get("counters") or {}).items())
+            out.append(
+                f"SLOW step {s.get('step')} t={s.get('t')}: loop "
+                f"{float(s['loop_s']):.3f}s = {sl.get('over_p50')} x p50, "
+                f"in={sl.get('in')} +{float(sl.get('excess_ms', 0.0)):.3f}ms"
+                f"  {counters}")
         out.append("")
 
     def render(self) -> str:
@@ -539,6 +593,7 @@ class Summary:
         self._scenario_section(out)
         self._flight_section(out)
         self._convergence_section(out)
+        self._run_loop_section(out)
         if self.torn_tails:
             out.append(f"({self.torn_tails} torn trailing line(s) ignored "
                        "— partial write, e.g. kill -9 mid-record)")

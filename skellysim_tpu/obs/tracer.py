@@ -22,6 +22,12 @@ Design constraints:
   instrumentation unconditionally, and a capture (`--profile DIR`) holds
   the host spans in the same dump, on the same clock, as the device ops:
   `obs.profile` labels every device idle gap with the span that covers it.
+* **A span's time outlives it where someone collects.** `_Span.__exit__`
+  measures every span whether or not a tracer listens; a span told to
+  `collect` hands the `(path below it, seconds, leaf)` of every span that
+  closes under it to a sink, tracer or no tracer, capture or no capture.
+  `System.run` collects under its ``run`` span into the step record
+  (`obs.step_record`): one list walk and one call a span.
 * **A span never changes when the program waits.** XLA dispatch is async: a
   jit call returns before the device finishes, so a span around it times
   the enqueue (the run loop's ``dispatch``), and the span around the first
@@ -80,35 +86,55 @@ def provenance() -> dict:
     return info
 
 
-#: the open spans of this process, outermost first: (name, step). Module
-#: state like `_ACTIVE` below: annotations need the path with no tracer on
+#: the open spans of this process, outermost first: [name, step, whether a
+#: span has opened under it]. Module state like `_ACTIVE` below:
+#: annotations need the path with no tracer on
 _STACK: list = []
+
+#: the open collecting spans, outermost first: (depth of `_STACK` below the
+#: collector, sink)
+_COLLECTORS: list = []
 
 _TRACE_ANNOTATION = None
 
 
 class _Span:
     """One timed scope (`span` / `Tracer.span`): a profiler annotation
-    always, a ``span`` event at exit where a tracer listens."""
+    always, its time handed to every collecting span open around it, a
+    ``span`` event at exit where a tracer listens."""
 
-    __slots__ = ("name", "fields", "_tracer", "_ann", "_t0")
+    __slots__ = ("name", "fields", "_tracer", "_ann", "_t0", "_collects")
 
     def __init__(self, name: str, fields: dict, tracer):
         self.name = name
         self.fields = fields
         self._tracer = tracer
+        self._collects = False
 
     def note(self, **fields):
         """Attach extra fields to the span event emitted at exit."""
         self.fields.update(fields)
 
+    def collect(self, sink):
+        """From now until this (open) span closes, every span that closes
+        under it calls ``sink(path, dur_s, leaf)``: ``path`` the slash-joined
+        names below this span, ``leaf`` whether no span opened under the
+        one that closed. No tracer or capture is needed."""
+        _COLLECTORS.append((len(_STACK), sink))
+        self._collects = True
+
     def __enter__(self):
         global _TRACE_ANNOTATION
         if _TRACE_ANNOTATION is None:
             from jax.profiler import TraceAnnotation as _TRACE_ANNOTATION
-        step = self.fields.get("step", _STACK[-1][1] if _STACK else None)
-        _STACK.append((self.name, step))
-        path = "/".join(n for n, _ in _STACK)
+        step = None
+        if _STACK:
+            parent = _STACK[-1]
+            step = parent[1]
+            parent[2] = True
+        step = self.fields.get("step", step)
+        _STACK.append([self.name, step, False])
+        path = "/".join(e[0] for e in _STACK)
         kw = self.fields if step is None else {**self.fields, "step": step}
         self._ann = _TRACE_ANNOTATION("skelly/" + path, **kw)
         self._ann.__enter__()
@@ -118,8 +144,13 @@ class _Span:
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
         self._ann.__exit__(*exc)
-        path = "/".join(n for n, _ in _STACK)
-        _, step = _STACK.pop()
+        names = [e[0] for e in _STACK]
+        path = "/".join(names)
+        _, step, parent_of_any = _STACK.pop()
+        if self._collects:
+            _COLLECTORS.pop()
+        for depth, sink in _COLLECTORS:
+            sink("/".join(names[depth:]), dur, not parent_of_any)
         if self._tracer is not None:
             self._tracer.emit(
                 "span", name=self.name, path=path,

@@ -27,6 +27,7 @@ logger = logging.getLogger("skellysim_tpu")
 from ..bodies import bodies as bd
 from ..fibers import container as fc
 from ..guard import verdict as _verdict
+from ..obs import step_record
 from ..obs import tracer as obs_tracer
 from ..obs.compile_log import observed_jit
 from ..ops import block_df, block_precond, kernels
@@ -97,13 +98,14 @@ def _rewrap_fibers(fibers, new_buckets: tuple):
 #: docs/performance.md "Run-loop metrics JSONL"; schema-pinned by
 #: tests/test_cli_pipeline.py). Resumed runs are segmented by a marker line
 #: {"resume": true, "t": ...} that `cli.run(resume=True)` appends first.
+#: The last three fields are the step record's (`obs.step_record.row_fields`).
 METRICS_FIELDS = ("step", "t", "dt", "iters", "gmres_cycles",
                   "collective_rounds", "gram_rows", "residual",
                   "residual_true",
                   "fiber_error", "accepted", "refines", "loss_of_accuracy",
                   "health", "guard_retries", "nucleations", "catastrophes",
-                  "active_fibers", "wall_s", "wall_ms", "gmres_history",
-                  "flight")
+                  "active_fibers", "wall_s", "gmres_history", "flight",
+                  "loop_s", "host_ms", "slow")
 
 
 def crossed_write_boundary(t_new: float, dt: float, dt_write: float) -> bool:
@@ -278,6 +280,9 @@ class System:
         self._vel_jit = observed_jit(self._velocity_at_targets_impl,
                                      name="system.velocity_at_targets",
                                      static_argnames=("pair",))
+        #: the run loop's step records (`obs.step_record`): the last 256
+        #: steps' host time by span, across `run` calls, tracer or no tracer
+        self.step_records = step_record.StepRecorder()
 
     @property
     def _refine_impl(self) -> str:
@@ -1604,10 +1609,16 @@ class System:
         try:
             with scope:
                 with prof:
-                    with obs_tracer.span("run", t_final=self.params.t_final):
+                    with obs_tracer.span(
+                            "run", t_final=self.params.t_final) as run_span:
+                        # every span that closes under ``run`` lands in the
+                        # step record, whoever else listens
+                        run_span.collect(self.step_records.span_closed)
+                        self.step_records.enter()
                         state = self._run_loop(state, writer=writer,
                                                max_steps=max_steps, rng=rng,
                                                metrics_fh=metrics_fh)
+                        self.step_records.leave()
                 if profile_dir is not None:
                     # fold the dump into the SAME telemetry stream: one
                     # `device_phase` event per attributed phase, so `obs
@@ -1835,6 +1846,36 @@ class System:
                                    fiber_error, accept, wall_s, converged,
                                    loss_of_accuracy, health, guard_retries,
                                    flight_row)
+                with span("advance_clock"):
+                    if accept:
+                        t_new = t_cur + dt
+                        state = new_state._replace(
+                            time=clock(t_new, dtype=state.time.dtype),
+                            dt=clock(dt_new, dtype=state.dt.dtype))
+                    else:
+                        # a rejected trial rolls back the physics but KEEPS
+                        # the flight ring: the recorder's whole point is the
+                        # trajectory into trouble, and the rejected
+                        # attempt's row is evidence
+                        state = backup._replace(
+                            dt=clock(dt_new, dtype=state.dt.dtype),
+                            flight=new_state.flight)
+                if accept and writer is not None and crossed_write_boundary(
+                        t_new, dt, p.dt_write):
+                    with span("write_frame", t=t_new) as wsp:
+                        # a `TrajectoryWriter` says how many bytes it wrote
+                        # (its ``encode`` and ``io`` spans nest here)
+                        kw = ({"rng_state": rng.dump_state()}
+                              if rng is not None else {})
+                        written = writer(state, solution, **kw)
+                        if written is not None:
+                            wsp.note(bytes=written)
+                t_next, dt_next = clock_read(state)
+                # the step's record closes with its last span; the row is
+                # written where the whole record exists, so its own time
+                # falls in the NEXT record's interval (or, after a call's
+                # last step, is carried there: `StepRecorder.leave`)
+                record = self.step_records.close(n_steps - 1)
                 if metrics_fh is not None:
                     with span("metrics_row"):
                         # key set == METRICS_FIELDS (schema-pinned;
@@ -1873,40 +1914,15 @@ class System:
                                 (new_state if accept else backup).fibers)
                                 if di_stats is not None else 0),
                             "wall_s": round(wall_s, 4),
-                            "wall_ms": round(wall_s * 1e3, 3),
                             "gmres_history": history_rows(info.history,
                                                           cycles),
                             # the flight recorder's decoded row for THIS
                             # trial (None at flight_window=0;
                             # docs/observability.md)
-                            "flight": flight_row}) + "\n")
+                            "flight": flight_row,
+                            **step_record.row_fields(record)}) + "\n")
                         metrics_fh.flush()
-
-                with span("advance_clock"):
-                    if accept:
-                        t_new = t_cur + dt
-                        state = new_state._replace(
-                            time=clock(t_new, dtype=state.time.dtype),
-                            dt=clock(dt_new, dtype=state.dt.dtype))
-                    else:
-                        # a rejected trial rolls back the physics but KEEPS
-                        # the flight ring: the recorder's whole point is the
-                        # trajectory into trouble, and the rejected
-                        # attempt's row is evidence
-                        state = backup._replace(
-                            dt=clock(dt_new, dtype=state.dt.dtype),
-                            flight=new_state.flight)
-                if accept and writer is not None and crossed_write_boundary(
-                        t_new, dt, p.dt_write):
-                    with span("write_frame", t=t_new) as wsp:
-                        # a `TrajectoryWriter` says how many bytes it wrote
-                        # (its ``encode`` and ``io`` spans nest here)
-                        kw = ({"rng_state": rng.dump_state()}
-                              if rng is not None else {})
-                        written = writer(state, solution, **kw)
-                        if written is not None:
-                            wsp.note(bytes=written)
-                t_cur, dt = clock_read(state)
+                t_cur, dt = t_next, dt_next
         if self.mesh is not None:
             # once a run; how each ring moved its blocks is in the stream
             # already, from the ring's own trace (`ring_fused` events,

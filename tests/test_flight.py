@@ -296,7 +296,7 @@ def _metrics_line(member=None, flight=None, **over):
            "residual_true": 1e-11, "fiber_error": 1e-9, "accepted": True,
            "refines": 0, "loss_of_accuracy": False, "health": 0,
            "guard_retries": 0, "nucleations": 0, "catastrophes": 0,
-           "active_fibers": 0, "wall_s": 0.1, "wall_ms": 100.0,
+           "active_fibers": 0, "wall_s": 0.1,
            "gmres_history": [], "flight": flight}
     if member is not None:
         rec.update(event="step", member=member, lane=0, round=0)

@@ -12,6 +12,7 @@ fixture compiles stay out of this module (the cost CLI test restricts to
 """
 
 import json
+import statistics
 
 import numpy as np
 import pytest
@@ -425,7 +426,8 @@ def test_run_metrics_and_trace_render_through_summarize(tmp_path):
     for rec in recs:
         assert set(rec) == set(METRICS_FIELDS)
         assert rec["gmres_cycles"] >= 1
-        assert rec["wall_ms"] == pytest.approx(rec["wall_s"] * 1e3, rel=0.1)
+        # the step's record: `wall_s` (dispatch + wait) lies inside it
+        assert rec["loop_s"] >= rec["wall_s"] - 1e-4
         hist = rec["gmres_history"]
         assert len(hist) == rec["gmres_cycles"]
         # last ring row's explicit residual is the step's residual_true
@@ -542,7 +544,7 @@ def test_summarize_dedupes_shared_round_wall(tmp_path):
                 fh.write(json.dumps({
                     "event": "step", "member": f"m{lane}", "lane": lane,
                     "round": rnd, "step": rnd, "iters": 3, "accepted": True,
-                    "wall_ms": 10.0}) + "\n")
+                    "wall_s": 0.010}) + "\n")
     report = summarize_files([p])
     # 2 rounds x 10 ms = 0.020 s — NOT 8 records x 10 ms = 0.080 s
     assert "batched-round wall: total 0.020s" in report
@@ -872,3 +874,270 @@ def test_summarize_multifile_source_columns(tmp_path):
     assert "serve_a.jsonl" in multi and "serve_b.jsonl" in multi
     assert "[serve_a.jsonl] rounds: 2" in multi
     assert "[serve_b.jsonl] rounds: 3" in multi
+
+
+# ------------------------------------------------------- the step record
+
+def test_collecting_span_gathers_descendants_without_a_tracer():
+    """A span told to `collect` is handed every span that closes under it
+    (path below it, seconds, leaf or not) with NO tracer active; when
+    nothing collects, nothing is handed anywhere."""
+    assert obs_tracer.active() is None
+    got = []
+    with obs_tracer.span("run") as run:
+        run.collect(lambda path, dur, leaf: got.append((path, dur, leaf)))
+        with obs_tracer.span("clock_read"):
+            pass
+        with obs_tracer.span("step", step=0):
+            with obs_tracer.span("write_frame"):
+                with obs_tracer.span("io"):
+                    pass
+    # after the collector closed: nobody is told
+    with obs_tracer.span("step"):
+        with obs_tracer.span("wait"):
+            pass
+    assert [(p, leaf) for p, _, leaf in got] == [
+        ("clock_read", True), ("step/write_frame/io", True),
+        ("step/write_frame", False), ("step", False)]
+    assert all(dur >= 0.0 for _, dur, _ in got)
+    assert obs_tracer._COLLECTORS == [] and obs_tracer._STACK == []
+
+
+def test_step_records_tile_the_run_and_fill_one_ring(tmp_path):
+    """Every row of a run carries its step record; the records tile the
+    ``run`` span (their ``loop_s`` sum to its duration), and re-entries of
+    `run(max_steps=1)` on one `System` fill ONE ring."""
+    from skellysim_tpu.audit import fixtures
+    from skellysim_tpu.system.system import METRICS_FIELDS
+
+    system = fixtures.make_system()
+    state = fixtures.free_state(system)
+    m = str(tmp_path / "metrics.jsonl")
+    tr = Tracer()
+    with obs_tracer.use(tr):    # on, to read the ``run`` span
+        state = system.run(state, max_steps=3, metrics_path=m)
+    rows = [json.loads(ln) for ln in open(m)]
+    assert len(rows) == 3
+    for row in rows:
+        assert set(row) == set(METRICS_FIELDS)
+        assert {"dispatch", "wait", "fetch_info", "clock_read",
+                "other"} <= set(row["host_ms"])
+        # spans that did not run are absent, not zero
+        assert "collision_gate" not in row["host_ms"]
+        assert "write_frame/io" not in row["host_ms"]
+        assert sum(row["host_ms"].values()) == pytest.approx(
+            row["loop_s"] * 1e3, abs=0.05)
+        assert row["slow"] is None
+    # the row is written after its record closed: its time is the next's
+    assert "metrics_row" not in rows[0]["host_ms"]
+    assert "metrics_row" in rows[1]["host_ms"]
+    (run_span,) = [e for e in tr.events if e["ev"] == "span"
+                   and e["path"] == "run"]
+    assert sum(r["loop_s"] for r in rows) == pytest.approx(
+        run_span["dur_s"], rel=0.02)
+
+    # no tracer, no metrics file: the ring fills all the same, one ring
+    assert len(system.step_records.ring) == 3
+    for _ in range(3):
+        state = system.run(state, max_steps=1)
+    ring = list(system.step_records.ring)
+    assert [r["n"] for r in ring] == list(range(6))
+    assert [r["step"] for r in ring] == [0, 1, 2, 0, 0, 0]
+    assert all(b["start"] > a["start"] for a, b in zip(ring, ring[1:]))
+    # the third row's write followed the first call's last record: carried
+    # into the first record of the next call
+    assert "metrics_row" in ring[3]["host_ms"]
+    assert "metrics_row" not in ring[4]["host_ms"]
+    from skellysim_tpu.obs.step_record import COUNTERS
+
+    assert all(set(r["counters"]) == set(COUNTERS) for r in ring)
+
+
+class _TickingClock:
+    """`time` as the tracer and the step record see it in the planted-stall
+    test: every `perf_counter()` call is one millisecond later than the
+    last, so each step of the test costs the same whatever else the machine
+    does (under six xdist workers a CPU step's real time swings by 4x, and
+    a rule that compares steps would flag the noise), and a stall is
+    planted by moving the clock."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def perf_counter(self):
+        self.now += 1e-3
+        return self.now
+
+
+class _StallingFile:
+    """A trajectory's file whose ``write`` stalls ``stall_s`` on the clock
+    above, on its ``on_call``-th call."""
+
+    def __init__(self, fh, clock, on_call, stall_s):
+        self.fh, self.clock = fh, clock
+        self.on_call, self.stall_s = on_call, stall_s
+        self.calls = 0
+
+    def write(self, data):
+        self.calls += 1
+        if self.calls == self.on_call:
+            self.clock.now += self.stall_s
+        return self.fh.write(data)
+
+    def flush(self):
+        self.fh.flush()
+
+    def close(self):
+        self.fh.close()
+
+
+def test_planted_stall_is_one_slow_step_named_three_ways(tmp_path, caplog,
+                                                         monkeypatch):
+    """A frame write that stalls 3 s on the ninth of ten steps gives
+    exactly one row with ``slow`` set, naming the span that stalled, ONE
+    ``fault`` event ``slow_step`` and ONE WARNING line; the eight steps
+    before it (fewer than eight records never flag) and the one after give
+    none."""
+    import logging
+
+    from skellysim_tpu.audit import fixtures
+    from skellysim_tpu.io.trajectory import TrajectoryWriter
+    from skellysim_tpu.obs import step_record
+    from skellysim_tpu.obs.summarize import summarize_files
+
+    clock = _TickingClock()
+    monkeypatch.setattr(obs_tracer, "time", clock)
+    monkeypatch.setattr(step_record, "time", clock)
+    system = fixtures.make_system(dt_write=1e-3)     # a frame every step
+    state = fixtures.free_state(system)
+    m = str(tmp_path / "metrics.jsonl")
+    writer = TrajectoryWriter(str(tmp_path / "traj.out"))
+    writer._fh = _StallingFile(writer._fh, clock, on_call=9, stall_s=3.0)
+    tr = Tracer()
+    with caplog.at_level(logging.WARNING, logger="skellysim_tpu"):
+        with obs_tracer.use(tr):
+            system.run(state, writer=writer.write_frame, metrics_path=m)
+    writer.close()
+
+    rows = [json.loads(ln) for ln in open(m)]
+    assert len(rows) == 10
+    slow = [r for r in rows if r["slow"]]
+    assert [r["step"] for r in slow] == [8]
+    verdict = slow[0]["slow"]
+    assert verdict["in"] == "write_frame/io"
+    assert verdict["over_p50"] > 50.0
+    assert verdict["excess_ms"] == pytest.approx(3000.0, abs=5.0)
+    assert set(verdict["counters"]) == set(step_record.COUNTERS)
+    assert slow[0]["host_ms"]["write_frame/io"] > 3000.0
+    assert slow[0]["loop_s"] == pytest.approx(
+        3.0 + statistics.median(r["loop_s"] for r in rows), abs=0.01)
+
+    faults = [e for e in tr.events if e["ev"] == "fault"]
+    assert [f["kind"] for f in faults] == ["slow_step"]
+    assert faults[0]["in"] == "write_frame/io" and faults[0]["step"] == 8
+    assert faults[0]["over_p50"] == verdict["over_p50"]
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("slow step")]
+    assert len(lines) == 1
+    assert "in=write_frame/io +3.00" in lines[0] and "n=8" in lines[0]
+    for field in ("majflt=", "nivcsw=", "gc_s=", "psi_mem_us=",
+                  "psi_io_us="):
+        assert field in lines[0]
+
+    # `obs summarize` on the metrics file ALONE lists it
+    report = summarize_files([m])
+    section = report.split("== run loop ==")[1]
+    assert "slow steps: 1" in section
+    (listed,) = [ln for ln in section.splitlines()
+                 if ln.startswith("SLOW step 8")]
+    assert "in=write_frame/io" in listed and "majflt=" in listed
+    assert "write_frame/io" in section and "loop - dispatch - wait" in section
+
+
+def test_slow_rule_needs_eight_records_and_twice_the_median():
+    """The rule alone, on records made by hand: nothing flags before eight
+    records exist, a step 1.5x the median (the walkthrough's alternation
+    reads 1.38x) never does, one 3x does and names the leaf that grew."""
+    from skellysim_tpu.obs import step_record
+
+    rec = step_record.StepRecorder()
+
+    def close(wait_ms, log_ms=1.0):
+        record = {"n": rec.count, "step": rec.count, "start": 0.0,
+                  "loop_s": (wait_ms + log_ms) / 1e3,
+                  "host_ms": {"wait": wait_ms, "log": log_ms},
+                  "counters": dict.fromkeys(step_record.COUNTERS, 0),
+                  "slow": None}
+        rec._judge(record)
+        rec.ring.append(record)
+        rec.count += 1
+        return record["slow"]
+
+    assert close(5000.0) is None        # the compiling first step
+    for _ in range(6):
+        assert close(100.0) is None
+    assert close(1000.0) is None        # the eighth: seven before it
+    for i in range(20):                 # 2- and 3-sweep steps in turn
+        assert close(100.0 if i % 2 else 150.0) is None
+    verdict = close(105.0, log_ms=300.0)
+    assert verdict["in"] == "log"
+    assert verdict["excess_ms"] == pytest.approx(299.0)
+    # the median of the 28 before it is 101 ms (sixteen of them read that)
+    assert verdict["over_p50"] == pytest.approx(405.0 / 101.0, abs=2e-3)
+    # a toy's millisecond steps: 3x the median, yet under the floor in
+    # seconds, and the flag's own cost can never flag the next
+    toy = step_record.StepRecorder()
+    rec = toy
+    for _ in range(10):
+        assert close(1.0, log_ms=0.1) is None
+    assert close(4.0, log_ms=0.1) is None
+
+
+def test_unreadable_pressure_files_give_nulls(monkeypatch):
+    """Where /proc/pressure cannot be opened the three counters are None —
+    never a zero for "not seen" — and no read raises."""
+    import os
+
+    from skellysim_tpu.obs import step_record
+
+    real_open = os.open
+
+    def refusing(path, *a, **kw):
+        if str(path).startswith("/proc/pressure"):
+            raise PermissionError(path)
+        return real_open(path, *a, **kw)
+
+    monkeypatch.setattr(step_record, "_PSI_FDS", None)
+    monkeypatch.setattr(os, "open", refusing)
+    before = step_record.read_counters()
+    assert [before[k] for k in ("psi_mem_us", "psi_io_us",
+                                "psi_cpu_us")] == [None] * 3
+    rec = step_record.StepRecorder()
+    rec.enter()
+    rec.span_closed("step/wait", 0.001, True)
+    record = rec.close(0)
+    rec.leave()
+    assert record["counters"]["psi_mem_us"] is None
+    assert record["counters"]["psi_io_us"] is None
+    assert record["counters"]["psi_cpu_us"] is None
+    assert record["counters"]["majflt"] >= 0
+    assert record["host_ms"]["wait"] == pytest.approx(1.0)
+    # dropped with the patch: the next reader opens the real files again
+    monkeypatch.setattr(step_record, "_PSI_FDS", None)
+
+
+def test_step_record_cost_guard():
+    """2,000 record cycles with the loop's span set average under 0.5 ms
+    (the chip's host reads 0.12 ms a cycle: PERF.md section 6); generous,
+    so that a crowded CI host does not flake."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "step_record_cost.py")
+    spec = importlib.util.spec_from_file_location("step_record_cost", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    out = mod.measure(2000)
+    assert out["record_ms"] < 0.5
+    assert out["cost_ms_per_step"] < 0.5

@@ -485,7 +485,7 @@ def test_summarize_renders_scenario_table():
             "dt": 0.02, "iters": 3, "gmres_cycles": 1, "residual": 1e-11,
             "residual_true": 1e-11, "fiber_error": 0.0, "accepted": True,
             "refines": 0, "loss_of_accuracy": False, "health": 0,
-            "guard_retries": 0, "wall_s": 0.1, "wall_ms": 100.0,
+            "guard_retries": 0, "wall_s": 0.1,
             "gmres_history": []}
     for step, (n, c, a) in enumerate([(2, 0, 4), (1, 1, 4), (0, 2, 2)]):
         s.add_line(json.dumps(dict(base, member="m0", step=step, round=step,
