@@ -179,7 +179,7 @@ def build_bodies(cfg_bodies: list, config_dir: str, dtype,
 
 
 def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None,
-                    mesh=None):
+                    mesh=None, words: bool = False):
     """(PeripheryState, PeripheryShape) from config + precompute npz.
 
     ``precond_dtype`` stores M_inv (the preconditioner) at a lower precision
@@ -190,7 +190,8 @@ def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None,
     `periphery.cpp:408-442`): no device ever holds the whole operator, and
     `System.run`'s placement finds each leaf where it belongs. A shell the
     mesh does not divide loads whole, and the run loop refuses it in
-    words."""
+    words. ``words`` holds the operator as its double-float words, split
+    on the host, in place of the float64 array (`System.shell_product`)."""
     data = _load_npz(os.path.join(config_dir, cfg_periphery.precompute_file),
                      "periphery")
     put = None
@@ -203,7 +204,8 @@ def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None,
                             data["quadrature_weights"],
                             data["stresslet_plus_complementary"],
                             data["M_inv"], dtype=dtype,
-                            precond_dtype=precond_dtype, put=put)
+                            precond_dtype=precond_dtype, put=put,
+                            words=words)
     shape_name = getattr(cfg_periphery, "shape", "sphere")
     if shape_name == "sphere":
         shape = peri.PeripheryShape(kind="sphere", radius=cfg_periphery.radius)
@@ -289,6 +291,7 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
                        "build_simulation; using the direct evaluator on one "
                        "device (set params.mesh_devices to the device count "
                        "to run on a mesh)")
+    system = System(params, mesh=mesh)
     shell, shape, shell_precompute = None, None, None
     if getattr(config, "periphery", None) is not None:
         # mixed mode gets an f32 M_inv, halving the shell preconditioner's
@@ -299,8 +302,9 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
                                   dtype == jnp.float64) == "mixed"
         pdt = jnp.float32 if mixed else None
         t_load = time.perf_counter()
-        shell, shape = build_periphery(config.periphery, config_dir, dtype,
-                                       precond_dtype=pdt, mesh=mesh)
+        shell, shape = build_periphery(
+            config.periphery, config_dir, dtype, precond_dtype=pdt, mesh=mesh,
+            words=system.shell_product(dtype) == "df_tile")
         # the npz read and the hand-over to the device; the upload itself is
         # not waited for here, a first step is what waits for it
         shell_precompute = {
@@ -319,7 +323,7 @@ def build_simulation(config, config_dir: str = ".", dtype=jnp.float64,
 
         fibers = pad_for_mesh(fibers, mesh.size)
 
-    system = System(params, shell_shape=shape, mesh=mesh)
+    system.shell_shape = shape
     system.shell_precompute = shell_precompute
     state = system.make_state(
         fibers=fibers,

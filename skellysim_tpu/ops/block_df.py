@@ -46,7 +46,8 @@ from .df_kernels import _df_split
 from .pallas_df import _DF, _require_x64, _round_up
 from .pallas_kernels import _out_struct
 
-__all__ = ["split_words", "block_matvec_df", "fills_a_step"]
+__all__ = ["split_words", "block_matvec_df", "fills_a_step",
+           "matvec_df_streamed"]
 
 #: bytes of ONE word of the matrix block a grid step holds in VMEM (two
 #: words, double-buffered: four times this, of the 16 MiB a kernel may
@@ -139,23 +140,35 @@ def _block_matvec_kernel(df, shared, mh_ref, ml_ref, xh_ref, xl_ref, oh_ref,
 
         lax.fori_loop(i32(0), i32(rows // hs), strip, None)
 
-        for b in range(part_ref.shape[1] // 128):
-            rb = slice(b * 128, (b + 1) * 128)
-            # [128 rows, 128 lanes] -> [128 lanes, 128 rows]: each row's
-            # partials now lie along the sublanes
-            h, l = part_ref[0, rb, :].T, part_ref[1, rb, :].T
-            m = 128
-            while m > 8:
-                m //= 2
-                h, l = df.add(h[:m], l[:m], h[m:], l[m:])
-            for w in (4, 2, 1):
-                # shift as int32: see `_DF.reduce_lanes`
-                h, l = df.add(h, l, pltpu.roll(h, np.int32(w), 0),
-                              pltpu.roll(l, np.int32(w), 0))
-            oh_ref[f, :, rb] = h[:1]
-            ol_ref[f, :, rb] = l[:1]
+        def store(rb, h, l):
+            oh_ref[f, :, rb] = h
+            ol_ref[f, :, rb] = l
+
+        _rows_to_lanes(df, part_ref, store)
 
     lax.fori_loop(i32(0), i32(fb), block, None)
+
+
+def _rows_to_lanes(df, part_ref, store):
+    """The rows' lane-wise partial sums ``part_ref`` [2, rows_p, 128] (hi,
+    lo) -> each row's total, handed to ``store(lanes, hi, lo)`` as [1, 128]
+    a block of 128 rows: the block's partials are transposed (XLU) and the
+    128 of each row — now along the sublanes — reduce by elementwise
+    double-float adds."""
+    for b in range(part_ref.shape[1] // 128):
+        rb = slice(b * 128, (b + 1) * 128)
+        # [128 rows, 128 lanes] -> [128 lanes, 128 rows]: each row's
+        # partials now lie along the sublanes
+        h, l = part_ref[0, rb, :].T, part_ref[1, rb, :].T
+        m = 128
+        while m > 8:
+            m //= 2
+            h, l = df.add(h[:m], l[:m], h[m:], l[m:])
+        for w in (4, 2, 1):
+            # shift as int32: see `_DF.reduce_lanes`
+            h, l = df.add(h, l, pltpu.roll(h, np.int32(w), 0),
+                          pltpu.roll(l, np.int32(w), 0))
+        store(rb, h[:1], l[:1])
 
 
 #: f32 operations a matrix entry (the strip body's count without the
@@ -214,3 +227,135 @@ def block_matvec_df(words, x, *, n_rows: int, interpret: bool = False):
     # hi + lo is exactly representable in f64: one conversion an element
     y = oh.astype(jnp.float64) + ol.astype(jnp.float64)
     return y.reshape(nb, rows_p)[:, :n_rows]
+
+
+# ------------------------------------------- one large matrix, streamed
+
+#: rows x columns of the matrix's words a grid step of the streamed tile
+#: holds (`matvec_df_streamed`): two words, double-buffered, are
+#: 16 x rows x columns bytes of VMEM. Swept on a v5e at the shell
+#: operators of the shell cells (PERF.md section 6, PR 39).
+STREAM_ROWS = 512
+STREAM_COLS = 1024
+#: columns of a strip's chunk: a strip of `_STREAM_STRIP` rows walks a
+#: block's columns this many at a time (8 vregs a value, as `DF_STRIP_ELEMS`)
+_STREAM_CHUNK = 256
+_STREAM_STRIP = 32
+
+
+def _streamed_kernel(df, n_col_blocks, last_cols, mh_ref, ml_ref, x_ref,
+                     oh_ref, ol_ref, acc_ref):
+    """Grid step (i, j): row block i of the matrix words ``mh_ref`` /
+    ``ml_ref`` [R, C] against column block j of the vector's words
+    ``x_ref`` [4, C] (hi, lo, and the Dekker halves of hi). ``acc_ref``
+    [2, R, 128] carries each row's lane-wise partial sums (hi, lo) across
+    the column blocks by double-float adds; the last column block hands
+    them to `_rows_to_lanes`, which writes the row block's totals into
+    ``oh_ref`` / ``ol_ref`` [1, R]. The last column block holds
+    ``last_cols`` columns of the matrix: the rest of it lies past the
+    matrix's edge, whose words are undefined there, and is masked (or,
+    chunk by chunk, skipped). Rows past the edge compute garbage into
+    lanes the caller drops."""
+    j = pl.program_id(1)
+    rows, cols = mh_ref.shape
+    hs, cw = _STREAM_STRIP, _STREAM_CHUNK
+    i32 = jnp.int32
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(n_cols):
+        def strip(s, _):
+            r = pl.ds(pl.multiple_of(s * i32(hs), hs), hs)
+            h = l = None
+            for k in range(-(-n_cols // cw)):
+                c = slice(k * cw, (k + 1) * cw)
+                ah, al = mh_ref[r, c], ml_ref[r, c]
+                if (k + 1) * cw > n_cols:
+                    keep = (lax.broadcasted_iota(i32, (hs, cw), 1)
+                            < n_cols - k * cw)
+                    ah = jnp.where(keep, ah, 0.0)
+                    al = jnp.where(keep, al, 0.0)
+                xb = [jnp.broadcast_to(x_ref[w:w + 1, c], (hs, cw))
+                      for w in range(4)]
+                ph, pl_ = df.mul(ah, al, xb[0], xb[1], y_sp=(xb[2], xb[3]))
+                m = cw
+                while m > 128:
+                    m //= 2
+                    ph, pl_ = df.add(ph[:, :m], pl_[:, :m], ph[:, m:],
+                                     pl_[:, m:])
+                h, l = (ph, pl_) if h is None else df.add(h, l, ph, pl_)
+            h, l = df.add(acc_ref[0, r, :], acc_ref[1, r, :], h, l)
+            acc_ref[0, r, :] = h
+            acc_ref[1, r, :] = l
+
+        lax.fori_loop(i32(0), i32(rows // hs), strip, None)
+
+    def finish():
+        accumulate(last_cols)
+
+        def store(rb, h, l):
+            oh_ref[:, rb] = h
+            ol_ref[:, rb] = l
+
+        _rows_to_lanes(df, acc_ref, store)
+
+    if n_col_blocks == 1:
+        finish()
+        return
+    pl.when(j < n_col_blocks - 1)(lambda: accumulate(cols))
+    pl.when(j == n_col_blocks - 1)(finish)
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def matvec_df_streamed(words, x, *, interpret: bool = False):
+    """``words`` = the (hi, lo) float32 words of ONE large matrix [rows,
+    cols], unpadded (`periphery.split_operator`), ``x`` [cols] (float64, or
+    float32: its lo word is zero) -> ``[rows]`` float64: the product of
+    `block_matvec_df`'s arithmetic, for a matrix that no VMEM holds. The
+    grid walks the words from HBM in `STREAM_ROWS` x `STREAM_COLS` blocks,
+    rows on its first axis, column blocks on its second; the vector is
+    split once a call and padded to whole column blocks, the matrix never
+    (its edge blocks are masked in the kernel)."""
+    _require_x64("matvec_df_streamed")
+    mh, ml = words
+    rows, cols = mh.shape
+    br = min(STREAM_ROWS, _round_up(rows, 128))
+    bc = min(STREAM_COLS, _round_up(cols, _STREAM_CHUNK))
+    n_i, n_j = -(-rows // br), -(-cols // bc)
+    xh, xl = _df_split(x)
+    # split by XLA, outside the kernel: the barrier keeps it from folding
+    # the Dekker split away (`_DF`)
+    xs = jnp.stack([xh, xl, *_DF(barrier=True).split(xh)])
+    xs = jnp.pad(xs, ((0, 0), (0, n_j * bc - cols)))
+    z = np.int32(0)  # i64/i32 index-map mix breaks Mosaic (pallas_kernels)
+    m_spec = pl.BlockSpec((br, bc), lambda i, j: (i, j),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, br), lambda i, j: (z, i),
+                          memory_space=pltpu.VMEM)
+    out = _out_struct((1, n_i * br), jnp.float32, mh, ml, xs)
+    oh, ol = pl.pallas_call(
+        partial(_streamed_kernel, _DF(barrier=interpret), n_j,
+                cols - (n_j - 1) * bc),
+        out_shape=(out, out),
+        grid=(n_i, n_j),
+        in_specs=[m_spec, m_spec,
+                  pl.BlockSpec((4, bc), lambda i, j: (z, j),
+                               memory_space=pltpu.VMEM)],
+        out_specs=(o_spec, o_spec),
+        scratch_shapes=[pltpu.VMEM((2, br, 128), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # the words' 16 x R x C double-buffered bytes, and room beside
+            vmem_limit_bytes=max(32 << 20, 24 * br * bc)),
+        cost_estimate=pl.CostEstimate(
+            flops=_FLOPS_PER_ENTRY * rows * cols,
+            bytes_accessed=8 * rows * cols + 16 * n_i * n_j * bc
+            + 8 * n_i * br,
+            transcendentals=0),
+        interpret=interpret,
+    )(mh, ml, xs)
+    # hi + lo is exactly representable in f64: one conversion an element
+    y = oh.astype(jnp.float64) + ol.astype(jnp.float64)
+    return y[0, :rows]

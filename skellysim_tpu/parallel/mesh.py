@@ -202,8 +202,9 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False,
         # matrix); a leaf that is there already is handed back as it is
         specs = shell_specs(shell, step, sharded=divides)
         shell = type(shell)(*[
-            leaf if leaf is None else
-            jax.device_put(jax.numpy.asarray(leaf), NamedSharding(mesh, spec))
+            leaf if leaf is None else jax.tree_util.tree_map(
+                lambda a, spec=spec: jax.device_put(
+                    jax.numpy.asarray(a), NamedSharding(mesh, spec)), leaf)
             for leaf, spec in zip(shell, specs)])
 
     rest = jax.tree_util.tree_map(

@@ -31,6 +31,19 @@ import jax.numpy as jnp
 from ..ops import kernels
 
 
+class OperatorWords(NamedTuple):
+    """The float64 operator held as its double-float words (a pytree):
+    ``hi`` = float32(A), ``lo`` = float32(A - hi), together ``A`` to 2^-48
+    in the bytes of the float64 array they stand in for. The refinement's
+    float64 product multiplies them through the streamed tile of
+    `ops.block_df`; the Krylov loop's float32 product is ``hi``, the same
+    rounding the lo seam's cast (`system._cast_floats`) gives the float64
+    array. Held where `System.shell_product` says ``df_tile``."""
+
+    hi: jnp.ndarray           # [3N, 3N] float32
+    lo: jnp.ndarray           # [3N, 3N] float32
+
+
 class PeripheryState(NamedTuple):
     """Device-resident shell state (a pytree)."""
 
@@ -38,7 +51,8 @@ class PeripheryState(NamedTuple):
     normals: jnp.ndarray      # [N, 3] (inward, as stored by precompute)
     weights: jnp.ndarray      # [N]
     M_inv: jnp.ndarray        # [3N, 3N] preconditioner
-    stresslet_plus_complementary: jnp.ndarray  # [3N, 3N] operator
+    #: [3N, 3N] operator, or its `OperatorWords`
+    stresslet_plus_complementary: jnp.ndarray
     density: jnp.ndarray      # [3N] current solution slice
     #: [N] bool quadrature-row mask, or None (all rows live — the default).
     #: Padded rows (skelly-bucket's shell axis, `grow_capacity`) carry zero
@@ -172,25 +186,48 @@ def build_shell_operator_device(nodes, normals, weights, eta: float = 1.0, *,
     return M, M_inv
 
 
+def split_operator(op) -> OperatorWords:
+    """A float64 operator (host or device) -> its `OperatorWords`, unpadded:
+    ``hi`` its float32 rounding, ``lo`` the rest rounded once."""
+    xp = np if isinstance(op, np.ndarray) else jnp
+    hi = op.astype(xp.float32)
+    # hi promotes to float64 exactly: the difference is rounded once
+    return OperatorWords(hi, (op - hi).astype(xp.float32))
+
+
 def make_state(nodes, normals, weights, operator, M_inv, dtype=jnp.float64,
-               precond_dtype=None, put=None) -> PeripheryState:
+               precond_dtype=None, put=None, words: bool = False
+               ) -> PeripheryState:
     """``precond_dtype`` stores M_inv (the preconditioner — accuracy does not
     matter) in a lower precision, halving its HBM footprint in mixed mode.
     ``put(host_array, dtype)`` hands each leaf to the device(s) (default:
     the whole of it to the default device; a mesh run's builder divides
-    every leaf by rows, `parallel.mesh.rows_to_shards`)."""
+    every leaf by rows, `parallel.mesh.rows_to_shards`). ``words`` holds
+    the operator as its `OperatorWords`, split on the host before they go
+    up, in place of the float64 array: the one place they are formed, where
+    `System.shell_product` says ``df_tile`` (`builder.build_simulation`
+    asks it)."""
     if put is None:
         def put(a, dt):
             return jnp.asarray(a, dtype=dt)
     N = len(nodes)
+    if words:
+        op = OperatorWords(*(put(w, jnp.float32)
+                             for w in split_operator(np.asarray(operator))))
+    else:
+        op = put(operator, dtype)
     return PeripheryState(
         nodes=put(nodes, dtype),
         normals=put(normals, dtype),
         weights=put(weights, dtype),
         M_inv=put(M_inv, precond_dtype or dtype),
-        stresslet_plus_complementary=put(operator, dtype),
+        stresslet_plus_complementary=op,
         density=put(np.zeros(3 * N), dtype),
     )
+
+
+def holds_words(shell: PeripheryState) -> bool:
+    return isinstance(shell.stresslet_plus_complementary, OperatorWords)
 
 
 def grow_capacity(shell: PeripheryState, new_n: int) -> PeripheryState:
@@ -223,12 +260,17 @@ def grow_capacity(shell: PeripheryState, new_n: int) -> PeripheryState:
         fill = np.repeat(a[:1], pad, axis=0)
         return np.concatenate([a, fill], axis=0)
 
-    def pad_op(m):
+    def pad_op(m, eye=True):
         m = np.asarray(m)
-        out = np.eye(3 * new_n, dtype=m.dtype)
+        out = (np.eye(3 * new_n, dtype=m.dtype) if eye
+               else np.zeros((3 * new_n,) * 2, dtype=m.dtype))
         out[:3 * n, :3 * n] = m
-        return out
+        return jnp.asarray(out)
 
+    op = shell.stresslet_plus_complementary
+    # the identity's words are (1, 0)
+    op = (OperatorWords(pad_op(op.hi), pad_op(op.lo, eye=False))
+          if holds_words(shell) else pad_op(op))
     dtype = shell.nodes.dtype
     normals = np.concatenate(
         [np.asarray(shell.normals), np.zeros((pad, 3))], axis=0)
@@ -237,10 +279,8 @@ def grow_capacity(shell: PeripheryState, new_n: int) -> PeripheryState:
         normals=jnp.asarray(normals, dtype=dtype),
         weights=jnp.asarray(np.concatenate(
             [np.asarray(shell.weights), np.zeros(pad)]), dtype=dtype),
-        M_inv=jnp.asarray(pad_op(shell.M_inv), dtype=shell.M_inv.dtype),
-        stresslet_plus_complementary=jnp.asarray(
-            pad_op(shell.stresslet_plus_complementary),
-            dtype=shell.stresslet_plus_complementary.dtype),
+        M_inv=pad_op(shell.M_inv),
+        stresslet_plus_complementary=op,
         density=jnp.asarray(np.concatenate(
             [np.asarray(shell.density), np.zeros(3 * pad)]), dtype=dtype),
         node_mask=jnp.asarray(mask))
@@ -277,23 +317,29 @@ def describe(shell: PeripheryState, chips: int = 1) -> dict:
     whole, or inside the mesh step its share of a shell divided by rows over
     ``chips`` devices — the shell's own numbers are then the share's times
     ``chips``, and the product's policy is the share's (it is the share's
-    rows that `_apply_operator` sees there)."""
+    rows that `_apply_operator` sees there). `OperatorWords` read as dtype
+    ``float32x2`` and the bytes of both words, their product ``df_tile``."""
     out = {"nodes": shell.n_nodes * chips}
-    for name, m in (("operator", shell.stresslet_plus_complementary),
-                    ("m_inv", shell.M_inv)):
+    words = holds_words(shell)
+    op = shell.stresslet_plus_complementary
+    held = op.hi if words else op
+    for name, m, n in (("operator", held, 2 if words else 1),
+                       ("m_inv", shell.M_inv, 1)):
         dtype = jnp.dtype(m.dtype)
         out.update({name: f"{m.shape[0] * chips}x{m.shape[1]}",
-                    name + "_dtype": dtype.name,
-                    name + "_bytes": m.size * dtype.itemsize * chips})
-    blocked = _row_blocked(shell.stresslet_plus_complementary)
-    return dict(out, f64_product="row_blocks" if blocked else "whole",
-                row_block=_F64_ROW_BLOCK if blocked else 0, chips=chips,
-                rows_per_chip=shell.stresslet_plus_complementary.shape[0])
+                    name + "_dtype": dtype.name + ("x2" if n == 2 else ""),
+                    name + "_bytes": m.size * dtype.itemsize * chips * n})
+    product = ("df_tile" if words
+               else "row_blocks" if _row_blocked(op) else "whole")
+    return dict(out, f64_product=product,
+                row_block=_F64_ROW_BLOCK if product == "row_blocks" else 0,
+                chips=chips, rows_per_chip=held.shape[0])
 
 
 @jax.named_scope("shell")
 def _apply_operator(op, x):
-    """``op @ x``; a large float64 operator goes in row blocks.
+    """``op @ x``; a large float64 operator goes in row blocks, its
+    `OperatorWords` through `_apply_words`.
 
     A TPU emulates an f64 dot through an ``[8, rows, cols]`` f32 expansion
     of the matrix: 9.7 GB at 6,000 shell nodes, which with the operator and
@@ -302,6 +348,8 @@ def _apply_operator(op, x):
     ``[8, _F64_ROW_BLOCK, cols]``; each row's dot product is unchanged. The
     last block starts early enough to stay in range, so trailing rows may
     be computed twice — to the same values."""
+    if isinstance(op, OperatorWords):
+        return _apply_words(op, x)
     rows = op.shape[0]
     if not _row_blocked(op):
         return op @ x
@@ -315,6 +363,20 @@ def _apply_operator(op, x):
 
     y0 = jnp.zeros((rows,) + x.shape[1:], dtype=jnp.result_type(op, x))
     return jax.lax.fori_loop(0, -(-rows // block), body, y0)
+
+
+def _apply_words(op: OperatorWords, x):
+    """The operator's words times ``x`` [3N]: a float32 ``x`` (the Krylov
+    loop's) multiplies ``hi``, float32(A) — the product the loop took of
+    the float64 array's float32 copy, to the bit; a float64 ``x`` (the
+    refinement residual's) goes through the streamed double-float tile of
+    `ops.block_df`, compiled for a TPU and interpreted elsewhere."""
+    if x.dtype != jnp.float64:
+        return op.hi @ x.astype(op.hi.dtype)
+    from ..ops import block_df
+
+    return block_df.matvec_df_streamed(
+        tuple(op), x, interpret=jax.default_backend() != "tpu")
 
 
 @jax.named_scope("shell")

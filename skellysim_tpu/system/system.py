@@ -406,6 +406,26 @@ class System:
                     "fibers=%(fibers)s fallback=%(fallback)s", fields)
         obs_tracer.emit("fiber_ops", **fields)
 
+    def shell_product(self, dtype) -> str:
+        """How the refinement residual multiplies the shell's float64
+        operator in a state of ``dtype``: ``"df_tile"`` — the shell holds
+        the operator as its `periphery.OperatorWords` in place of the
+        float64 array, the residual's product runs through the streamed
+        double-float tile of `ops.block_df` and the Krylov loop's is the
+        hi word — exactly where the mixed tier's float64 flows take the
+        Pallas double-float tile (`_refine_impl`), which on a TPU they do;
+        ``"f64_dot"`` (`periphery._apply_operator` on the float64 array)
+        everywhere else. Follows the backend and the dtype, as
+        `_fiber_ops_for` does."""
+        from ..params import resolve_precision
+
+        if (jnp.dtype(dtype) == jnp.float64
+                and resolve_precision(self.params.solver_precision,
+                                      True) == "mixed"
+                and self._refine_impl == "pallas_df"):
+            return "df_tile"
+        return "f64_dot"
+
     #: where the shell's stored operators came from, and the seconds it took
     #: to read them and hand them to the device: `builder.build_simulation`
     #: says (a shell handed to `make_state` by anyone else has no file)
@@ -418,10 +438,13 @@ class System:
         divided and how many rows a chip holds, the precompute file they
         were read from and in how many seconds, and how the float64 operator
         is multiplied (`periphery._apply_operator`: ``row_blocks`` of
-        ``row_block`` rows, or ``whole``; on a mesh, of a chip's rows) — in
-        the log and as a ``periphery`` event. The mesh step calls it inside
-        its `shard_map`, where ``state`` is one chip's share of ``chips``.
-        Silent without a shell."""
+        ``row_block`` rows, or ``whole``; on a mesh, of a chip's rows; or
+        ``df_tile``, the words through the streamed tile) — in the log and
+        as a ``periphery`` event; a state that holds the words where
+        `shell_product` does not resolve to ``df_tile``, or the float64
+        array where it does, says so as a ``fault``. The mesh step
+        calls it inside its `shard_map`, where ``state`` is one chip's share
+        of ``chips``. Silent without a shell."""
         if state.shell is None:
             return
         src = self.shell_precompute or {}
@@ -438,6 +461,12 @@ class System:
             "chips=%(chips)d rows_per_chip=%(rows_per_chip)d "
             "precompute=%(precompute)s load_s=%(load_s).3f", fields)
         obs_tracer.emit("periphery", **fields)
+        resolved = self.shell_product(state.time.dtype)
+        if (resolved == "df_tile") != (fields["f64_product"] == "df_tile"):
+            logger.warning("the shell's float64 product resolved to %r but "
+                           "takes %r", resolved, fields["f64_product"])
+            obs_tracer.emit("fault", kind="shell_product_mismatch",
+                            resolved=resolved, taken=fields["f64_product"])
 
     def _precision_for(self, state) -> str:
         """Resolve Params.solver_precision for one state ("full"/"mixed").

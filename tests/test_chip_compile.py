@@ -321,3 +321,112 @@ def test_gmres_live_row_walk_compiles(topo, n_dev):
     text = _compile(step, st, st)
     assert "dynamic-slice" in text
     assert ("all-reduce" in text) == (n_dev > 1)
+
+
+@pytest.mark.parametrize("rows,cols", [(24000, 24000), (6000, 24000)])
+def test_streamed_shell_tile_compiles(one_chip, rows, cols):
+    """The streamed double-float tile (`ops.block_df.matvec_df_streamed`)
+    at `ellipsoid_256.run`'s whole 24,000^2 shell operator and at a chip's
+    6,000 rows of it in `ellipsoid_mesh4.run`: ragged blocks both ways."""
+    from skellysim_tpu.ops import block_df
+
+    def st(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compile(lambda h, l, x: block_df.matvec_df_streamed((h, l), x),
+                    st((rows, cols), F32), st((rows, cols), F32),
+                    st((cols,), jnp.float64))
+    assert "tpu_custom_call" in text
+
+
+def test_streamed_shell_tile_compiles_vmapped(one_chip):
+    """The tile under `jax.vmap`, as the ensemble's batched step runs it:
+    `pallas_call`'s batching rule adds a member axis to its grid (two
+    members of the walkthrough's 18,000^2 words)."""
+    from skellysim_tpu.ops import block_df
+
+    def st(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    batched = jax.vmap(lambda h, l, x: block_df.matvec_df_streamed((h, l),
+                                                                   x))
+    text = _compile(batched, st((2, 18000, 18000), F32),
+                    st((2, 18000, 18000), F32), st((2, 18000), jnp.float64))
+    assert "tpu_custom_call" in text
+
+
+def test_mixed_step_at_the_walkthroughs_shell_has_no_f64_operator_dot(
+        one_chip, monkeypatch):
+    """The mixed step on a 6,000-node shell (the walkthrough's 18,000^2
+    operator) and a 400-node body, the backend read as a TPU: the shell
+    holds its words, the residual's product is the streamed tile, and the
+    step's program has no float64 ``dot_general`` with an 18,000-wide
+    operand — where the float64 array (the parent's) has its row blocks."""
+    from skellysim_tpu.params import Params
+    from skellysim_tpu.periphery import periphery as peri
+    from skellysim_tpu.system import System
+    from skellysim_tpu.testing import make_coupled_parts
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, rows = 6000, 18000
+    shell, shape, bodies = make_coupled_parts(40, 400, jnp.float64)
+    system = System(Params(), shell_shape=shape)
+    assert system.shell_product(jnp.float64) == "df_tile"
+    state = system.make_state(shell=shell._replace(
+        M_inv=shell.M_inv.astype(F32),
+        stresslet_plus_complementary=peri.split_operator(
+            shell.stresslet_plus_complementary)), bodies=bodies)
+
+    def st(a, shape=None):
+        return jax.ShapeDtypeStruct(a.shape if shape is None else shape,
+                                    a.dtype, sharding=one_chip)
+
+    big = state.shell._replace(
+        nodes=st(state.shell.nodes, (n, 3)),
+        normals=st(state.shell.normals, (n, 3)),
+        weights=st(state.shell.weights, (n,)),
+        M_inv=st(state.shell.M_inv, (rows, rows)),
+        stresslet_plus_complementary=peri.OperatorWords(
+            *(st(w, (rows, rows))
+              for w in state.shell.stresslet_plus_complementary)),
+        density=st(state.shell.density, (rows,)))
+    abstract = jax.tree_util.tree_map(st, state._replace(shell=None))
+    words = abstract._replace(shell=big)
+    lowered = jax.jit(system._solve_impl).lower(words)
+    wide = f"{rows}xf64"
+    assert not [line for line in lowered.as_text().splitlines()
+                if "dot_general" in line and wide in line]
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    # the control: the float64 array the parent held
+    array = abstract._replace(shell=big._replace(
+        stresslet_plus_complementary=jax.ShapeDtypeStruct(
+            (rows, rows), jnp.float64, sharding=one_chip)))
+    assert [line for line in jax.jit(system._solve_impl).lower(
+        array).as_text().splitlines()
+        if "dot_general" in line and wide in line]
+
+
+def test_streamed_shell_tile_compiles_inside_the_mesh_step(topo):
+    """The streamed tile where `step_spmd` calls it in `ellipsoid_mesh4.run`:
+    inside a `shard_map` over four described chips, each multiplying its
+    6,000 rows of the 24,000^2 words by the all-gathered density."""
+    from skellysim_tpu.ops import block_df
+    from skellysim_tpu.parallel.mesh import FIBER_AXIS
+
+    n_dev, rows = 4, 24000
+    mesh = Mesh(topo.devices[:n_dev], (FIBER_AXIS,))
+    by_rows = NamedSharding(mesh, P(FIBER_AXIS))
+
+    def local(hi, lo, x):
+        full = jax.lax.all_gather(x, FIBER_AXIS, tiled=True)
+        return block_df.matvec_df_streamed((hi, lo), full)
+
+    step = jax.shard_map(local, mesh=mesh, in_specs=P(FIBER_AXIS),
+                         out_specs=P(FIBER_AXIS))
+    words = jax.ShapeDtypeStruct((rows, rows), F32, sharding=by_rows)
+    text = _compile(step, words, words,
+                    jax.ShapeDtypeStruct((rows,), jnp.float64,
+                                         sharding=by_rows))
+    # the compiler may lower the density's gather as an all-reduce (PR 36)
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text or "all-reduce" in text
