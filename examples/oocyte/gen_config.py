@@ -1,8 +1,17 @@
 #!/usr/bin/env python3
 """Surface-of-revolution (oocyte) periphery + 3000 clamped fibers.
 
-Counterpart of `/root/reference/examples/oocyte/gen_config.py`: the envelope
+Counterpart of upstream's `examples/oocyte/gen_config.py`: the envelope
 height function is revolved around x, fibers nucleate on the surface.
+
+    python gen_config.py skelly_config.toml          # the shipped 3,000
+    python gen_config.py skelly_config.toml 1024     # the cut one chip steps
+    python -m skellysim_tpu.precompute skelly_config.toml
+    python -m skellysim_tpu --config-file=skelly_config.toml
+
+The fiber count is the one thing the second form changes: the placement
+draws fiber by fiber, each against those laid before it, so the cut's
+fibers are the first 1,024 of the shipped 3,000.
 """
 
 import sys
@@ -11,36 +20,42 @@ import numpy as np
 
 from skellysim_tpu.config import ConfigRevolution, Fiber
 
-config_file = sys.argv[1] if len(sys.argv) > 1 else "skelly_config.toml"
-rng = np.random.default_rng(100)
 
-n_fibers = 3000
+def build_config(n_fibers: int = 3000):
+    """The scene with ``n_fibers`` fibers, laid as the example lays them."""
+    rng = np.random.default_rng(100)
 
-config = ConfigRevolution()
-config.params.dt_write = 0.1
-config.params.dt_initial = 1e-2
-config.params.dt_max = 1e-2
-config.params.periphery_interaction_flag = False
-config.params.seed = 350
-config.params.eta = 1.0
+    config = ConfigRevolution()
+    config.params.dt_write = 0.1
+    config.params.dt_initial = 1e-2
+    config.params.dt_max = 1e-2
+    config.params.periphery_interaction_flag = False
+    config.params.seed = 350
+    config.params.eta = 1.0
 
-config.fibers = [
-    Fiber(length=1.0, bending_rigidity=2.5e-3, force_scale=-0.05,
-          minus_clamped=True, n_nodes=32)
-    for _ in range(n_fibers)
-]
+    config.fibers = [
+        Fiber(length=1.0, bending_rigidity=2.5e-3, force_scale=-0.05,
+              minus_clamped=True, n_nodes=32)
+        for _ in range(n_fibers)
+    ]
 
-config.periphery.envelope.n_nodes_target = 6000
-config.periphery.envelope.lower_bound = -3.75
-config.periphery.envelope.upper_bound = 3.75
-config.periphery.envelope.height = \
-    "0.5 * T * ((1 + 2*x/length)**p1) * ((1 - 2*x/length)**p2) * length"
-config.periphery.envelope.T = 0.72
-config.periphery.envelope.p1 = 0.4
-config.periphery.envelope.p2 = 0.2
-config.periphery.envelope.length = 7.5
+    config.periphery.envelope.n_nodes_target = 6000
+    config.periphery.envelope.lower_bound = -3.75
+    config.periphery.envelope.upper_bound = 3.75
+    config.periphery.envelope.height = \
+        "0.5 * T * ((1 + 2*x/length)**p1) * ((1 - 2*x/length)**p2) * length"
+    config.periphery.envelope.T = 0.72
+    config.periphery.envelope.p1 = 0.4
+    config.periphery.envelope.p2 = 0.2
+    config.periphery.envelope.length = 7.5
 
-config.periphery.move_fibers_to_surface(config.fibers, ds_min=0.1, rng=rng)
+    config.periphery.move_fibers_to_surface(config.fibers, ds_min=0.1,
+                                            rng=rng, verbose=False)
+    return config
 
-config.save(config_file)
-print(f"wrote {config_file}; next: python -m skellysim_tpu.precompute")
+
+if __name__ == "__main__":
+    config_file = sys.argv[1] if len(sys.argv) > 1 else "skelly_config.toml"
+    n_fibers = int(sys.argv[2]) if len(sys.argv) > 2 else 3000
+    build_config(n_fibers).save(config_file)
+    print(f"wrote {config_file}; next: python -m skellysim_tpu.precompute")

@@ -213,6 +213,11 @@ def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None,
         shape = peri.PeripheryShape(
             kind="ellipsoid",
             abc=(cfg_periphery.a, cfg_periphery.b, cfg_periphery.c))
+    elif shape_name == "surface_of_revolution":
+        env = cfg_periphery.envelope
+        shape = peri.PeripheryShape(
+            kind="revolution",
+            bounds=(float(env["lower_bound"]), float(env["upper_bound"])))
     else:
         shape = peri.PeripheryShape(kind="generic")
     return state, shape

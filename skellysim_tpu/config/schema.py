@@ -16,6 +16,7 @@ Layout notes vs the reference:
 from __future__ import annotations
 
 import dataclasses
+import os
 from dataclasses import dataclass, field, fields, is_dataclass
 from typing import List
 
@@ -736,6 +737,18 @@ class ConfigEllipsoidal(Config):
 @dataclass
 class ConfigRevolution(Config):
     periphery: RevolutionPeriphery = field(default_factory=RevolutionPeriphery)
+
+    def save(self, filename: str = "skelly_config.toml") -> None:
+        """The envelope's discretisation picks the node count, which the
+        precompute writes back into the file it computes from. Where the
+        precompute file is there already (a cache hit), the count it holds
+        is written the same way, so a hit and a miss save one file."""
+        path = os.path.join(os.path.dirname(os.path.abspath(filename)),
+                            self.periphery.precompute_file)
+        if os.path.exists(path):
+            with np.load(path) as z:
+                self.periphery.n_nodes = int(z["nodes"].shape[0])
+        super().save(filename)
 
 
 # ---------------------------------------------------------------------------

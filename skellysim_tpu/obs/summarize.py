@@ -35,14 +35,16 @@ ANNOUNCEMENTS = {
     "refine_tile": ("refinement tile (f64 residual and prep flows)",
                     ("impl", "requested", "backend")),
     "block_precond": ("block preconditioner",
-                      ("apply", "dtype", "fibers", "bodies")),
+                      ("apply", "dtype", "fibers", "bodies", "fiber_nodes",
+                       "fiber_block")),
     "fiber_ops": ("fiber operators",
-                  ("apply", "dtype", "fibers", "fallback")),
+                  ("apply", "dtype", "fibers", "fallback", "nodes", "block",
+                   "blocks_per_step")),
     "periphery": ("periphery",
                   ("shape", "nodes", "operator", "operator_dtype",
                    "operator_bytes", "m_inv", "m_inv_dtype", "m_inv_bytes",
                    "f64_product", "row_block", "chips", "rows_per_chip",
-                   "precompute", "load_s")),
+                   "precompute", "load_s", "bounds")),
 }
 
 

@@ -46,8 +46,8 @@ from .df_kernels import _df_split
 from .pallas_df import _DF, _require_x64, _round_up
 from .pallas_kernels import _out_struct
 
-__all__ = ["split_words", "block_matvec_df", "fills_a_step",
-           "matvec_df_streamed"]
+__all__ = ["split_words", "block_matvec_df", "blocks_per_step",
+           "fills_a_step", "matvec_df_streamed"]
 
 #: bytes of ONE word of the matrix block a grid step holds in VMEM (two
 #: words, double-buffered: four times this, of the 16 MiB a kernel may
@@ -70,15 +70,20 @@ def split_words(m):
     traced arrays (the per-step blocks: split once a step, in `prep`)."""
     xp = np if isinstance(m, np.ndarray) else jnp
     rows, cols = m.shape[-2:]
-    pad = [(0, 0)] * (m.ndim - 2) + [(0, _round_up(rows, 8) - rows),
-                                     (0, _round_up(cols, 128) - cols)]
+    rows_p, cols_p = padded_block(rows, cols)
+    pad = [(0, 0)] * (m.ndim - 2) + [(0, rows_p - rows), (0, cols_p - cols)]
     m = xp.pad(m, pad)
     hi = m.astype(xp.float32)
     lo = (m - hi.astype(xp.float64)).astype(xp.float32)
     return hi, lo
 
 
-def _blocks_per_step(n_blocks: int, rows: int, cols: int) -> int:
+def padded_block(rows: int, cols: int) -> tuple[int, int]:
+    """A block's rows x columns as `split_words` pads its words."""
+    return _round_up(rows, 8), _round_up(cols, 128)
+
+
+def blocks_per_step(n_blocks: int, rows: int, cols: int) -> int:
     """Blocks a grid step takes: the largest divisor of ``n_blocks`` whose
     words fit `DF_BLOCK_BYTES` (one block at least)."""
     fit = max(1, DF_BLOCK_BYTES // (4 * rows * cols))
@@ -196,7 +201,7 @@ def block_matvec_df(words, x, *, n_rows: int, interpret: bool = False):
     xh = jnp.pad(xh, pad).reshape(nb, 1, cols)
     xl = jnp.pad(xl, pad).reshape(nb, 1, cols)
 
-    fb = _blocks_per_step(nb, rows, cols)
+    fb = blocks_per_step(nb, rows, cols)
     rows_p = _round_up(rows, 128)
     z = np.int32(0)  # i64/i32 index-map mix breaks Mosaic (pallas_kernels)
     if shared:

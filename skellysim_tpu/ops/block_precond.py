@@ -63,13 +63,15 @@ def solve(caches, x):
 def describe(caches, body_caches) -> dict:
     """The fields of a step's ``block_precond`` announcement, read off the
     fiber and body caches `prep` made (lists of buckets, or None): e.g.
-    ``apply="inverse", dtype="float32", fibers="256x256x256", bodies="-"``.
-    Several buckets join their shapes with ``+``; ``-`` stands for none."""
+    ``apply="inverse", dtype="float32", fibers="256x256x256", bodies="-",
+    fiber_nodes="64", fiber_block="256x256"`` (a fiber of n nodes has a
+    block of 4n rows). Several buckets join with ``+``; ``-`` stands for
+    none."""
     def stored(c):
         return c.inv if c.inv is not None else c.lu
 
-    def shapes(cs):
-        return "+".join("x".join(map(str, stored(c).shape)) for c in cs) or "-"
+    def shapes(cs, fn=lambda s: "x".join(map(str, s))):
+        return "+".join(fn(stored(c).shape) for c in cs) or "-"
 
     fibers, bodies = list(caches or []), list(body_caches or [])
     apply = dtype = "-"
@@ -77,4 +79,6 @@ def describe(caches, body_caches) -> dict:
         apply = "inverse" if c.inv is not None else "lu_solve"
         dtype = str(stored(c).dtype)
     return dict(apply=apply, dtype=dtype, fibers=shapes(fibers),
-                bodies=shapes(bodies))
+                bodies=shapes(bodies),
+                fiber_nodes=shapes(fibers, lambda s: str(s[-1] // 4)),
+                fiber_block=shapes(fibers, lambda s: f"{s[-2]}x{s[-1]}"))

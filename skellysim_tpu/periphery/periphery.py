@@ -71,11 +71,14 @@ class PeripheryState(NamedTuple):
 
 @dataclass(frozen=True)
 class PeripheryShape:
-    """Static collision geometry. kind: 'sphere' | 'ellipsoid' | 'generic'."""
+    """Static collision geometry. kind: 'sphere' | 'ellipsoid' | 'generic'
+    | 'revolution' (a surface of revolution: no wall physics, as
+    'generic'; ``bounds`` its envelope's x range)."""
 
     kind: str = "generic"
     radius: float = 0.0
     abc: tuple = (0.0, 0.0, 0.0)
+    bounds: tuple = ()
 
 
 def build_shell_operator(nodes, normals, weights, eta: float = 1.0):

@@ -7,8 +7,8 @@ gradient for exact normals (consumed by the quadrature and collision checks).
 
 The surface-of-revolution envelope takes the user's height expression (a string
 over ``x`` with numpy available as ``np``, matching the reference's TOML
-contract) and fits a Chebyshev proxy for fast evaluation/differentiation,
-replacing the reference's `function_generator` dependency.
+contract) and evaluates and differentiates it directly (`Envelope`), where the
+reference fits it with its `function_generator` dependency.
 """
 
 from __future__ import annotations
@@ -77,8 +77,14 @@ class Envelope:
     Accepts the reference's config contract (`shape_gallery.py:6-42`):
     ``height`` is a python expression in ``x``, with ``lower_bound``,
     ``upper_bound`` and any extra constants available as names in the
-    expression. Internally fits a high-degree Chebyshev approximation for
-    differentiation and fast evaluation.
+    expression. The height and its slope are the expression's own: the
+    slope by a complex step, exact to rounding wherever numpy evaluates the
+    expression in complex arithmetic (a central difference where it does
+    not). A high-degree Chebyshev fit is kept for the precompute file's
+    record only (`get_state`): near the ends of a height such as
+    ``(1 + 2x/L)**0.4``, whose slope is unbounded there, its derivative is
+    off by percents, which tilts the normals and the quadrature weights
+    built on them.
     """
 
     def __init__(self, config: dict):
@@ -96,13 +102,28 @@ class Envelope:
         x = 0.5 * (lo + hi) + 0.5 * (hi - lo) * np.cos(np.pi * np.arange(2000) / 1999)
         self._cheb = np.polynomial.Chebyshev.fit(x, self.raw_height(x), deg=200,
                                                  domain=[lo, hi])
-        self._dcheb = self._cheb.deriv()
 
     def __call__(self, x):
-        return self._cheb(np.clip(x, self.lower_bound, self.upper_bound))
+        return self.raw_height(np.clip(x, self.lower_bound, self.upper_bound))
 
     def differentiate(self, x):
-        return self._dcheb(np.clip(x, self.lower_bound, self.upper_bound))
+        x = np.clip(np.asarray(x, dtype=float), self.lower_bound,
+                    self.upper_bound)
+        step = 1e-30 * max(1.0, self.upper_bound - self.lower_bound)
+        try:
+            with np.errstate(all="ignore"):
+                h = self.raw_height(x + 1j * step)
+            # an expression that drops the imaginary part (np.abs, ...)
+            # has no complex step to give
+            slope = np.imag(h) / step if np.iscomplexobj(h) else None
+        except (TypeError, ValueError):
+            slope = None
+        if slope is None or not np.all(np.isfinite(slope)):
+            dx = 1e-6 * (self.upper_bound - self.lower_bound)
+            lo = np.maximum(x - dx, self.lower_bound)
+            hi = np.minimum(x + dx, self.upper_bound)
+            slope = (self.raw_height(hi) - self.raw_height(lo)) / (hi - lo)
+        return slope
 
     def get_state(self) -> dict:
         """Serializable fit state (coefficient vector + bounds) for npz files."""
@@ -114,7 +135,11 @@ class Envelope:
 
 
 def surface_of_revolution_shape(envelope_config: dict, scale_factor: float = 1.0) -> ShapeSpec:
-    """Arclength-equispaced rings around the x axis (`shape_gallery.py:151-214`)."""
+    """Arclength-equispaced rings around the x axis (`shape_gallery.py:151-214`).
+
+    The nodes lie on the envelope's surface scaled by ``scale_factor`` k
+    (r = k h(x / k)), and ``h`` / ``gradh`` are that surface's: its level
+    function and its gradient, the end caps at k x the bounds."""
     env = Envelope(envelope_config)
     target_nodes = int(envelope_config["n_nodes_target"])
     n_x = int(round(np.sqrt(target_nodes)))
@@ -138,24 +163,24 @@ def surface_of_revolution_shape(envelope_config: dict, scale_factor: float = 1.0
         theta = 2 * np.pi * np.arange(n_rad) / n_rad
         for th in theta:
             nodes.append([xi, ri * np.cos(th), ri * np.sin(th)])
-    nodes = np.asarray(nodes) * scale_factor
+    k = scale_factor
+    nodes = np.asarray(nodes) * k
 
     def h(p):
-        return env.raw_height(p[:, 0]) ** 2 - p[:, 1] ** 2 - p[:, 2] ** 2
+        return (k * env(p[:, 0] / k)) ** 2 - p[:, 1] ** 2 - p[:, 2] ** 2
 
     def gradh(p):
         out = np.zeros_like(p)
         x, y, z = p[:, 0], p[:, 1], p[:, 2]
-        hv = env(x)
-        dh = env.differentiate(x)
-        out[:, 0] = -hv * dh
+        # d/dx of (k h(x / k))^2 / 2 = k h(x / k) h'(x / k)
+        out[:, 0] = -k * env(x / k) * env.differentiate(x / k)
         out[:, 1] = y
         out[:, 2] = z
         nrm = np.linalg.norm(out, axis=1, keepdims=True)
         out /= np.where(nrm > 0, nrm, 1.0)
         # end caps point along the axis
-        out[x <= env.lower_bound] = [-1.0, 0.0, 0.0]
-        out[x >= env.upper_bound] = [1.0, 0.0, 0.0]
+        out[x <= k * env.lower_bound] = [-1.0, 0.0, 0.0]
+        out[x >= k * env.upper_bound] = [1.0, 0.0, 0.0]
         return out
 
     normals = gradh(nodes)

@@ -351,7 +351,9 @@ class System:
         log and as a ``block_precond`` event."""
         fields = block_precond.describe(caches, body_caches)
         logger.info("block_precond apply=%(apply)s dtype=%(dtype)s "
-                    "fibers=%(fibers)s bodies=%(bodies)s", fields)
+                    "fibers=%(fibers)s bodies=%(bodies)s "
+                    "fiber_nodes=%(fiber_nodes)s fiber_block=%(fiber_block)s",
+                    fields)
         obs_tracer.emit("block_precond", **fields)
 
     #: tests and `scripts/fiber_ops_parity.py` only: "df_tile" takes the
@@ -394,6 +396,10 @@ class System:
         taken = [self._fiber_ops_for(state, precision, g) for g in buckets]
         applies = sorted({a for a, _ in taken})
         reasons = sorted({r for _, r in taken if r != "-"})
+
+        def per_bucket(fn):
+            return "+".join(fn(g, a) for g, (a, _) in zip(buckets, taken))
+
         fields = dict(
             apply="+".join(applies) or "-",
             dtype="+".join("float32x2" if a == "df_tile"
@@ -401,9 +407,22 @@ class System:
                            for a in applies) or "-",
             fibers="+".join(f"{g.n_fibers}x{4 * g.n_nodes}x{4 * g.n_nodes}"
                             for g in buckets) or "-",
-            fallback="+".join(reasons) or ("-" if buckets else "no_fibers"))
+            fallback="+".join(reasons) or ("-" if buckets else "no_fibers"),
+            # each bucket's resolution, its blocks' rows x columns as the
+            # tile holds them, and the blocks a grid step of it takes
+            nodes=per_bucket(lambda g, a: str(g.n_nodes)) or "-",
+            block=per_bucket(lambda g, a: "x".join(
+                map(str, block_df.padded_block(4 * g.n_nodes,
+                                               4 * g.n_nodes)))) or "-",
+            blocks_per_step=per_bucket(lambda g, a: str(
+                block_df.blocks_per_step(
+                    g.n_fibers, *block_df.padded_block(4 * g.n_nodes,
+                                                       4 * g.n_nodes)))
+                if a == "df_tile" else "-") or "-")
         logger.info("fiber_ops apply=%(apply)s dtype=%(dtype)s "
-                    "fibers=%(fibers)s fallback=%(fallback)s", fields)
+                    "fibers=%(fibers)s fallback=%(fallback)s "
+                    "nodes=%(nodes)s block=%(block)s "
+                    "blocks_per_step=%(blocks_per_step)s", fields)
         obs_tracer.emit("fiber_ops", **fields)
 
     def shell_product(self, dtype) -> str:
@@ -448,18 +467,21 @@ class System:
         if state.shell is None:
             return
         src = self.shell_precompute or {}
+        bounds = self.shell_shape.bounds if self.shell_shape else ()
         fields = dict(
             shape=self.shell_shape.kind if self.shell_shape else "generic",
             **peri.describe(state.shell, chips),
             precompute=src.get("file", "-"),
-            load_s=round(src.get("load_s", 0.0), 3))
+            load_s=round(src.get("load_s", 0.0), 3),
+            bounds=":".join(f"{b:g}" for b in bounds) or "-")
         logger.info(
             "periphery shape=%(shape)s nodes=%(nodes)d "
             "operator=%(operator)s %(operator_dtype)s %(operator_bytes)dB "
             "m_inv=%(m_inv)s %(m_inv_dtype)s %(m_inv_bytes)dB "
             "f64_product=%(f64_product)s row_block=%(row_block)d "
             "chips=%(chips)d rows_per_chip=%(rows_per_chip)d "
-            "precompute=%(precompute)s load_s=%(load_s).3f", fields)
+            "precompute=%(precompute)s load_s=%(load_s).3f "
+            "bounds=%(bounds)s", fields)
         obs_tracer.emit("periphery", **fields)
         resolved = self.shell_product(state.time.dtype)
         if (resolved == "df_tile") != (fields["f64_product"] == "df_tile"):

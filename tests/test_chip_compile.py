@@ -220,11 +220,12 @@ def test_f64_direct_tile_compiles(one_chip):
     _compile(lambda s, t, p: stokeslet_direct(s, t, p, 1.0), r_src, r_trg, f)
 
 
-@pytest.mark.parametrize("blocks,width", [(256, 256), (1, 1206)])
+@pytest.mark.parametrize("blocks,width", [(256, 256), (1, 1206),
+                                          (1024, 128)])
 def test_block_inverse_compiles(one_chip, blocks, width):
     """The mixed tier's block preconditioner (`ops.block_precond`) at the
     benchmark's widths — 256 fiber blocks of 256^2, the walkthrough body's
-    1,206^2: the triangular solves (XLA's `InvertDiagBlocks*` custom calls
+    1,206^2, `oocyte_1024.run`'s 1,024 blocks of 128^2: the triangular solves (XLA's `InvertDiagBlocks*` custom calls
     on a TPU) stay where the inverse is formed, once a step; an application
     holds none."""
     from collections import namedtuple
@@ -248,14 +249,19 @@ def test_block_inverse_compiles(one_chip, blocks, width):
 @pytest.mark.parametrize("blocks,rows,cols,shared,vmapped", [
     (256, 256, 256, False, False), (256, 192, 256, False, False),
     (256, 248, 256, True, False), (256, 64, 128, True, False),
-    (1, 256, 256, False, False), (16, 256, 256, False, True)])
+    (1, 256, 256, False, False), (16, 256, 256, False, True),
+    (1024, 128, 128, False, False), (1024, 96, 128, False, False),
+    (1024, 120, 128, True, False), (1024, 32, 128, True, False)])
 def test_block_df_tile_compiles(one_chip, blocks, rows, cols, shared, vmapped):
     """The double-float block matvec (`ops.block_df`) at the fiber cells'
     shapes — 256 blocks of `A_bc` 256^2 and of the force operator 192 x 256,
     the shared `P_down` (242 rows, split to 248) and `D1` (64 columns, split
-    to 128 lanes) — the walkthrough's one block, and under a `vmap` of two
-    members as the ensemble calls it: Mosaic takes the strips, the 128 x 128
-    transposes and the sublane rolls."""
+    to 128 lanes) — the walkthrough's one block, under a `vmap` of two
+    members as the ensemble calls it, and the 32-node bucket of
+    `oocyte_1024.run` (1,024 blocks of 128^2, 32 a grid step; the force
+    operator 96 x 128, `P_down` 114 rows split to 120, `D1` 32 x 32 split
+    to 32 x 128): Mosaic takes the strips, the 128 x 128 transposes and the
+    sublane rolls."""
     from skellysim_tpu.ops import block_df
 
     def st(shape, dtype):
@@ -323,11 +329,13 @@ def test_gmres_live_row_walk_compiles(topo, n_dev):
     assert ("all-reduce" in text) == (n_dev > 1)
 
 
-@pytest.mark.parametrize("rows,cols", [(24000, 24000), (6000, 24000)])
+@pytest.mark.parametrize("rows,cols", [(24000, 24000), (6000, 24000),
+                                       (19293, 19293)])
 def test_streamed_shell_tile_compiles(one_chip, rows, cols):
     """The streamed double-float tile (`ops.block_df.matvec_df_streamed`)
-    at `ellipsoid_256.run`'s whole 24,000^2 shell operator and at a chip's
-    6,000 rows of it in `ellipsoid_mesh4.run`: ragged blocks both ways."""
+    at `ellipsoid_256.run`'s whole 24,000^2 shell operator, at a chip's
+    6,000 rows of it in `ellipsoid_mesh4.run`, and at `oocyte_1024.run`'s
+    19,293^2 (odd, no multiple of 8): ragged blocks both ways."""
     from skellysim_tpu.ops import block_df
 
     def st(shape, dtype):

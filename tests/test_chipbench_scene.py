@@ -204,3 +204,106 @@ def test_ellipsoid_mesh_toy_builds_on_four_devices_and_steps(tmp_path,
     snap = run.snapshot(state)
     assert snap["shell_density"].shape == (3 * cfg["periphery"]["n_nodes"],)
     assert len(state.shell.density.sharding.device_set) == 4
+
+
+# ------------------------------------------------------------ `oocyte_1024`
+
+#: sha256 of `build_config(oocyte_1024, seed).save(path)`'s file, recorded
+#: when the configuration was added; the seed is the one line that
+#: differs (`scene._on_periphery`: --seed does not move the scene)
+OOCYTE_1024_PINS = {
+    0: "a89b2212cbff4b38a647548284f432a45c89cf8114ca5fe7b4fbf005bdb50563",
+    5: "4376e1fb3faf5a80667d78efa02c01f65f1bc59e72f102aefd67e4e372549076",
+    2147531004:
+        "3b047c1370977df9c3afeafe1e1da617074e811f56469e50aa613031a32e5ba5",
+}
+#: a changed key is a cache miss: the host precompute at 19,293 rows
+OOCYTE_1024_PRECOMPUTE_KEY = "203ac1e332069ad96d0f"
+#: the minus ends of the first two fibers the seeded toolkit call lays
+OOCYTE_1024_FIRST_ENDS = [
+    [3.0856834721432964, -1.9951168445876066, -1.3845492679451565],
+    [-1.0831733389631255, 2.388792903254884, 0.6607904402265402]]
+
+
+def _oocyte_example():
+    spec = importlib.util.spec_from_file_location(
+        "oocyte_gen_config", os.path.join(ROOT, "examples", "oocyte",
+                                          "gen_config.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("seed", sorted(OOCYTE_1024_PINS))
+def test_oocyte_1024_builds_the_toml_it_was_added_with(tmp_path, seed):
+    import numpy as np
+    import scene
+
+    cfg = _scene_tests._configuration("oocyte_1024")
+    config = scene.build_config(cfg, seed)
+    toml = _scene_tests._saved(config, tmp_path / "c.toml")
+    assert hashlib.sha256(toml).hexdigest() == OOCYTE_1024_PINS[seed]
+    assert scene.precompute_key(cfg) == OOCYTE_1024_PRECOMPUTE_KEY
+    assert len(config.fibers) == 1024
+    assert {f.n_nodes for f in config.fibers} == {32}
+    np.testing.assert_allclose([f.x[:3] for f in config.fibers[:2]],
+                               OOCYTE_1024_FIRST_ENDS, rtol=0, atol=1e-12)
+
+
+def test_oocyte_1024_is_the_examples_own_construction(tmp_path):
+    """`examples/oocyte/gen_config.py`'s `build_config(1024)`, with what a
+    configuration here adds to every scene (the adaptive gate off,
+    `--seed`) and a ``t_final`` past any window."""
+    import scene
+
+    cfg = _scene_tests._configuration("oocyte_1024")
+    assert cfg["reduced"] == ["n_fibers"]
+    assert cfg["published"] == {"n_fibers": 3000}
+    assert cfg["reference"] == "revolution_shell_step"
+    assert cfg["guarantees"]["gmres_tol"] == cfg["limits"]["ref_residual"]
+    theirs = _oocyte_example().build_config(1024)
+    theirs.params.adaptive_timestep_flag = False
+    theirs.params.seed = 11
+    theirs.params.t_final = cfg["params"]["t_final"]
+    assert (_scene_tests._saved(scene.build_config(cfg, 11),
+                                tmp_path / "ours.toml")
+            == _scene_tests._saved(theirs, tmp_path / "theirs.toml"))
+
+
+def test_revolution_scene_saves_one_toml_on_a_hit_and_a_miss(tmp_path,
+                                                             monkeypatch):
+    """The envelope's discretisation picks the node count: the precompute
+    writes it into the TOML on a miss, and `ConfigRevolution.save` reads it
+    from the precompute file on a hit, so both give one file."""
+    import jax
+    import numpy as np
+    import scene
+
+    jax.config.update("jax_enable_x64", True)
+    monkeypatch.setattr(scene, "CACHE_DIR", str(tmp_path / "cache"))
+    cfg = _scene_tests._toy("revolution_toy")
+    miss = scene.write_scene(cfg, 7, str(tmp_path / "miss"), log=lambda *_: 0)
+    hit = scene.write_scene(cfg, 7, str(tmp_path / "hit"), log=lambda *_: 0)
+    assert (miss["precompute"], hit["precompute"]) == ("miss", "hit")
+    toml = [open(info["config_path"], "rb").read() for info in (miss, hit)]
+    assert toml[0] == toml[1]
+    from skellysim_tpu.config import load_config
+
+    n = load_config(hit["config_path"]).periphery.n_nodes
+    with np.load(os.path.join(hit["precompute_dir"],
+                              "periphery_precompute.npz")) as z:
+        assert n == z["nodes"].shape[0] > 0
+
+
+def test_oocyte_cut_lays_the_first_fibers_of_the_shipped_scene():
+    """The surface of revolution's placement draws fiber by fiber, each
+    against those laid before it: the cut's 1,024 fibers are the first
+    1,024 of the example's 3,000 (the ellipsoid's draws its trial points up
+    front, so its cuts are not a prefix)."""
+    import numpy as np
+
+    example = _oocyte_example()
+    cut, shipped = example.build_config(1024), example.build_config(3000)
+    assert len(shipped.fibers) == 3000
+    np.testing.assert_array_equal([f.x for f in cut.fibers],
+                                  [f.x for f in shipped.fibers[:1024]])
