@@ -14,6 +14,10 @@ ellipsoid, `f_dl = 2 eta n (x) rho`. On the chip:
 
 (lines kept in `chiprun_out/pair_tile_rates.jsonl`); `--cpu` is the dry run
 here, every shape cut to a few hundred nodes, its times worth nothing.
+
+`--tiles 256x2048x512,...` also times the Pallas tile itself at each
+(tile_t, tile_s, strip_w) on the shapes `--shapes` picks (indices into
+`SHAPES`), the sweep its defaults in `ops.pallas_kernels` were pinned from.
 """
 
 from __future__ import annotations
@@ -39,6 +43,8 @@ SHAPES = [
     ("stokeslet", 16384, 18384, "ellipsoid_mesh4: one ring block a chip"),
     ("stokeslet", 16384, 16384, "free_fibers_256: the control (3.17 ms)"),
     ("stokeslet", 64, 6464, "walkthrough: the fiber onto all nodes"),
+    ("stokeslet", 32768, 39199, "oocyte_1024: the fibers onto all nodes"),
+    ("stresslet", 6431, 32768, "oocyte_1024: the shell onto the fiber nodes"),
 ]
 SEMI_AXES = (7.8, 4.16, 4.16)
 ETA = 1.0
@@ -81,9 +87,16 @@ def main(argv=None) -> int:
     ap.add_argument("--cpu", action="store_true",
                     help="dry run on the CPU at a few hundred nodes")
     ap.add_argument("--seed", type=int, default=37)
+    ap.add_argument("--shapes", default=",".join(map(str, range(len(SHAPES)))),
+                    help="indices into SHAPES, comma separated")
+    ap.add_argument("--tiles", default="",
+                    help="tile_t x tile_s x strip_w of the Pallas tile to "
+                         "time on each shape, comma separated")
     ap.add_argument("--out", default=os.path.join(
         ROOT, "chiprun_out", "pair_tile_rates.jsonl"))
     args = ap.parse_args(argv)
+    tiles = [tuple(int(v) for v in t.split("x"))
+             for t in args.tiles.split(",") if t]
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -92,7 +105,7 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from skellysim_tpu.ops import kernels
+    from skellysim_tpu.ops import kernels, pallas_kernels
 
     jax.config.update("jax_enable_x64", True)   # as every CLI runs
     dev = jax.devices()[0]
@@ -110,7 +123,8 @@ def main(argv=None) -> int:
 
         emit(device=dict(platform=dev.platform, kind=dev.device_kind),
              jax=jax.__version__, seed=args.seed)
-        for kind, n_src, n_trg, where in SHAPES:
+        for index in map(int, args.shapes.split(",")):
+            kind, n_src, n_trg, where = SHAPES[index]
             if args.cpu:
                 n_src, n_trg = min(n_src, 300), min(n_trg, 200)
             fn = getattr(kernels, f"{kind}_direct")
@@ -118,24 +132,38 @@ def main(argv=None) -> int:
             ref = _oracle(kind, src, trg[:64], pay)
             dev_args = [jnp.asarray(a, jnp.float32) for a in (src, trg, pay)]
             got = {}
-            for impl in ("exact", "pallas", "auto"):
-                taken = kernels.resolve_impl(impl, *dev_args)
+
+            def timed(call, **fields):
                 t0 = time.perf_counter()
-                u = fn(*dev_args, ETA, impl=impl).block_until_ready()
+                u = call().block_until_ready()
                 first = time.perf_counter() - t0
                 ms = []
                 for _ in range(5):
                     t0 = time.perf_counter()
-                    fn(*dev_args, ETA, impl=impl).block_until_ready()
+                    call().block_until_ready()
                     ms.append(1e3 * (time.perf_counter() - t0))
-                got[impl] = u = np.asarray(u, np.float64)
+                u = np.asarray(u, np.float64)
                 med = statistics.median(ms)
                 emit(kind=kind, n_src=n_src, n_trg=n_trg, where=where,
-                     impl=impl, taken=taken, first_call_s=round(first, 3),
+                     **fields, first_call_s=round(first, 3),
                      ms=[round(m, 4) for m in ms], ms_median=round(med, 4),
                      gpairs_per_s=round(n_src * n_trg / med / 1e6, 3),
                      err_vs_f64=float(np.abs(u[:64] - ref).max()
-                                      / np.abs(ref).max()))
+                                      / np.abs(ref).max()),
+                     **({"vs_exact": float(np.abs(u - got["exact"]).max()
+                                           / np.abs(got["exact"]).max())}
+                        if "exact" in got else {}))
+                return u
+
+            for impl in ("exact", "pallas", "auto"):
+                got[impl] = timed(
+                    lambda: fn(*dev_args, ETA, impl=impl), impl=impl,
+                    taken=kernels.resolve_impl(impl, *dev_args))
+            tile_fn = getattr(pallas_kernels, f"{kind}_pallas")
+            for tile in tiles:
+                kw = dict(zip(("tile_t", "tile_s", "strip_w"), tile))
+                timed(lambda: tile_fn(*dev_args, ETA, interpret=args.cpu,
+                                      **kw), impl="pallas", tile=list(tile))
             scale = np.abs(got["exact"]).max()
             emit(kind=kind, n_src=n_src, n_trg=n_trg,
                  pallas_vs_exact=float(

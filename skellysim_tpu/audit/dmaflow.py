@@ -65,6 +65,7 @@ contract pins.
 
 from __future__ import annotations
 
+import math
 from collections import deque
 from dataclasses import dataclass
 
@@ -125,15 +126,28 @@ def fused_ring_within_budget(payload_rows: int, n_dev: int, nt: int,
             and fp["comm_floats"] <= VMEM_COMM_BUDGET)
 
 
-def gridded_footprint(tile_t: int, tile_s: int) -> dict:
-    """VMEM terms of one gridded interaction tile (floats): the
-    [tile_t, tile_s] pair intermediates dominate the block operands."""
-    return {"pair_elems": tile_t * tile_s}
+#: cap on what one gridded tile holds in VMEM (floats): half of a v5e's
+#: 16 MiB of scoped VMEM, the other half left to the compiler's own
+#: buffers; the strip tiles hold 460,288 (Stokeslet) and 525,824
+#: (stresslet) at their default geometry
+VMEM_GRIDDED_BUDGET = 1 << 21
 
 
-def gridded_within_budget(tile_t: int, tile_s: int) -> bool:
-    return gridded_footprint(tile_t, tile_s)["pair_elems"] \
-        <= VMEM_PAIR_BUDGET
+def _floats(shape) -> int:
+    return math.prod(getattr(d, "block_size", d) or 1 for d in shape)
+
+
+def gridded_footprint(block_shapes, scratch_shapes) -> dict:
+    """VMEM one gridded tile holds (floats): every block operand twice (the
+    pipeline fetches the next block while the kernel works on this one),
+    and its scratch once."""
+    return {"vmem_floats": 2 * sum(map(_floats, block_shapes))
+            + sum(map(_floats, scratch_shapes))}
+
+
+def gridded_within_budget(block_shapes, scratch_shapes) -> bool:
+    return gridded_footprint(block_shapes, scratch_shapes)["vmem_floats"] \
+        <= VMEM_GRIDDED_BUDGET
 
 
 # ------------------------------------------------- jaxpr walking / decoding
@@ -561,6 +575,10 @@ def _aval_str(var):
     return str(getattr(var, "aval", ""))
 
 
+def _aval_shape(var):
+    return getattr(getattr(var.aval, "inner_aval", var.aval), "shape", ())
+
+
 def _ref_name(var, names):
     return names.get(id(var), "ref")
 
@@ -778,27 +796,19 @@ def analyze(built) -> DmaReport:
     # (4) VMEM accounting
     observed = {}
     scratch = invars[n_in + n_out:]
-    comm_refs = [v for v in scratch if "dma_sem" not in _aval_str(v)
+    vmem_refs = [v for v in scratch if "dma_sem" not in _aval_str(v)
                  and "barrier" not in _aval_str(v)
                  and "sem" not in _aval_str(v)]
-    dma_sem_slots = 0
-    for v in scratch:
-        if "dma_sem" in _aval_str(v):
-            shape = getattr(getattr(v.aval, "inner_aval", v.aval),
-                            "shape", ())
-            n = 1
-            for d in shape:
-                n *= d
-            dma_sem_slots += n
+    dma_sem_slots = sum(math.prod(_aval_shape(v)) for v in scratch
+                        if "dma_sem" in _aval_str(v))
     if remote_starts:
-        comm = comm_refs[0] if comm_refs else None
+        comm = vmem_refs[0] if vmem_refs else None
         if comm is None:
             findings.append(DmaFinding(KIND_STRUCT, (
                 f"{KIND_STRUCT}: ring kernel has remote DMA but no VMEM "
                 "comm scratch the analyzer can account")))
             return DmaReport(findings, observed)
-        cshape = getattr(getattr(comm.aval, "inner_aval", comm.aval),
-                         "shape", ())
+        cshape = _aval_shape(comm)
         slots, rows, ns = (cshape + (0, 0, 0))[:3]
         nt = _last_block_dim(gm.block_mappings[n_in])
         payload_rows = rows - 3
@@ -825,26 +835,25 @@ def analyze(built) -> DmaReport:
         })
         if skew_bound is not None:
             observed["phase_skew_bound"] = skew_bound
+        observed["pair_budget"] = VMEM_PAIR_BUDGET
+        observed["comm_budget"] = VMEM_COMM_BUDGET
     else:
-        tile_t = _last_block_dim(gm.block_mappings[n_in])
-        tile_s = max((_last_block_dim(bm)
-                      for bm in gm.block_mappings[:n_in]), default=0)
-        fp = gridded_footprint(tile_t, tile_s)
-        if not gridded_within_budget(tile_t, tile_s):
+        blocks = [bm.block_shape for bm in gm.block_mappings[:n_in + n_out]]
+        scratch_shapes = [_aval_shape(v) for v in vmem_refs]
+        fp = gridded_footprint(blocks, scratch_shapes)
+        if not gridded_within_budget(blocks, scratch_shapes):
             findings.append(DmaFinding(KIND_VMEM, (
                 f"{KIND_VMEM}: gridded tile footprint over budget — "
-                f"pair {fp['pair_elems']} elems (budget "
-                f"{VMEM_PAIR_BUDGET}) for tile_t={tile_t}, "
-                f"tile_s={tile_s}")))
+                f"{fp['vmem_floats']} floats (budget "
+                f"{VMEM_GRIDDED_BUDGET}) for blocks {blocks} and scratch "
+                f"{scratch_shapes}")))
         observed.update({
             "kernel": "gridded", "n_dev": n_dev, "comm_slots": 0,
             "remote_writes": 0, "dma_sem_slots": dma_sem_slots,
             "barrier_signals": bar_sig, "barrier_waits": bar_wait,
-            "pair_elems": fp["pair_elems"],
+            "vmem_floats": fp["vmem_floats"],
+            "vmem_budget": VMEM_GRIDDED_BUDGET,
         })
-    observed["pair_budget"] = VMEM_PAIR_BUDGET
-    if remote_starts:
-        observed["comm_budget"] = VMEM_COMM_BUDGET
 
     # formula-vs-builder pin: the registered scene must agree with the
     # build-time eligibility check (one formula, consulted twice)

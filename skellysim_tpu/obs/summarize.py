@@ -27,11 +27,14 @@ def _fmt_s(v: float) -> str:
     return f"{v:.4f}"
 
 
-#: events a build announces once, and how `render` prints them: event ->
-#: (section title, the fields of its line, in order)
+#: events a build announces once (``f32_tile``: once for each shape it
+#: traces), and how `render` prints them: event -> (section title, the
+#: fields of its line, in order); events of one title share its section
 ANNOUNCEMENTS = {
     "pair_tile": ("pair tile (Krylov loop)",
                   ("impl", "requested", "backend", "dtype")),
+    "f32_tile": ("pair tile (Krylov loop)",
+                 ("kind", "n_trg", "n_src", "tile", "strip_w", "grid")),
     "refine_tile": ("refinement tile (f64 residual and prep flows)",
                     ("impl", "requested", "backend")),
     "block_precond": ("block preconditioner",
@@ -296,12 +299,15 @@ class Summary:
         out.append("")
 
     def _announcement_sections(self, out: list[str]):
-        for ev, (title, _) in ANNOUNCEMENTS.items():
-            if ev not in self.announcements:
+        for title in dict.fromkeys(t for t, _ in ANNOUNCEMENTS.values()):
+            evs = [ev for ev, (t, _) in ANNOUNCEMENTS.items()
+                   if t == title and ev in self.announcements]
+            if not evs:
                 continue
             out.append(f"== {title} ==")
-            out.extend(f"{ev} {line}  (builds: {n})" for line, n
-                       in sorted(self.announcements[ev].items()))
+            for ev in evs:
+                out.extend(f"{ev} {line}  (builds: {n})" for line, n
+                           in sorted(self.announcements[ev].items()))
             out.append("")
 
     def _fault_section(self, out: list[str]):

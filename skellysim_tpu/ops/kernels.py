@@ -315,10 +315,10 @@ def stokeslet_direct(r_src, r_trg, f_src, eta, *, block_size: int = 4096,
         return u.astype(r_trg.dtype)  # see the pallas_df branch
     impl = resolve_impl(impl, r_trg, r_src, f_src)
     if impl == "pallas":
-        # fused VMEM-tile kernel (`ops.pallas_kernels`); Mosaic lowering on
-        # real TPUs (84.7 Gpairs/s in the step against 13.7-19.2 for the XLA
-        # path on a v5e; ledger, PR 29 and PR 36), interpret mode on CPU
-        # (tests / fallback).
+        # fused VMEM strip tile (`ops.pallas_kernels`); Mosaic lowering on
+        # real TPUs (93-129 Gpairs/s standalone on the host clock against
+        # 13.7-19.2 for the XLA path on a v5e; PERF.md section 6),
+        # interpret mode on CPU (tests / fallback).
         from .pallas_kernels import stokeslet_pallas
 
         return stokeslet_pallas(r_src, r_trg, f_src, eta,

@@ -1,11 +1,15 @@
 """Pallas TPU kernels for the hot pairwise Stokes sums.
 
 The XLA path (`ops.kernels`) materializes [block, n_src] displacement tensors
-in HBM between fused ops; these kernels keep the whole interaction tile in
-VMEM: coordinates live transposed as [3, N] so the source axis is the 128-wide
-lane dimension, each grid cell computes a [TILE_T, TILE_S] interaction block
-with pure VPU arithmetic (~20 flops/pair, no MXU dependency), and target tiles
-accumulate across the sequential source-tile grid axis.
+in HBM between fused ops; these kernels keep the whole interaction in VMEM
+and vector registers. Coordinates live transposed as [3, N], so the source
+axis is the 128-wide lane dimension. Each grid cell holds a (tile_t, tile_s)
+block and walks it in strips of 8 targets x ``w`` sources, whose values fit
+in vregs: each lane accumulates its pair terms over the whole source axis of
+the grid in a VMEM scratch, and the lanes reduce once a target tile. That is
+the double-float tile's structure (`ops.pallas_df`) with one f32 word in
+place of two: 30 VPU operations a Stokeslet pair and 40 a stresslet pair,
+plus one rsqrt (with its Newton step, ~8 more), with no MXU dependency.
 
 Numerics follow `ops.kernels.stokeslet_block` exactly: coincident pairs (r == 0)
 contribute zero. Padded sources contribute exactly zero because their
@@ -21,8 +25,10 @@ backend-consistency tests (SURVEY.md §4.1).
 
 from __future__ import annotations
 
+import logging
 import math
 from functools import partial
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -31,19 +37,25 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+logger = logging.getLogger("skellysim_tpu")
+
 # sentinel for padded source coordinates: far enough that rinv underflows to
 # exactly 0 in f32, small enough that r^2 stays finite
 _PAD_SENTINEL = 1e18
 
-# Tile shapes swept on a v5 lite chip (round 5): stokeslet peaks at
-# (256, 1024) ~53 Gpairs/s, stresslet at (128, 2048) ~48 Gpairs/s — the
-# stresslet's 9-row source tile wants a wider lane dim at a shorter target
-# tile to fit VMEM. Larger source tiles (512x2048+) exceed VMEM and fail to
-# compile.
-DEFAULT_TILE_T = 256
-DEFAULT_TILE_S = 1024
-STRESSLET_TILE_T = 128
-STRESSLET_TILE_S = 2048
+# Block, strip and unroll of both tiles, each the largest a call may take
+# (`_geometry` and `_unroll` clip them to the call's shapes), swept on a v5e
+# (PERF.md section 6): a strip of 8 x 512 lanes, 8 chunks a loop trip, in
+# 128 x 4,096 blocks takes 2.88 ms for the f32 Stokeslet at 16,384^2 and
+# 9.97 ms at 32,768 x 39,199 on the host clock (the whole-block tile it
+# replaced: 4.02 / 16.17 ms), 2.09 ms for the stresslet at 8,000 -> 16,384
+# (2.69). Strips of 256 or 1,024 lanes and other blocks were 1-24 % slower;
+# a chunk a trip (no unroll) leaves the rsqrt's latency exposed: 58 bundles
+# a chunk of 4 vregs in the compiled loop, against 41 unrolled.
+TILE_T = 128
+TILE_S = 4096
+STRIP_W = 512
+UNROLL = 8
 
 
 def _vma(*arrays):
@@ -70,184 +82,273 @@ def _pad_to(a, n, axis, value=0.0):
     return jnp.pad(a, widths, constant_values=value)
 
 
-def stokeslet_tile_sums(trg_T, src_T, f_T):
-    """Unscaled Stokeslet pair sums for one transposed-layout tile:
-    ``[3, nt]`` targets x ``[3, ns]`` sources/forces -> ``(ux, uy, uz)``
-    row sums. Pure jnp math on values already loaded from refs — the ONE
-    definition shared by the gridded VMEM tile below and the fused ring
-    kernel (`parallel.ring_fused`), so the two cannot drift."""
-    tx, ty, tz = trg_T[0, :], trg_T[1, :], trg_T[2, :]
-    sx, sy, sz = src_T[0, :], src_T[1, :], src_T[2, :]
-    fx, fy, fz = f_T[0, :], f_T[1, :], f_T[2, :]
+def _rinv(d):
+    """1/r of each pair of the displacement ``d``; zero for a coincident
+    pair (the select drops rsqrt(0) = inf, and a select does not carry the
+    value it drops)."""
+    r2 = d[0] * d[0] + d[1] * d[1] + d[2] * d[2]
+    return jnp.where(r2 > 0.0, lax.rsqrt(r2), 0.0)
 
-    dx = tx[:, None] - sx[None, :]
-    dy = ty[:, None] - sy[None, :]
-    dz = tz[:, None] - sz[None, :]
-    r2 = dx * dx + dy * dy + dz * dz
-    mask = r2 > 0.0
-    rinv = jnp.where(mask, lax.rsqrt(jnp.where(mask, r2, 1.0)), 0.0)
+
+def stokeslet_pair_terms(d, f):
+    """The unscaled Stokeslet terms of each pair, elementwise: ``d`` the
+    three components of target - source, ``f(k)`` force row k, broadcast
+    against them -> ``(ux, uy, uz)``. The ONE per-pair expression of the
+    gridded strip tile below and of the fused ring kernel's block sums
+    (`stokeslet_tile_sums`, `parallel.ring_fused`), so the two cannot
+    drift."""
+    rinv = _rinv(d)
     rinv3 = rinv * rinv * rinv
-
-    df = dx * fx[None, :] + dy * fy[None, :] + dz * fz[None, :]
-    common = df * rinv3
-
-    ux = jnp.sum(rinv * fx[None, :] + common * dx, axis=1)
-    uy = jnp.sum(rinv * fy[None, :] + common * dy, axis=1)
-    uz = jnp.sum(rinv * fz[None, :] + common * dz, axis=1)
-    return ux, uy, uz
+    common = (d[0] * f(0) + d[1] * f(1) + d[2] * f(2)) * rinv3
+    return tuple(rinv * f(k) + common * d[k] for k in range(3))
 
 
-def _stokeslet_kernel(trg_ref, src_ref, f_ref, out_ref):
-    """One [TILE_T, TILE_S] interaction tile; accumulates over grid axis 1."""
+def stresslet_pair_terms(d, s):
+    """The stresslet terms (d.S.d) d_k / r^5 of each pair, elementwise, as
+    `stokeslet_pair_terms`: ``s(m)`` is component m of the row-major 3 x 3
+    source (Sxx..Szz). d.S.d is contracted row by row,
+    (S_i0 dx + S_i1 dy + S_i2 dz) d_i summed over i. The kernel's -3 goes
+    on once a sum (`_KINDS`), as in the double-float tile."""
+    rinv = _rinv(d)
+    rinv2 = rinv * rinv
+    rinv5 = rinv2 * rinv2 * rinv
+    row = [(s(3 * i) * d[0] + s(3 * i + 1) * d[1] + s(3 * i + 2) * d[2])
+           * d[i] for i in range(3)]
+    common = (row[0] + row[1] + row[2]) * rinv5
+    return tuple(common * d[k] for k in range(3))
+
+
+class _Kind(NamedTuple):
+    terms: Callable     # the per-pair expression
+    scale: float        # what the pair sums take once, besides 1/(8 pi eta)
+    flops: int          # f32 operations a pair of the strip body (the
+                        # difference, the terms, the add into the lane's
+                        # sum), for the cost estimate
+
+
+_KINDS = {"stokeslet": _Kind(stokeslet_pair_terms, 1.0, 30),
+          "stresslet": _Kind(stresslet_pair_terms, -3.0, 40)}
+
+
+def _tile_sums(kind, trg_T, src_T, pay_T):
+    """Row sums of ``kind``'s terms over one whole transposed-layout
+    block."""
+    terms, scale, _ = _KINDS[kind]
+    d = [trg_T[k, :][:, None] - src_T[k, :][None, :] for k in range(3)]
+    u = terms(d, lambda m: pay_T[m, :][None, :])
+    return tuple(scale * jnp.sum(uk, axis=1) for uk in u)
+
+
+def stokeslet_tile_sums(trg_T, src_T, f_T):
+    """Unscaled Stokeslet pair sums for one transposed-layout block:
+    ``[3, nt]`` targets x ``[3, ns]`` sources/forces -> ``(ux, uy, uz)``
+    row sums; the fused ring kernel's (`parallel.ring_fused`)."""
+    return _tile_sums("stokeslet", trg_T, src_T, f_T)
+
+
+def stresslet_tile_sums(trg_T, src_T, s_T):
+    """Unscaled stresslet pair sums for one transposed-layout block:
+    ``[3, nt]`` targets x ``[3, ns]`` sources + ``[9, ns]`` row-major
+    stresslet components -> ``(ux, uy, uz)``, like `stokeslet_tile_sums`."""
+    return _tile_sums("stresslet", trg_T, src_T, s_T)
+
+
+def _strip_kernel(kind, unroll, trg_ref, src_ref, out_ref, tb_ref, acc_ref):
+    """One (tile_t, tile_s) block of a pair sum, walked in strips.
+
+    ``trg_ref`` [3, tile_t] holds the targets; ``src_ref`` [tile_s / 128,
+    rows, 128] the sources in 128-lane groups, position rows then payload
+    rows. ``tb_ref`` holds the targets broadcast along the lanes and
+    ``acc_ref`` the lane-wise partial sums, both [3, tile_t, w] and alive
+    across the source axis of the grid: the lane reduction runs once a
+    target tile, not once a block. A strip of 8 targets walks the block's
+    sources ``w`` lanes a chunk and ``unroll`` chunks a loop trip, so the
+    scheduler can overlap one chunk's dependency chain (the rsqrt's above
+    all) with the next one's.
+    """
+    terms = _KINDS[kind].terms
     j = pl.program_id(1)
+    tile_t, w = tb_ref.shape[1:]
+    groups = w // 128
+    trips = src_ref.shape[0] // (groups * unroll)
+    # loop bounds as int32 ARRAYS: Python and NumPy ints give the loop an i64
+    # counter under x64, which Mosaic refuses
+    i32 = jnp.int32
 
     @pl.when(j == 0)
     def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        for k in range(3):
+            tb_ref[k] = jnp.broadcast_to(trg_ref[k, :][:, None], (tile_t, w))
+        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    ux, uy, uz = stokeslet_tile_sums(trg_ref[:], src_ref[:], f_ref[:])
-    out_ref[0, :] += ux
-    out_ref[1, :] += uy
-    out_ref[2, :] += uz
+    def strip(r, _):
+        rows = pl.ds(pl.multiple_of(r * i32(8), 8), 8)
+        t = [tb_ref[k, rows, :] for k in range(3)]
+
+        def chunk(g, acc):
+            def row(m):
+                # row m of the chunk's sources on all 8 sublanes, a 128-lane
+                # group at a time; Mosaic lowers each broadcast to one
+                # sublane-stride-0 load. Compiled for a v5e, the inner loop
+                # is 333 bundles a strip of 8 chunks for the Stokeslet (330
+                # with that load written out, which the interpreter cannot
+                # run) and 410 for the stresslet (410 both ways)
+                return jnp.concatenate(
+                    [jnp.broadcast_to(src_ref[g + q, pl.ds(m, 1), :],
+                                      (8, 128)) for q in range(groups)],
+                    axis=1)
+
+            u = terms([t[k] - row(k) for k in range(3)],
+                      lambda m: row(3 + m))
+            return tuple(a + b for a, b in zip(acc, u))
+
+        def trip(c, acc):
+            for q in range(unroll):
+                acc = chunk((c * i32(unroll) + q) * i32(groups), acc)
+            return acc
+
+        acc = tuple(acc_ref[k, rows, :] for k in range(3))
+        acc = lax.fori_loop(i32(0), i32(trips), trip, acc)
+        for k in range(3):
+            acc_ref[k, rows, :] = acc[k]
+
+    lax.fori_loop(i32(0), i32(tile_t // 8), strip, None)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        for k in range(3):
+            out_ref[k, :] = jnp.sum(acc_ref[k], axis=1)
 
 
-@partial(jax.jit, static_argnames=("tile_t", "tile_s", "interpret"))
-def stokeslet_pallas(r_src, r_trg, f_src, eta, *, tile_t: int = DEFAULT_TILE_T,
-                     tile_s: int = DEFAULT_TILE_S, interpret: bool = False):
-    """Singular Stokeslet sum as a fused Pallas kernel.
+def _round_up(n, m):
+    return -(-n // m) * m
 
-    Same contract as `ops.kernels.stokeslet_direct`: [n_src, 3] sources,
-    [n_trg, 3] targets, [n_src, 3] forces -> [n_trg, 3] velocities.
-    """
+
+def _unroll(n_chunks):
+    """Chunks a loop trip: the largest divisor of the block's chunks up to
+    `UNROLL`."""
+    return max(u for u in range(1, UNROLL + 1) if n_chunks % u == 0)
+
+
+def _geometry(n_trg, n_src, tile_t, tile_s, strip_w):
+    """``(tile_t, tile_s, w, nt, ns)`` of one call. The strip is the next
+    128 * 2^k lanes over the sources, up to ``strip_w`` and the block (64
+    fiber nodes pad to 128, not to a whole strip). The sources split into
+    the fewest blocks of at most ``tile_s`` lanes, each the whole strips of
+    an equal share (6,431 shell nodes: two blocks of 3,584, not 8,192
+    lanes); the block is no taller than the padded targets."""
+    for name, v in (("tile_s", tile_s), ("strip_w", strip_w)):
+        if v < 128 or v % 128 or (v // 128) & (v // 128 - 1):
+            raise ValueError(f"{name} must be 128 * 2^k, got {v}")
+    if tile_t < 8 or tile_t % 8:
+        raise ValueError(f"tile_t must be a multiple of 8, got {tile_t}")
+    w = 128
+    while w < min(strip_w, tile_s, n_src):
+        w *= 2
+    blocks = -(-n_src // tile_s)
+    tile_s = _round_up(-(-n_src // blocks), w)
+    tile_t = min(tile_t, _round_up(n_trg, 8))
+    return tile_t, tile_s, w, _round_up(n_trg, tile_t), blocks * tile_s
+
+
+def _strip_call(kind, r_src, r_trg, payload, eta, tile_t, tile_s, strip_w,
+                interpret):
+    """The f32 tiles' one pallas_call: [n_src, 3] sources,
+    [n_trg, 3] targets, [n_src, ...] payload -> [n_trg, 3] velocities,
+    scaled by 1/(8 pi eta), in the targets' dtype."""
     n_trg, n_src = r_trg.shape[0], r_src.shape[0]
     if n_trg == 0 or n_src == 0:
         return jnp.zeros_like(r_trg)
     dtype = r_trg.dtype
-
-    nt = pl.cdiv(n_trg, tile_t) * tile_t
-    ns = pl.cdiv(n_src, tile_s) * tile_s
+    tile_t, tile_s, w, nt, ns = _geometry(n_trg, n_src, tile_t, tile_s,
+                                          strip_w)
+    grid = (nt // tile_t, ns // tile_s)
+    _announce(kind, n_trg, n_src, tile_t, tile_s, w, grid)
 
     trg_T = _pad_to(r_trg.T, nt, axis=1)
-    src_T = _pad_to(r_src.T, ns, axis=1, value=_PAD_SENTINEL)
-    f_T = _pad_to(f_src.T, ns, axis=1)
+    pay_T = payload.reshape(n_src, -1).T
+    # rows a multiple of the 8-sublane tiling (Mosaic refuses a 6- or
+    # 12-row source block): Stokeslet 3 + 3 -> 8, stresslet 3 + 9 -> 16
+    rows = _round_up(3 + pay_T.shape[0], 8)
+    src = jnp.concatenate([_pad_to(r_src.T.astype(dtype), ns, axis=1,
+                                   value=_PAD_SENTINEL),
+                           _pad_to(pay_T.astype(dtype), ns, axis=1)], axis=0)
+    # [rows, ns] -> [ns / 128, rows, 128]: a chunk indexes its 128-lane
+    # groups on the leading axis
+    src = _pad_to(src, rows, axis=0).reshape(rows, ns // 128, 128)
+    src = src.transpose(1, 0, 2)
 
-    grid = (nt // tile_t, ns // tile_s)
     # index-map zeros must be np.int32: under jax_enable_x64 a literal 0
     # traces as i64 while grid indices stay i32, and Mosaic rejects the
     # mixed-type index map (remote-compile HTTP 500 on this backend)
     z = np.int32(0)
     u_T = pl.pallas_call(
-        _stokeslet_kernel,
+        partial(_strip_kernel, kind, _unroll(tile_s // w)),
         # vma: inside shard_map (the ring evaluator's tile) the output varies
         # over whatever mesh axes the operands do; outside it's frozenset()
-        out_shape=_out_struct((3, nt), dtype, trg_T, src_T, f_T),
+        out_shape=_out_struct((3, nt), dtype, trg_T, src),
         grid=grid,
         in_specs=[
             pl.BlockSpec((3, tile_t), lambda i, j: (z, i),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, tile_s), lambda i, j: (z, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, tile_s), lambda i, j: (z, j),
+            pl.BlockSpec((tile_s // 128, rows, 128), lambda i, j: (j, z, z),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((3, tile_t), lambda i, j: (z, i),
                                memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((3, tile_t, w), dtype),
+                        pltpu.VMEM((3, tile_t, w), dtype)],
         cost_estimate=pl.CostEstimate(
-            flops=22 * nt * ns, bytes_accessed=4 * 3 * (nt + 2 * ns + nt),
+            flops=_KINDS[kind].flops * nt * ns,
+            bytes_accessed=dtype.itemsize * (6 * nt + rows * ns),
             transcendentals=nt * ns),
         interpret=interpret,
-    )(trg_T, src_T, f_T)
+    )(trg_T, src)
 
-    factor = 1.0 / (8.0 * math.pi)
+    factor = _KINDS[kind].scale / (8.0 * math.pi)
     return u_T.T[:n_trg] * (factor / eta)
 
 
-def stresslet_tile_sums(trg_T, src_T, s_T):
-    """Unscaled stresslet pair sums for one transposed-layout tile:
-    ``[3, nt]`` targets x ``[3, ns]`` sources + ``[9, ns]`` row-major
-    stresslet components -> ``(ux, uy, uz)``. Shared by the gridded tile
-    and the fused ring kernel like `stokeslet_tile_sums`."""
-    tx, ty, tz = trg_T[0, :], trg_T[1, :], trg_T[2, :]
-    sx, sy, sz = src_T[0, :], src_T[1, :], src_T[2, :]
+def _announce(kind, n_trg, n_src, tile_t, tile_s, w, grid):
+    """Trace-time, once for each shape and tile a process traces (a later
+    build of the same shapes hits the jit cache and says nothing): the
+    geometry the call takes, in the log and as an ``f32_tile`` event (`obs
+    summarize`, ``== pair tile (Krylov loop) ==``)."""
+    from ..obs import tracer as obs_tracer
 
-    dx = tx[:, None] - sx[None, :]
-    dy = ty[:, None] - sy[None, :]
-    dz = tz[:, None] - sz[None, :]
-    r2 = dx * dx + dy * dy + dz * dz
-    mask = r2 > 0.0
-    rinv = jnp.where(mask, lax.rsqrt(jnp.where(mask, r2, 1.0)), 0.0)
-    rinv2 = rinv * rinv
-    rinv5 = rinv2 * rinv2 * rinv
-
-    # d^T S d over the 9 components (S row-major: Sxx..Szz)
-    dSd = (dx * dx * s_T[0, :][None, :] + dx * dy * s_T[1, :][None, :]
-           + dx * dz * s_T[2, :][None, :] + dy * dx * s_T[3, :][None, :]
-           + dy * dy * s_T[4, :][None, :] + dy * dz * s_T[5, :][None, :]
-           + dz * dx * s_T[6, :][None, :] + dz * dy * s_T[7, :][None, :]
-           + dz * dz * s_T[8, :][None, :])
-    common = -3.0 * dSd * rinv5
-
-    return (jnp.sum(common * dx, axis=1), jnp.sum(common * dy, axis=1),
-            jnp.sum(common * dz, axis=1))
+    fields = dict(kind=kind, n_trg=n_trg, n_src=n_src,
+                  tile=f"{tile_t}x{tile_s}", strip_w=w,
+                  grid=f"{grid[0]}x{grid[1]}")
+    logger.info("f32_tile kind=%(kind)s n_trg=%(n_trg)s n_src=%(n_src)s "
+                "tile=%(tile)s strip_w=%(strip_w)s grid=%(grid)s", fields)
+    obs_tracer.emit("f32_tile", **fields)
 
 
-def _stresslet_kernel(trg_ref, src_ref, s_ref, out_ref):
-    """Stresslet tile: s_ref holds the 9 source components [9, TILE_S]."""
-    j = pl.program_id(1)
+@partial(jax.jit, static_argnames=("tile_t", "tile_s", "strip_w",
+                                   "interpret"))
+def stokeslet_pallas(r_src, r_trg, f_src, eta, *, tile_t: int = TILE_T,
+                     tile_s: int = TILE_S, strip_w: int = STRIP_W,
+                     interpret: bool = False):
+    """Singular Stokeslet sum as a fused Pallas kernel.
 
-    @pl.when(j == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    ux, uy, uz = stresslet_tile_sums(trg_ref[:], src_ref[:], s_ref[:])
-    out_ref[0, :] += ux
-    out_ref[1, :] += uy
-    out_ref[2, :] += uz
+    Same contract as `ops.kernels.stokeslet_direct`: [n_src, 3] sources,
+    [n_trg, 3] targets, [n_src, 3] forces -> [n_trg, 3] velocities.
+    """
+    return _strip_call("stokeslet", r_src, r_trg, f_src, eta, tile_t, tile_s,
+                       strip_w, interpret)
 
 
-@partial(jax.jit, static_argnames=("tile_t", "tile_s", "interpret"))
-def stresslet_pallas(r_dl, r_trg, f_dl, eta, *, tile_t: int = STRESSLET_TILE_T,
-                     tile_s: int = STRESSLET_TILE_S, interpret: bool = False):
+@partial(jax.jit, static_argnames=("tile_t", "tile_s", "strip_w",
+                                   "interpret"))
+def stresslet_pallas(r_dl, r_trg, f_dl, eta, *, tile_t: int = TILE_T,
+                     tile_s: int = TILE_S, strip_w: int = STRIP_W,
+                     interpret: bool = False):
     """Singular stresslet sum as a fused Pallas kernel.
 
     Same contract as `ops.kernels.stresslet_direct`: ``f_dl`` is [n_src, 3, 3].
     """
-    n_trg, n_src = r_trg.shape[0], r_dl.shape[0]
-    if n_trg == 0 or n_src == 0:
-        return jnp.zeros_like(r_trg)
-    dtype = r_trg.dtype
-
-    nt = pl.cdiv(n_trg, tile_t) * tile_t
-    ns = pl.cdiv(n_src, tile_s) * tile_s
-
-    trg_T = _pad_to(r_trg.T, nt, axis=1)
-    src_T = _pad_to(r_dl.T, ns, axis=1, value=_PAD_SENTINEL)
-    s_T = _pad_to(f_dl.reshape(n_src, 9).T, ns, axis=1)
-
-    grid = (nt // tile_t, ns // tile_s)
-    z = np.int32(0)  # see stokeslet_pallas: i64/i32 index-map mix breaks Mosaic
-    u_T = pl.pallas_call(
-        _stresslet_kernel,
-        out_shape=_out_struct((3, nt), dtype, trg_T, src_T, s_T),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((3, tile_t), lambda i, j: (z, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((3, tile_s), lambda i, j: (z, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((9, tile_s), lambda i, j: (z, j),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((3, tile_t), lambda i, j: (z, i),
-                               memory_space=pltpu.VMEM),
-        cost_estimate=pl.CostEstimate(
-            flops=40 * nt * ns, bytes_accessed=4 * (3 * nt + 12 * ns + 3 * nt),
-            transcendentals=nt * ns),
-        interpret=interpret,
-    )(trg_T, src_T, s_T)
-
-    factor = 1.0 / (8.0 * math.pi)
-    return u_T.T[:n_trg] * (factor / eta)
+    return _strip_call("stresslet", r_dl, r_trg, f_dl, eta, tile_t, tile_s,
+                       strip_w, interpret)
 
 
 # eta chosen so stokeslet_pallas's trailing (1/(8 pi))/eta scale is exactly
@@ -282,16 +383,12 @@ def auditable_kernels():
     from ..audit.dmaflow import pallas_calls
     from ..audit.registry import AuditKernel, BuiltKernel
 
-    specs = [
-        ("stokeslet_pallas_tiles", stokeslet_pallas,
-         DEFAULT_TILE_T, DEFAULT_TILE_S, (3,)),
-        ("stresslet_pallas_tiles", stresslet_pallas,
-         STRESSLET_TILE_T, STRESSLET_TILE_S, (3, 3)),
-    ]
+    specs = [("stokeslet_pallas_tiles", stokeslet_pallas, (3,)),
+             ("stresslet_pallas_tiles", stresslet_pallas, (3, 3))]
 
-    def build(fn, tile_t, tile_s, pay):
+    def build(fn, pay):
         def _build():
-            n_trg, n_src = 2 * tile_t, 2 * tile_s
+            n_trg, n_src = 2 * TILE_T, 2 * TILE_S
             closed = jax.make_jaxpr(
                 lambda r_s, r_t, f: fn(r_s, r_t, f, _UNIT_ETA))(
                     jnp.zeros((n_src, 3), jnp.float32),
@@ -306,7 +403,8 @@ def auditable_kernels():
     return [
         AuditKernel(name=name, layer="ops",
                     summary=(f"gridded {name.split('_')[0]} pair kernel: "
-                             f"{tile_t}x{tile_s} VMEM tiles"),
-                    build=build(fn, tile_t, tile_s, pay))
-        for name, fn, tile_t, tile_s, pay in specs
+                             f"{TILE_T}x{TILE_S} VMEM tiles in strips of "
+                             f"8x{STRIP_W}"),
+                    build=build(fn, pay))
+        for name, fn, pay in specs
     ]

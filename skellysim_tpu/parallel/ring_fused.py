@@ -16,8 +16,9 @@ Scope (build-time checked, `fused_ring_fits`):
 
 * f32 `impl="pallas"` tiles only — the kernel's pair math IS the Pallas
   tile math (`ops.pallas_kernels.stokeslet_tile_sums` /
-  `stresslet_tile_sums`, one shared definition), so a user probing the
-  exact/mxu tiles keeps the `ppermute` ring and its tile semantics;
+  `stresslet_tile_sums` sum the per-pair expression the gridded strip
+  tile walks, one shared definition), so a user probing the exact/mxu
+  tiles keeps the `ppermute` ring and its tile semantics;
 * whole-shard blocks resident in VMEM (`audit.dmaflow.VMEM_PAIR_BUDGET`,
   the shared build/verify-time accounting): this is a
   LATENCY optimization for the solve-scale regime where the ladder loses

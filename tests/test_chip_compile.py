@@ -72,6 +72,45 @@ def test_f32_pallas_tile_compiles(one_chip, kind, payload_tail):
     assert "tpu_custom_call" in text
 
 
+# every cell's f32 pair sum: the fiber cells' square sum and a ring block,
+# the ellipsoid's fibers onto all nodes and its shell onto the fibers (one
+# chip, a ring block of four), oocyte's both ways, the walkthrough's three
+@pytest.mark.parametrize("kind,n_src,n_trg", [
+    ("stokeslet", 16384, 16384), ("stokeslet", 16384, 24384),
+    ("stokeslet", 16384, 18384), ("stokeslet", 32768, 39199),
+    ("stokeslet", 64, 6464), ("stresslet", 8000, 16384),
+    ("stresslet", 2000, 16384), ("stresslet", 6431, 32768),
+    ("stresslet", 6000, 464), ("stresslet", 400, 6064)])
+def test_f32_pallas_tile_compiles_at_the_cells_shapes(one_chip, kind, n_src,
+                                                      n_trg):
+    """The f32 strip tile at the geometry each cell's shape gives it (the
+    strip and the block clipped to the shapes), under x64."""
+    from skellysim_tpu.ops import pallas_kernels
+
+    fn = getattr(pallas_kernels, f"{kind}_pallas")
+    tail = (3,) if kind == "stokeslet" else (3, 3)
+    r_src, r_trg, pay = _cloud(n_src, n_trg, tail, F32, one_chip)
+    text = _compile(lambda s, t, p: fn(s, t, p, 1.0), r_src, r_trg, pay)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,payload_tail", [("stokeslet", (3,)),
+                                               ("stresslet", (3, 3))])
+def test_f32_pallas_tile_compiles_under_vmap(one_chip, kind, payload_tail):
+    """Two members' sums in one call, as the ensemble's vmapped step makes
+    them: the grid grows a member axis and keeps its scratch."""
+    from skellysim_tpu.ops import pallas_kernels
+
+    fn = getattr(pallas_kernels, f"{kind}_pallas")
+
+    def st(shape):
+        return jax.ShapeDtypeStruct((2,) + shape, F32, sharding=one_chip)
+
+    text = _compile(jax.vmap(lambda s, t, p: fn(s, t, p, 1.0)),
+                    st((4096, 3)), st((4160, 3)), st((4096,) + payload_tail))
+    assert "tpu_custom_call" in text
+
+
 # the shapes the benchmark's cells run: the fiber cell's square sum, then
 # the walkthrough's 6,464 nodes against its shell, body and fiber sources
 @pytest.mark.parametrize("kind,payload_tail,n_src,n_trg", [

@@ -234,8 +234,15 @@ def test_footprint_formula_values():
     fp = dmaflow.fused_ring_footprint(3, 8, 8, 128)
     # each 6-row stokeslet slot is padded to one 8-sublane tile
     assert fp == {"pair_elems": 1024, "comm_floats": 8 * 8 * 128}
-    assert dmaflow.gridded_footprint(256, 1024) == {"pair_elems": 262144}
-    assert not dmaflow.gridded_within_budget(1024, 2048)
+    # the strip tile's blocks (targets, sources, outputs) twice, its two
+    # [3, tile_t, strip] scratches once
+    blocks = [(3, 128), (32, 8, 128), (3, 128)]
+    scratch = [(3, 128, 512)] * 2
+    assert dmaflow.gridded_footprint(blocks, scratch) == {
+        "vmem_floats": 2 * (384 + 32768 + 384) + 2 * 196608}
+    assert dmaflow.gridded_within_budget(blocks, scratch)
+    # a strip of 2,048 lanes over 512-row tiles holds 12 MiB of scratch
+    assert not dmaflow.gridded_within_budget(blocks, [(3, 512, 2048)] * 2)
 
 
 # ------------------------------------------------ contract / CLI discipline
