@@ -27,17 +27,31 @@ of every all-gathered shell density zeroed) and ``max_refine_1`` (the
 program's own float32 path: `chipbench/controls.py`'s ``--no-refine``).
 ``--variants f32_answer,unchanged,altered`` adds to the sound case the
 readings of `chipbench/controls.py` on the same window, and the sound case
-prints the norms |b|, |b_shell|, |b_bc| the reference's limits are set from.
+prints the norms |b|, |b_shell|, |b_bc| the reference's limits are set from
+(and |A_sf x|, the flow a shell with no wall force cancels).
+
+``--cell oocyte_mesh4.run`` runs the shipped oocyte on its own scene: a
+shell of 6,431 nodes the mesh step pads to 6,432. Its own case,
+``padded_row_live``: the padded node made live (masked in, node 0's normal
+and weight copied onto it), so that it acts as a second copy of node 0.
+``--seeds a,b,c`` runs the sound case once a seed (``--sound-seconds`` its
+window), the faults at ``--seed``.
 
     chiprun --chips 4 -- python scripts/mesh_exchange_control.py
     chiprun --chips 4 -- python scripts/mesh_exchange_control.py \
         --cell ellipsoid_mesh4.run \
         --cases sound,shell_rows_of_a_hop,gathered_density_quarter
+    # on a host of four chips, the shipped oocyte:
+    python scripts/mesh_exchange_control.py --cell oocyte_mesh4.run \
+        --cases sound,padded_row_live --seeds 11,12 --sound-seconds 45 \
+        --variants f32_answer
     # off the chip, at a size a CPU steps (not a device number):
     python scripts/mesh_exchange_control.py --cpu --n-fibers 64 --box 3.7
     python scripts/mesh_exchange_control.py --cpu --cell ellipsoid_mesh4.run \
         --n-fibers 8 --fiber-nodes 16 --shell-nodes 300 \
         --cases sound,shell_rows_of_a_hop,gathered_density_quarter
+    python scripts/mesh_exchange_control.py --cpu --cell oocyte_mesh4.run \
+        --n-fibers 16 --shell-nodes 300 --cases sound,padded_row_live
 
 Every line it prints is kept in ``chiprun_out/mesh_exchange_control.jsonl``;
 the exit code is 0 only where every case came out as it has to.
@@ -139,6 +153,29 @@ def _gathered_quarter_zeroed(lax):
     return Lax()
 
 
+def _padded_row_live(pad_shell_rows):
+    """`parallel.spmd._pad_shell_rows` whose padded nodes are made LIVE:
+    their mask set, and node 0's normal and weight copied onto them (they
+    sit at node 0's position already). Each is then a second copy of node 0
+    whose density solves against the fibers' flow there and whose double
+    layer reaches every fiber — in the right-hand side, the Krylov loop and
+    the program's own explicit residual alike (its rows are the program's
+    own), so only the reference, which has the shell's N nodes, can see
+    it."""
+    import jax.numpy as jnp
+
+    def pad(shell, axis, n_dev):
+        local = pad_shell_rows(shell, axis, n_dev)
+        dead = ~local.node_mask
+        return local._replace(
+            node_mask=jnp.ones_like(local.node_mask),
+            normals=jnp.where(dead[:, None], shell.normals[:1],
+                              local.normals),
+            weights=jnp.where(dead, shell.weights[0], local.weights))
+
+    return pad
+
+
 def plant(case: str, setattr_=setattr, *, fiber_rows: int = 0):
     """Plant ``case`` in the program as imported in this process; a test
     passes `monkeypatch.setattr`, which takes it out again. ``fiber_rows``:
@@ -154,39 +191,49 @@ def plant(case: str, setattr_=setattr, *, fiber_rows: int = 0):
                  _ring_with(_shell_rows_zeroed(fiber_rows)))
     elif case == "gathered_density_quarter":
         setattr_(spmd, "lax", _gathered_quarter_zeroed(lax))
+    elif case == "padded_row_live":
+        setattr_(spmd, "_pad_shell_rows",
+                 _padded_row_live(spmd._pad_shell_rows))
     else:
         raise ValueError(f"no such fault: {case!r}")
 
 
-FAULTS = ("dropped_hop", "shell_rows_of_a_hop", "gathered_density_quarter")
+FAULTS = ("dropped_hop", "shell_rows_of_a_hop", "gathered_density_quarter",
+          "padded_row_live")
 
 
 def record_norms(check, into: list):
     """`check.load_reference` whose reference notes, for every step it
-    builds, the norms of its right-hand side: whole, the shell's rows, the
-    fibers' 14 boundary rows (`clamped_shell_step` only: the limits of a
-    shell-and-fibers cell are the tolerance times their ratios)."""
+    judges, the norms of its right-hand side: whole, the shell's rows, the
+    fibers' 14 boundary rows, and for a shell with no wall force (a zero
+    right-hand side: `revolution_shell_step`) the flow its rows cancel,
+    |A_sf x| (the limits of a shell-and-fibers cell are the tolerance times
+    their ratios)."""
     import numpy as np
 
     load = check.load_reference
 
     def load_reference(name):
         ref = load(name)
-        step_cls = getattr(ref, "ClampedShellStep", None)
+        step_cls = (getattr(ref, "ClampedShellStep", None)
+                    or getattr(ref, "RevolutionShellStep", None))
         if step_cls is not None:
-            rhs = step_cls.rhs
+            residual = step_cls.residual
 
-            def noted(self):
-                b = rhs(self)
+            def noted(self, X, T, rho_shell):
+                b = self.rhs()
                 n_f = self.fib.F * 4 * self.fib.n
-                into.append({
-                    "b": float(np.linalg.norm(b)),
-                    "b_shell": float(np.linalg.norm(b[n_f:])),
-                    "b_bc": float(np.linalg.norm(
-                        b[:n_f].reshape(self.fib.F, -1)[:, -14:]))})
-                return b
+                row = {"b": float(np.linalg.norm(b)),
+                       "b_shell": float(np.linalg.norm(b[n_f:])),
+                       "b_bc": float(np.linalg.norm(
+                           b[:n_f].reshape(self.fib.F, -1)[:, -14:]))}
+                if hasattr(self, "fiber_flow_on_shell"):
+                    row["fiber_flow_on_shell"] = float(np.linalg.norm(
+                        self.fiber_flow_on_shell(X, T)))
+                into.append(row)
+                return residual(self, X, T, rho_shell)
 
-            step_cls.rhs = noted
+            step_cls.residual = noted
         return ref
 
     check.load_reference = load_reference
@@ -200,6 +247,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=8.0,
                     help="the window of each case (three steps are checked)")
     ap.add_argument("--seed", type=int, default=2147520301)
+    ap.add_argument("--seeds", default="",
+                    help="run the sound case once per seed of this comma "
+                         "list (the faults take --seed)")
+    ap.add_argument("--sound-seconds", type=float,
+                    help="the window of a sound case (default --seconds)")
     ap.add_argument("--curvature", type=float, default=2.0,
                     help="of the arcs a scene of straight free fibers is "
                          "bent to (a scene laid on a periphery stands as "
@@ -243,7 +295,9 @@ def main(argv=None) -> int:
             cfg["box"] = args.box
         if args.fiber_nodes:
             cfg["fibers"]["n_nodes"] = args.fiber_nodes
-        if args.shell_nodes:
+        if args.shell_nodes and "envelope" in cfg["periphery"]:
+            cfg["periphery"]["envelope"]["n_nodes_target"] = args.shell_nodes
+        elif args.shell_nodes:
             cfg["periphery"]["n_nodes"] = args.shell_nodes
         if args.cpu and cfg["params"].get("kernel_impl") == "pallas":
             cfg["params"]["kernel_impl"] = "exact"   # no Mosaic off the chip
@@ -270,10 +324,15 @@ def main(argv=None) -> int:
 
     ok = True
     cell = harness.Cell(args.cell, False)
-    for case in args.cases.split(","):
+    seeds = [int(s) for s in args.seeds.split(",") if s] or [args.seed]
+    runs = [(case, seed) for case in args.cases.split(",")
+            for seed in (seeds if case == "sound" else [args.seed])]
+    for case, seed in runs:
         restore = []
-        one = argparse.Namespace(workload=args.cell, seed=args.seed,
-                                 seconds=args.seconds, trace=0)
+        seconds = (args.sound_seconds or args.seconds if case == "sound"
+                   else args.seconds)
+        one = argparse.Namespace(workload=args.cell, seed=seed,
+                                 seconds=seconds, trace=0)
         if case == "max_refine_1":
             one.control = {"params": {"max_refine": 1}}
         elif case != "sound":
@@ -287,19 +346,29 @@ def main(argv=None) -> int:
         finally:
             for mod, attr, old in restore:
                 setattr(mod, attr, old)
+        t_chk = time.perf_counter()
         checks = check.check_window(run.cfg, run.traffic, run.rows,
-                                    run.snaps, run.frames, seed=args.seed,
+                                    run.snaps, run.frames, seed=seed,
                                     tol=run.tol, eta=run.eta,
                                     log=harness.log)
+        check_s = time.perf_counter() - t_chk
         res = harness.report(one, run, cell.device, cell.metric_entries,
                              cell.readers, checks)
         harness.print_checks(checks)
         seen = {"norms": list(norms)} if norms else {}
-        if case == "sound" and args.variants:
-            seen["readings"] = controls.readings(
-                run, args.seed, {k: controls.VARIANTS[k]
-                                 for k in args.variants.split(",")},
-                log=lambda *_: None)
+        if case == "sound" and args.variants and seed == seeds[0]:
+            # each variant judged on the held states alone, one check each
+            # (`controls.readings` without its repeat of the sound check)
+            seen["readings"] = {}
+            for name in args.variants.split(","):
+                got = check.check_window(
+                    run.cfg, run.traffic, run.rows, controls.map_answers(
+                        run.snaps, controls.VARIANTS[name]), {}, seed=seed,
+                    tol=run.tol, eta=run.eta, log=lambda *_: None,
+                    pre_snaps=run.snaps)
+                seen["readings"][name] = dict(
+                    {c["name"]: c["value"] for c in got},
+                    correct=all(c["ok"] for c in got))
         over = {k: c for k, c in res["checks"].items()
                 if k.startswith("ref_residual")
                 and not c["value"] <= c["limit"]}
@@ -310,13 +379,15 @@ def main(argv=None) -> int:
                 (not res["correct"] and bool(over)
                  and (res["failed"] == 0 or case == "max_refine_1")))
         ok &= bool(good)
-        emit(case=case, as_it_has_to_be=bool(good), correct=res["correct"],
+        emit(case=case, seed=seed, as_it_has_to_be=bool(good),
+             correct=res["correct"], check_s=round(check_s, 1),
              over_their_limits=sorted(over), checks=res["checks"],
              attempted=res["attempted"], failed=res["failed"],
              iters=res["run"]["iters"], metrics=res["metrics"],
              run={k: res["run"][k] for k in (
                  "compiles_in_window", "precompute", "precompute_seconds",
-                 "compile_seconds_total", "window_wall_s")},
+                 "compile_seconds_total", "window_wall_s", "steps")},
+             setup_s=run.setup_s,
              residual_true_max=max((r["residual_true"] for r in run.rows),
                                    default=None),
              fiber_error=[r["fiber_error"] for r in run.rows][:4],

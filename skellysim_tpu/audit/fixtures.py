@@ -20,6 +20,8 @@ BASE_PARAMS = dict(eta=1.0, dt_initial=1e-3, t_final=1e-2, gmres_tol=1e-10,
 
 #: shell node count for the coupled scene — divides 2/4/8 node-aligned
 SHELL_NODES = 56
+#: ... and for its padded twin: 54 nodes, which a mesh of four pads to 56
+PADDED_SHELL_NODES = 54
 BODY_NODES = 50
 
 
@@ -63,13 +65,14 @@ def free_state(system, seed=5):
                                        dtype=jnp.float64))
 
 
-def coupled_state(system, seed=7):
-    """16 fibers + the 56-node shell + one externally forced body."""
+def coupled_state(system, seed=7, shell_nodes=SHELL_NODES):
+    """16 fibers + the 56-node shell (or ``shell_nodes``) + one externally
+    forced body."""
     import jax.numpy as jnp
 
     from ..testing import make_coupled_parts
 
-    shell, _, bodies = make_coupled_parts(SHELL_NODES, BODY_NODES,
+    shell, _, bodies = make_coupled_parts(shell_nodes, BODY_NODES,
                                           jnp.float64)
     return system.make_state(fibers=make_fibers(seed=seed, box=2.0),
                              shell=shell, bodies=bodies)

@@ -184,28 +184,37 @@ def build_periphery(cfg_periphery, config_dir: str, dtype, precond_dtype=None,
 
     ``precond_dtype`` stores M_inv (the preconditioner) at a lower precision
     — the mixed solver only ever applies it in f32, so keeping an f64 copy
-    would waste (3N)^2 * 8 bytes of HBM. With a ``mesh`` that divides the
-    shell's nodes, every leaf goes from the host's arrays straight to the
-    shards the mesh step holds it in (the reference's Scatterv'd rows,
-    `periphery.cpp:408-442`): no device ever holds the whole operator, and
-    `System.run`'s placement finds each leaf where it belongs. A shell the
-    mesh does not divide loads whole, and the run loop refuses it in
-    words. ``words`` holds the operator as its double-float words, split
+    would waste (3N)^2 * 8 bytes of HBM. With a ``mesh``, the operators go
+    from the host's arrays straight to the shards the mesh step holds them
+    in (the reference's Scatterv'd rows, `periphery.cpp:408-442`): no
+    device ever holds the whole operator, and `System.run`'s placement
+    finds each leaf where it belongs. Where the mesh divides the shell's
+    nodes every leaf goes by rows; where it does not, the two operators are
+    padded on the host to whole nodes a chip (`parallel.mesh.padded_nodes`,
+    `periphery.pad_operator`) before their rows are cut, and the O(N)
+    vectors keep the N nodes the user configured (the run loop replicates
+    them). ``words`` holds the operator as its double-float words, split
     on the host, in place of the float64 array (`System.shell_product`)."""
     data = _load_npz(os.path.join(config_dir, cfg_periphery.precompute_file),
                      "periphery")
-    put = None
+    put = put_operators = None
+    operator, M_inv = data["stresslet_plus_complementary"], data["M_inv"]
     if mesh is not None:
-        from .parallel.mesh import rows_to_shards, shell_divides
+        from .parallel.mesh import padded_nodes, rows_to_shards
 
-        if shell_divides(len(data["nodes"]), mesh.size, "spmd"):
-            put = rows_to_shards(mesh)
+        put_operators = rows_to_shards(mesh)
+        n = len(data["nodes"])
+        rows = 3 * padded_nodes(n, mesh.size)
+        if rows == 3 * n:
+            put = put_operators
+        else:
+            operator = peri.pad_operator(operator, rows)
+            M_inv = peri.pad_operator(M_inv, rows)
     state = peri.make_state(data["nodes"], data["normals"],
-                            data["quadrature_weights"],
-                            data["stresslet_plus_complementary"],
-                            data["M_inv"], dtype=dtype,
-                            precond_dtype=precond_dtype, put=put,
-                            words=words)
+                            data["quadrature_weights"], operator, M_inv,
+                            dtype=dtype, precond_dtype=precond_dtype,
+                            put=put, words=words,
+                            put_operators=put_operators)
     shape_name = getattr(cfg_periphery, "shape", "sphere")
     if shape_name == "sphere":
         shape = peri.PeripheryShape(kind="sphere", radius=cfg_periphery.radius)

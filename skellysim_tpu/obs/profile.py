@@ -62,8 +62,9 @@ PHASE_SCOPES = frozenset({
     "prep", "gmres", "precond", "refine", "advance",
     # solver phases inside the Krylov loop (solver/gmres.py)
     "arnoldi", "gram", "givens",
-    # SPMD collective phases (parallel/spmd.py, parallel/ring.py)
-    "ring-step", "allgather-density", "psum-dots",
+    # SPMD collective phases (parallel/spmd.py, parallel/ring.py); the
+    # padding of a shell the mesh does not divide (parallel/spmd.py)
+    "ring-step", "allgather-density", "psum-dots", "shell_pad",
     # treecode traversal phases (ops/treecode.py)
     "upward", "near", "far",
     # spectral-Ewald pipeline phases (ops/spectral.py; "near" is shared

@@ -44,10 +44,11 @@ ANNOUNCEMENTS = {
                   ("apply", "dtype", "fibers", "fallback", "nodes", "block",
                    "blocks_per_step")),
     "periphery": ("periphery",
-                  ("shape", "nodes", "operator", "operator_dtype",
-                   "operator_bytes", "m_inv", "m_inv_dtype", "m_inv_bytes",
-                   "f64_product", "row_block", "chips", "rows_per_chip",
-                   "precompute", "load_s", "bounds")),
+                  ("shape", "nodes", "nodes_padded", "operator",
+                   "operator_dtype", "operator_bytes", "m_inv", "m_inv_dtype",
+                   "m_inv_bytes", "f64_product", "row_block", "chips",
+                   "rows_per_chip", "operator_bytes_per_chip", "precompute",
+                   "load_s", "bounds")),
 }
 
 

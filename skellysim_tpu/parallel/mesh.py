@@ -82,8 +82,10 @@ def shard_ensemble(ens, mesh: Mesh):
 #: rows, `periphery.cpp:408-442`, whose matvec becomes all-gather(density) +
 #: local row-block GEMV, `periphery.cpp:21-47`); every other shell leaf
 #: (nodes/normals/weights/density — all O(n_nodes)) replicates. The mesh
-#: step (`parallel.spmd`) divides EVERY shell leaf by rows: `shell_specs`
-#: is the one table both programs, `shard_state` and the loader read.
+#: step (`parallel.spmd`) divides EVERY shell leaf by rows where the mesh
+#: divides the nodes, and holds a shell it does not divide as GSPMD does,
+#: its operators padded (`padded_nodes`): `shell_specs` is the one table
+#: both programs, `shard_state` and the loader read.
 SHELL_ROW_SHARDED_FIELDS = ("stresslet_plus_complementary", "M_inv")
 
 #: the programs a placed state is for: GSPMD's `System.step`, and the mesh
@@ -91,19 +93,24 @@ SHELL_ROW_SHARDED_FIELDS = ("stresslet_plus_complementary", "M_inv")
 STEPS = ("gspmd", "spmd")
 
 
-def shell_specs(shell, step: str, *, sharded: bool = True):
+def shell_specs(shell, step: str, *, sharded: bool = True,
+                padded: bool = False):
     """Where each leaf of a `PeripheryState` lives on a mesh, as a
     `PeripheryState` of `PartitionSpec`s (None for an absent leaf): THE
     table. ``step="gspmd"``: the dense operators' rows over the fiber axis,
     the O(n_nodes) vectors replicated. ``step="spmd"``: every leaf by its
     leading axis — nodes/normals [N, 3], weights [N], density [3N], the
     operators' rows — node-aligned, so a chip holds N/D nodes and their
-    3N/D rows of everything. ``sharded=False``: the whole shell replicated
-    (the ``allow_replicated_shell`` opt-in of both programs)."""
+    3N/D rows of everything; ``padded=True`` (a shell the mesh does not
+    divide): the operators' rows, padded to 3N' (`padded_nodes`), and the
+    O(n_nodes) vectors replicated at their N nodes, which the mesh step pads
+    inside itself. ``sharded=False``: the whole shell replicated (the
+    ``allow_replicated_shell`` opt-in of both programs)."""
     if step not in STEPS:
         raise ValueError(f"step {step!r}: one of {STEPS}")
     by_rows = () if not sharded else (
-        shell._fields if step == "spmd" else SHELL_ROW_SHARDED_FIELDS)
+        shell._fields if step == "spmd" and not padded
+        else SHELL_ROW_SHARDED_FIELDS)
     return type(shell)(*[
         None if leaf is None else P(FIBER_AXIS) if name in by_rows else P()
         for name, leaf in zip(shell._fields, shell)])
@@ -114,6 +121,13 @@ def shell_divides(n_nodes: int, mesh_size: int, step: str) -> bool:
     GSPMD needs whole rows a device, the mesh step whole NODES (a node's
     three density components never straddle shards)."""
     return (n_nodes if step == "spmd" else 3 * n_nodes) % mesh_size == 0
+
+
+def padded_nodes(n_nodes: int, mesh_size: int) -> int:
+    """The mesh step's node count for a shell of ``n_nodes``: whole nodes a
+    device, ``ceil(n_nodes / mesh_size) * mesh_size`` (``n_nodes`` where the
+    mesh divides it)."""
+    return -(-n_nodes // mesh_size) * mesh_size
 
 
 def rows_to_shards(mesh: Mesh):
@@ -156,13 +170,17 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False,
     3*n_nodes (regression-pinned in tests/test_shell_sharding.py). Field
     names, not shapes, now decide.
 
-    pjit rejects uneven shardings, so the shell rows can only distribute when
-    the mesh size divides 3*n_nodes (n_nodes for the mesh step). Anything
-    else raises: silently
-    replicating an O(n_nodes^2) matrix per device turns the expected O(N/D)
-    footprint into D copies of the full operator, an OOM a user would only
-    find with a profiler. Pass ``allow_replicated_shell=True`` to opt in for
-    small shells.
+    pjit rejects uneven shardings, so under GSPMD the shell rows can only
+    distribute when the mesh size divides 3*n_nodes. Anything else raises:
+    silently replicating an O(n_nodes^2) matrix per device turns the
+    expected O(N/D) footprint into D copies of the full operator, an OOM a
+    user would only find with a profiler. The mesh step takes a shell whose
+    nodes the mesh does not divide PADDED: its two operators grown to
+    3N' x 3N' rows (`padded_nodes`; `periphery.pad_operator`, on the host,
+    each chip's rows cut from there) and divided by rows, its O(n_nodes)
+    vectors replicated at N nodes (`shell_specs(padded=True)`).
+    ``allow_replicated_shell=True`` replicates the whole shell instead, for
+    small shells, under either program.
     """
     fib_sharding = NamedSharding(mesh, P(FIBER_AXIS))
     rep_sharding = NamedSharding(mesh, P())
@@ -187,20 +205,24 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False,
     shell = state.shell
     if shell is not None:
         divides = shell_divides(shell.n_nodes, mesh.size, step)
-        if not divides and not allow_replicated_shell:
-            rows = (f"shell n_nodes ({shell.n_nodes})" if step == "spmd" else
-                    f"shell operator rows (3*n_nodes = {3 * shell.n_nodes})")
+        padded = not (divides or allow_replicated_shell) and step == "spmd"
+        if not (divides or allow_replicated_shell or padded):
             raise ValueError(
-                f"{rows} are not divisible "
-                f"by the mesh size ({mesh.size}), so the O(n_nodes^2) dense "
-                "operators cannot be row-sharded and would be fully replicated "
-                "on every device. Pick a shell n_nodes that is a multiple of "
-                f"{mesh.size}, or pass allow_replicated_shell=True to accept "
-                "the per-device memory cost.")
+                f"shell operator rows (3*n_nodes = {3 * shell.n_nodes}) are "
+                f"not divisible by the mesh size ({mesh.size}), so GSPMD "
+                "cannot row-shard the O(n_nodes^2) dense operators and would "
+                "replicate them on every device. Pick a shell n_nodes that "
+                f"is a multiple of {mesh.size}, step the mesh program "
+                "(step='spmd', which pads the rows), or pass "
+                "allow_replicated_shell=True to accept the per-device memory "
+                "cost.")
+        if padded:
+            shell = _pad_operators(shell, mesh)
         # place the O(n^2) operators straight to their final sharding (never
         # replicate them first — peak per-device memory would be the full
         # matrix); a leaf that is there already is handed back as it is
-        specs = shell_specs(shell, step, sharded=divides)
+        specs = shell_specs(shell, step, sharded=divides or padded,
+                            padded=padded)
         shell = type(shell)(*[
             leaf if leaf is None else jax.tree_util.tree_map(
                 lambda a, spec=spec: jax.device_put(
@@ -210,3 +232,25 @@ def shard_state(state, mesh: Mesh, *, allow_replicated_shell: bool = False,
     rest = jax.tree_util.tree_map(
         rep, state._replace(fibers=None, shell=None))
     return rest._replace(fibers=fibers, shell=shell)
+
+
+def _pad_operators(shell, mesh: Mesh):
+    """``shell`` with its two dense operators at the mesh step's padded rows
+    (`padded_nodes`): those that are there already as they are, the others
+    padded on the HOST and each chip's rows sent from there
+    (`rows_to_shards`)."""
+    from ..periphery.periphery import pad_operator
+
+    rows = 3 * padded_nodes(shell.n_nodes, mesh.size)
+    put = rows_to_shards(mesh)
+
+    def grown(op):
+        if jax.tree_util.tree_leaves(op)[0].shape[0] == rows:
+            return op
+        return jax.tree_util.tree_map(
+            lambda host, a: put(host, a.dtype), pad_operator(op, rows), op)
+
+    return shell._replace(
+        M_inv=grown(shell.M_inv),
+        stresslet_plus_complementary=grown(
+            shell.stresslet_plus_complementary))

@@ -18,7 +18,15 @@ Decomposition (everything per shard, mesh size D):
 * the shell row-shards node-aligned (N/D nodes per shard): the dense
   operators [3N/D, 3N], the density rows, and the RHS rows live distributed;
   applying the dense operator / its inverse is all-gather(density) + local
-  row-block GEMV — exactly the reference's `periphery.cpp:21-47` matvec;
+  row-block GEMV — exactly the reference's `periphery.cpp:21-47` matvec. A
+  shell whose N the mesh does not divide is padded to N' = ceil(N/D) D
+  nodes (`mesh.padded_nodes`): its operators come in padded [3N'/D, 3N']
+  (the padded rows the identity, the padded columns zero), its O(N)
+  vectors replicated at N, and the step pads them to this shard's N'/D
+  nodes on entry and gathers the new density back to 3N (scope
+  ``shell_pad``). A padded node is inert: masked (`node_mask`), zero
+  normal, weight and density, at node 0's position (never a zero-filled
+  point), so its density solves to exactly zero and no flow leaves it;
 * bodies and scalars replicate (the reference's rank-0 body ownership).
 
 Collectives are explicit and bounded (docs/parallel.md documents the full
@@ -67,7 +75,7 @@ from ..solver import gmres, gmres_ir
 from ..system.system import (SimState, StepInfo, _cast_floats, _rewrap_bodies,
                              _rewrap_fibers, body_buckets, fiber_buckets)
 from .compat import pmax
-from .mesh import FIBER_AXIS, shell_divides, shell_specs
+from .mesh import FIBER_AXIS, padded_nodes, shell_divides, shell_specs
 
 
 class SpmdSolution(NamedTuple):
@@ -84,12 +92,14 @@ class SpmdSolution(NamedTuple):
 def spmd_shell_mode(state: SimState, mesh: Mesh, *,
                     allow_replicated_shell: bool = False) -> str:
     """Validate a state for the SPMD step; returns the shell placement mode
-    ("sharded" | "replicated" | "none").
+    ("sharded" | "padded" | "replicated" | "none").
 
-    Stricter than `shard_state`: the shell must split NODE-aligned
-    (n_nodes % D == 0, not just 3*n_nodes % D == 0) so a node's three
-    density components never straddle shards, and every fiber bucket must
-    divide the mesh (`fibers.container.grow_capacity` pads a batch up).
+    The shell splits NODE-aligned so a node's three density components
+    never straddle shards: "sharded" where D divides n_nodes, else
+    "padded" (its operators at `padded_nodes` rows, as
+    `shard_state(step="spmd")` places them), or the whole shell
+    "replicated" where the caller opts in. Every fiber bucket must divide
+    the mesh (`fibers.container.grow_capacity` pads a batch up).
     """
     buckets = fiber_buckets(state.fibers)
     if not buckets:
@@ -105,17 +115,21 @@ def spmd_shell_mode(state: SimState, mesh: Mesh, *,
                 "are free)")
     if state.shell is None:
         return "none"
-    if shell_divides(state.shell.n_nodes, mesh.size, "spmd"):
+    n = state.shell.n_nodes
+    if shell_divides(n, mesh.size, "spmd"):
         return "sharded"
     if allow_replicated_shell:
         return "replicated"
-    raise ValueError(
-        f"shell n_nodes ({state.shell.n_nodes}) is not divisible by the "
-        f"mesh size ({mesh.size}), so the shell rows cannot be sharded "
-        "node-aligned and the O(n_nodes^2) dense operators would replicate "
-        "on every device. Pick a node count that is a multiple of "
-        f"{mesh.size}, or pass allow_replicated_shell=True to accept the "
-        "per-device memory cost.")
+    rows = 3 * padded_nodes(n, mesh.size)
+    if state.shell.M_inv.shape[0] != rows:
+        raise ValueError(
+            f"shell n_nodes ({n}) is not divisible by the mesh size "
+            f"({mesh.size}): the mesh step takes its operators padded to "
+            f"{rows} rows, and these have {state.shell.M_inv.shape[0]}. "
+            "Place the state with parallel.shard_state(state, mesh, "
+            "step='spmd'), which pads them, or pass "
+            "allow_replicated_shell=True to replicate the whole shell.")
+    return "padded"
 
 
 def _state_specs(state: SimState, shell_mode: str) -> SimState:
@@ -135,7 +149,8 @@ def _state_specs(state: SimState, shell_mode: str) -> SimState:
     # operators' ROWS), or the whole shell replicated
     shell_spec = (None if state.shell is None else
                   shell_specs(state.shell, "spmd",
-                              sharded=shell_mode == "sharded"))
+                              sharded=shell_mode in ("sharded", "padded"),
+                              padded=shell_mode == "padded"))
     return SimState(time=P(), dt=P(), fibers=fib_spec,
                     points=rep(state.points), background=rep(state.background),
                     shell=shell_spec, bodies=rep(state.bodies),
@@ -143,6 +158,37 @@ def _state_specs(state: SimState, shell_mode: str) -> SimState:
                     # writes the bitwise-identical row (psum'd/pmax'd
                     # reductions — obs.flight; repflow-verified)
                     flight=rep(state.flight))
+
+
+def _pad_shell_rows(shell, axis: str, n_dev: int):
+    """Inside the mesh step: this shard's block of a shell whose nodes the
+    mesh does not divide, its N replicated nodes padded to `padded_nodes`
+    and cut to N'/D. A padded node sits at node 0's position (a finite
+    target; never a zero-filled point) with a zero normal, weight and
+    density and ``node_mask`` False: it solves the identity against a zero
+    right-hand side and its double layer is zero."""
+    n = shell.n_nodes
+    per = padded_nodes(n, n_dev) // n_dev
+    pad = per * n_dev - n
+    live = (jnp.ones(n, dtype=bool) if shell.node_mask is None
+            else shell.node_mask)
+
+    def grown(a, fill):
+        return jnp.concatenate(
+            [a, jnp.broadcast_to(fill, (pad * a.shape[0] // n,)
+                                 + a.shape[1:]).astype(a.dtype)])
+
+    start = lax.axis_index(axis) * per
+
+    def take(a, k=1):
+        return lax.dynamic_slice_in_dim(a, start * k, per * k)
+
+    return shell._replace(
+        nodes=take(grown(shell.nodes, shell.nodes[:1])),
+        normals=take(grown(shell.normals, 0.0)),
+        weights=take(grown(shell.weights, 0.0)),
+        density=take(grown(shell.density, 0.0), 3),
+        node_mask=take(grown(live, False)))
 
 
 def _make_rdot(axis: str, nonrep_end: int) -> Callable:
@@ -211,7 +257,10 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
     n_dev = mesh.size
     shell_mode = spmd_shell_mode(
         state, mesh, allow_replicated_shell=allow_replicated_shell)
-    sharded_shell = shell_mode == "sharded"
+    # a padded shell runs the sharded shell's program on its padded rows:
+    # `local_step` pads it on entry and gathers the density back
+    padded_shell = shell_mode == "padded"
+    sharded_shell = shell_mode in ("sharded", "padded")
     has_shell = shell_mode != "none"
     has_bodies = state.bodies is not None
 
@@ -369,7 +418,7 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                 v_shell = v_loc[nf_l:]
             else:
                 v_shell = v_rep[:ns_rep]
-            shell_rhs = peri.update_RHS(v_shell)
+            shell_rhs = peri.update_RHS(v_shell, st.shell.node_mask)
 
         return st, caches, body_caches, shell_rhs, body_rhs
 
@@ -638,6 +687,10 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
     # ------------------------------------------------------------ local step
 
     def local_step(st, anchors=None):
+        whole = st.shell
+        if padded_shell:
+            with jax.named_scope("shell_pad"):
+                st = st._replace(shell=_pad_shell_rows(whole, axis, n_dev))
         # skelly-pulse phase scopes (obs/profile.py): metadata-only — the
         # audited mesh programs (collective inventory, replication
         # analysis, cost baselines) are byte-identical
@@ -655,7 +708,9 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             rhs = jnp.concatenate(rhs_parts)
         # a shard's own blocks: the shapes are one device's
         system._announce_block_precond(caches, body_caches)
-        system._announce_periphery(st, chips=n_dev if sharded_shell else 1)
+        system._announce_periphery(
+            st, chips=n_dev if sharded_shell else 1,
+            live_nodes=whole.n_nodes if padded_shell else None)
         system._announce_fiber_ops(st, precision)
         system._announce_pair_tile(st, precision)
 
@@ -708,8 +763,17 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             sol_shell = None
             if has_shell:
                 sol_shell = result.x[fib_size:fib_size + shell_size]
-                new_state = new_state._replace(shell=st.shell._replace(
-                    density=sol_shell))
+                if padded_shell:
+                    # the live density, replicated, as the state holds it
+                    with jax.named_scope("shell_pad"):
+                        sol_shell = lax.all_gather(
+                            sol_shell, axis,
+                            tiled=True)[:whole.solution_size]
+                    new_state = new_state._replace(shell=whole._replace(
+                        density=sol_shell))
+                else:
+                    new_state = new_state._replace(shell=st.shell._replace(
+                        density=sol_shell))
             sol_body = None
             if b_list:
                 off_b = fib_size + shell_size
@@ -757,12 +821,14 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
             from ..obs import flight as flight_mod
 
             new_state = new_state._replace(flight=flight_mod.record_step(
-                st, new_state, result.x,
+                st._replace(shell=whole) if padded_shell else st,
+                new_state, result.x,
                 residual_true=result.residual_true, health=health,
                 dt_used=st.dt, shell_shape=system.shell_shape,
                 solution_norm=jnp.sqrt(rdot(result.x, result.x)),
                 axis_name=axis, axis_size=n_dev,
-                sol_scan_rows=nonrep_end, shell_sharded=sharded_shell))
+                sol_scan_rows=nonrep_end,
+                shell_sharded=sharded_shell and not padded_shell))
         info = StepInfo(
             converged=result.converged, iters=result.iters,
             residual=result.residual, fiber_error=fiber_error,
@@ -783,7 +849,8 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
     state_specs = _state_specs(state, shell_mode)
     sol_specs = (
         tuple(P(FIBER_AXIS) for _ in fiber_buckets(state.fibers)),
-        (P(FIBER_AXIS) if sharded_shell else P()) if has_shell else None,
+        (P(FIBER_AXIS) if sharded_shell and not padded_shell else P())
+        if has_shell else None,
         P() if has_bodies else None,
     )
     info_specs = jax.tree_util.tree_map(
@@ -865,7 +932,7 @@ def auditable_programs():
     from . import shard_state
     from .mesh import make_mesh
 
-    def build(n_dev):
+    def build(n_dev, shell_nodes=fixtures.SHELL_NODES):
         def _build():
             mesh = make_mesh(n_dev)
             # gmres_block_s=4: the audited ladder configuration IS the
@@ -875,7 +942,11 @@ def auditable_programs():
             # cycle's 3 per iteration), so a regression back to
             # per-iteration psums fails the collective inventory
             system = fixtures.make_system(shell=True, gmres_block_s=4)
-            state = shard_state(fixtures.coupled_state(system), mesh)
+            state = fixtures.coupled_state(system, shell_nodes=shell_nodes)
+            # the dividing ladder is lowered from the placement it was
+            # pinned on; a padded shell only from the mesh step's own
+            state = shard_state(state, mesh, step="spmd" if shell_nodes
+                                != fixtures.SHELL_NODES else "gspmd")
             fn = build_spmd_step(system, mesh, state, flat_solution=False,
                                  donate=True)
             return built_from(fn, state)
@@ -901,4 +972,10 @@ def auditable_programs():
                     "mesh (row-sharded shell, donated state)",
             build=build(n_dev),
             retrace_probe=retrace_probe if n_dev == 2 else None))
+    progs.append(AuditProgram(
+        name="step_spmd_padded_d4", layer="parallel",
+        summary="explicitly-sharded coupled step on the 4-device mesh, its "
+                f"{fixtures.PADDED_SHELL_NODES}-node shell padded to whole "
+                "nodes a device (donated state)",
+        build=build(4, fixtures.PADDED_SHELL_NODES)))
     return progs

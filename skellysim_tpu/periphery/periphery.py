@@ -199,34 +199,65 @@ def split_operator(op) -> OperatorWords:
 
 
 def make_state(nodes, normals, weights, operator, M_inv, dtype=jnp.float64,
-               precond_dtype=None, put=None, words: bool = False
-               ) -> PeripheryState:
+               precond_dtype=None, put=None, words: bool = False,
+               put_operators=None) -> PeripheryState:
     """``precond_dtype`` stores M_inv (the preconditioner — accuracy does not
     matter) in a lower precision, halving its HBM footprint in mixed mode.
     ``put(host_array, dtype)`` hands each leaf to the device(s) (default:
     the whole of it to the default device; a mesh run's builder divides
-    every leaf by rows, `parallel.mesh.rows_to_shards`). ``words`` holds
-    the operator as its `OperatorWords`, split on the host before they go
-    up, in place of the float64 array: the one place they are formed, where
-    `System.shell_product` says ``df_tile`` (`builder.build_simulation`
-    asks it)."""
+    every leaf by rows, `parallel.mesh.rows_to_shards`);
+    ``put_operators`` (default ``put``) hands the two dense operators,
+    which may come padded to more rows than the shell has nodes
+    (`pad_operator`: a mesh that does not divide the nodes). ``words``
+    holds the operator as its `OperatorWords`, split on the host before
+    they go up, in place of the float64 array: the one place they are
+    formed, where `System.shell_product` says ``df_tile``
+    (`builder.build_simulation` asks it)."""
     if put is None:
         def put(a, dt):
             return jnp.asarray(a, dtype=dt)
+    put_op = put if put_operators is None else put_operators
     N = len(nodes)
     if words:
-        op = OperatorWords(*(put(w, jnp.float32)
+        op = OperatorWords(*(put_op(w, jnp.float32)
                              for w in split_operator(np.asarray(operator))))
     else:
-        op = put(operator, dtype)
+        op = put_op(operator, dtype)
     return PeripheryState(
         nodes=put(nodes, dtype),
         normals=put(normals, dtype),
         weights=put(weights, dtype),
-        M_inv=put(M_inv, precond_dtype or dtype),
+        M_inv=put_op(M_inv, precond_dtype or dtype),
         stresslet_plus_complementary=op,
         density=put(np.zeros(3 * N), dtype),
     )
+
+
+def pad_operator(op, rows: int):
+    """A dense operator (host or device; its `OperatorWords` too) grown on
+    the HOST to ``rows`` x ``rows`` block-diagonally with the identity: the
+    padded rows solve the identity and the padded columns couple to
+    nothing, so the padded system's inverse is the padded inverse. The
+    identity's words are (1, 0). ``rows`` equal to the operator's own
+    returns it as a host array."""
+    if isinstance(op, OperatorWords):
+        return OperatorWords(pad_operator(op.hi, rows),
+                             _pad_square(op.lo, rows, 0.0))
+    return _pad_square(op, rows, 1.0)
+
+
+def _pad_square(m, rows: int, diagonal: float):
+    m = np.asarray(m)
+    n = m.shape[0]
+    if rows < n:
+        raise ValueError(f"periphery.pad_operator: {rows} rows below the "
+                         f"operator's {n}")
+    if rows == n:
+        return m
+    out = np.zeros((rows, rows), dtype=m.dtype)
+    out[:n, :n] = m
+    out[np.arange(n, rows), np.arange(n, rows)] = diagonal
+    return out
 
 
 def holds_words(shell: PeripheryState) -> bool:
@@ -263,17 +294,9 @@ def grow_capacity(shell: PeripheryState, new_n: int) -> PeripheryState:
         fill = np.repeat(a[:1], pad, axis=0)
         return np.concatenate([a, fill], axis=0)
 
-    def pad_op(m, eye=True):
-        m = np.asarray(m)
-        out = (np.eye(3 * new_n, dtype=m.dtype) if eye
-               else np.zeros((3 * new_n,) * 2, dtype=m.dtype))
-        out[:3 * n, :3 * n] = m
-        return jnp.asarray(out)
-
-    op = shell.stresslet_plus_complementary
-    # the identity's words are (1, 0)
-    op = (OperatorWords(pad_op(op.hi), pad_op(op.lo, eye=False))
-          if holds_words(shell) else pad_op(op))
+    op = pad_operator(shell.stresslet_plus_complementary, 3 * new_n)
+    op = (OperatorWords(*map(jnp.asarray, op)) if holds_words(shell)
+          else jnp.asarray(op))
     dtype = shell.nodes.dtype
     normals = np.concatenate(
         [np.asarray(shell.normals), np.zeros((pad, 3))], axis=0)
@@ -282,7 +305,7 @@ def grow_capacity(shell: PeripheryState, new_n: int) -> PeripheryState:
         normals=jnp.asarray(normals, dtype=dtype),
         weights=jnp.asarray(np.concatenate(
             [np.asarray(shell.weights), np.zeros(pad)]), dtype=dtype),
-        M_inv=pad_op(shell.M_inv),
+        M_inv=jnp.asarray(pad_operator(shell.M_inv, 3 * new_n)),
         stresslet_plus_complementary=op,
         density=jnp.asarray(np.concatenate(
             [np.asarray(shell.density), np.zeros(3 * pad)]), dtype=dtype),
@@ -313,16 +336,23 @@ def _row_blocked(op) -> bool:
     return op.dtype == jnp.float64 and op.shape[0] > 2 * _F64_ROW_BLOCK
 
 
-def describe(shell: PeripheryState, chips: int = 1) -> dict:
+def describe(shell: PeripheryState, chips: int = 1,
+             live_nodes: int | None = None) -> dict:
     """What a run holds of a shell and how `_apply_operator` multiplies its
     float64 operator, for `System._announce_periphery` (shapes and dtypes
     only: a traced state serves). ``shell`` is what ONE chip holds: the
     whole, or inside the mesh step its share of a shell divided by rows over
     ``chips`` devices — the shell's own numbers are then the share's times
     ``chips``, and the product's policy is the share's (it is the share's
-    rows that `_apply_operator` sees there). `OperatorWords` read as dtype
-    ``float32x2`` and the bytes of both words, their product ``df_tile``."""
-    out = {"nodes": shell.n_nodes * chips}
+    rows that `_apply_operator` sees there). ``live_nodes``: the nodes the
+    shell has where the mesh step padded it to whole nodes a chip
+    (``nodes_padded`` is then the share's times ``chips``). `OperatorWords`
+    read as dtype ``float32x2`` and the bytes of both words, their product
+    ``df_tile``; ``operator_bytes_per_chip`` is a chip's rows of both dense
+    operators together."""
+    held_nodes = shell.n_nodes * chips
+    out = {"nodes": held_nodes if live_nodes is None else live_nodes,
+           "nodes_padded": held_nodes, "operator_bytes_per_chip": 0}
     words = holds_words(shell)
     op = shell.stresslet_plus_complementary
     held = op.hi if words else op
@@ -332,6 +362,7 @@ def describe(shell: PeripheryState, chips: int = 1) -> dict:
         out.update({name: f"{m.shape[0] * chips}x{m.shape[1]}",
                     name + "_dtype": dtype.name + ("x2" if n == 2 else ""),
                     name + "_bytes": m.size * dtype.itemsize * chips * n})
+        out["operator_bytes_per_chip"] += m.size * dtype.itemsize * n
     product = ("df_tile" if words
                else "row_blocks" if _row_blocked(op) else "whole")
     return dict(out, f64_product=product,

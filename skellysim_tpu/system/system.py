@@ -450,7 +450,8 @@ class System:
     #: says (a shell handed to `make_state` by anyone else has no file)
     shell_precompute = None
 
-    def _announce_periphery(self, state, chips: int = 1):
+    def _announce_periphery(self, state, chips: int = 1,
+                            live_nodes: int | None = None):
         """Trace-time (once per build, like `_announce_block_precond`): what
         this solve holds of a shell — shape, nodes, the stored operator and
         `M_inv` (shape, dtype, bytes), over how many chips their rows are
@@ -463,14 +464,15 @@ class System:
         `shell_product` does not resolve to ``df_tile``, or the float64
         array where it does, says so as a ``fault``. The mesh step
         calls it inside its `shard_map`, where ``state`` is one chip's share
-        of ``chips``. Silent without a shell."""
+        of ``chips``, and ``live_nodes`` the nodes of a shell it padded to
+        whole nodes a chip (``nodes_padded``). Silent without a shell."""
         if state.shell is None:
             return
         src = self.shell_precompute or {}
         bounds = self.shell_shape.bounds if self.shell_shape else ()
         fields = dict(
             shape=self.shell_shape.kind if self.shell_shape else "generic",
-            **peri.describe(state.shell, chips),
+            **peri.describe(state.shell, chips, live_nodes),
             precompute=src.get("file", "-"),
             load_s=round(src.get("load_s", 0.0), 3),
             bounds=":".join(f"{b:g}" for b in bounds) or "-")
@@ -480,6 +482,8 @@ class System:
             "m_inv=%(m_inv)s %(m_inv_dtype)s %(m_inv_bytes)dB "
             "f64_product=%(f64_product)s row_block=%(row_block)d "
             "chips=%(chips)d rows_per_chip=%(rows_per_chip)d "
+            "nodes_padded=%(nodes_padded)d "
+            "operator_bytes_per_chip=%(operator_bytes_per_chip)d "
             "precompute=%(precompute)s load_s=%(load_s).3f "
             "bounds=%(bounds)s", fields)
         obs_tracer.emit("periphery", **fields)
@@ -1797,7 +1801,8 @@ class System:
             # placed, the builder's shell and a `run(max_steps=1)` re-entry
             # leaves that are (placing a placed leaf is a no-op)
             step_fn, clock = self._mesh_step(rng, donate_ok)
-            with span("place_state", devices=self.mesh.size):
+            with span("place_state", devices=self.mesh.size,
+                      **self._shell_layout(state.shell)):
                 from ..parallel import shard_state
 
                 state = shard_state(state, self.mesh, step="spmd")
@@ -1980,16 +1985,40 @@ class System:
             # `fused_ring_fallback` faults: `parallel.ring._ring_or_fused`)
             from ..parallel import FIBER_AXIS
 
-            # ... and a shell's leaves are divided by rows, as `place_state`
-            # left them (`shard_state` refuses a shell it cannot divide)
-            shell = state.shell
+            # ... and a shell's operators are divided by rows, as
+            # `place_state` left them (padded where the mesh does not divide
+            # the nodes)
+            layout = self._shell_layout(state.shell)
             obs_tracer.emit("mesh", devices=self.mesh.size, axis=FIBER_AXIS,
                             step="spmd",
-                            shell="none" if shell is None else "rows",
-                            shell_rows_per_chip=(
-                                0 if shell is None
-                                else shell.solution_size // self.mesh.size))
+                            shell=layout.get("shell_layout", "none"),
+                            shell_rows_per_chip=layout.get(
+                                "shell_rows_per_chip", 0))
         return state
+
+    def _shell_layout(self, shell) -> dict:
+        """How the mesh step holds ``shell`` on ``self.mesh`` (shapes only):
+        ``shell_layout`` ``rows`` (every leaf divided by rows) or
+        ``padded`` (the nodes padded to whole nodes a chip,
+        `parallel.mesh.padded_nodes`: the operators' rows divided, the
+        O(N) vectors replicated), the live and the padded node counts, the
+        operators' rows a chip and their bytes a chip. Empty without a
+        shell."""
+        if shell is None:
+            return {}
+        from ..parallel.mesh import padded_nodes
+
+        n_dev = self.mesh.size
+        n_pad = padded_nodes(shell.n_nodes, n_dev)
+        rows = 3 * n_pad // n_dev
+        per_chip = sum(rows * 3 * n_pad * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(
+                           (shell.M_inv, shell.stresslet_plus_complementary)))
+        return {"shell_layout": "rows" if n_pad == shell.n_nodes
+                else "padded",
+                "shell_nodes": shell.n_nodes, "shell_nodes_padded": n_pad,
+                "shell_rows_per_chip": rows,
+                "operator_bytes_per_chip": per_chip}
 
 
 # ---------------------------------------------------------------- skelly-audit

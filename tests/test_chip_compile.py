@@ -165,10 +165,13 @@ def test_pallas_df_ring_compiles_on_four_chips(topo, monkeypatch):
 
 # a chip's share of `ellipsoid_mesh4.run`: the fibers' Stokeslet from 16,384
 # sources a ring block onto its 16,384 fiber + 2,000 shell rows, the shell's
-# double layer from 2,000 sources a block onto its 16,384 fiber nodes; then
-# the one-chip sums of `ellipsoid_256.run` and of the walkthrough
+# double layer from 2,000 sources a block onto its 16,384 fiber nodes; and of
+# `oocyte_mesh4.run`: 24,000 fiber sources onto 24,000 + 1,608 padded shell
+# rows, 1,608 shell sources onto 24,000 fiber nodes; then the one-chip sums
+# of `ellipsoid_256.run` and of the walkthrough
 @pytest.mark.parametrize("kind,payload_tail,src_rows,trg_rows", [
-    ("stokeslet", (3,), 16384, 18384), ("stresslet", (3, 3), 2000, 16384)])
+    ("stokeslet", (3,), 16384, 18384), ("stresslet", (3, 3), 2000, 16384),
+    ("stokeslet", (3,), 24000, 25608), ("stresslet", (3, 3), 1608, 24000)])
 def test_auto_ring_takes_the_pallas_tile_on_four_chips(
         topo, monkeypatch, kind, payload_tail, src_rows, trg_rows):
     """`ring_flow_local(impl="auto")`, as the mesh step calls it inside its
@@ -260,11 +263,12 @@ def test_f64_direct_tile_compiles(one_chip):
 
 
 @pytest.mark.parametrize("blocks,width", [(256, 256), (1, 1206),
-                                          (1024, 128)])
+                                          (1024, 128), (750, 128)])
 def test_block_inverse_compiles(one_chip, blocks, width):
     """The mixed tier's block preconditioner (`ops.block_precond`) at the
     benchmark's widths — 256 fiber blocks of 256^2, the walkthrough body's
-    1,206^2, `oocyte_1024.run`'s 1,024 blocks of 128^2: the triangular solves (XLA's `InvertDiagBlocks*` custom calls
+    1,206^2, `oocyte_1024.run`'s 1,024 blocks of 128^2 and a chip's 750 of
+    them in `oocyte_mesh4.run`: the triangular solves (XLA's `InvertDiagBlocks*` custom calls
     on a TPU) stay where the inverse is formed, once a step; an application
     holds none."""
     from collections import namedtuple
@@ -290,7 +294,8 @@ def test_block_inverse_compiles(one_chip, blocks, width):
     (256, 248, 256, True, False), (256, 64, 128, True, False),
     (1, 256, 256, False, False), (16, 256, 256, False, True),
     (1024, 128, 128, False, False), (1024, 96, 128, False, False),
-    (1024, 120, 128, True, False), (1024, 32, 128, True, False)])
+    (1024, 120, 128, True, False), (1024, 32, 128, True, False),
+    (750, 128, 128, False, False)])
 def test_block_df_tile_compiles(one_chip, blocks, rows, cols, shared, vmapped):
     """The double-float block matvec (`ops.block_df`) at the fiber cells'
     shapes — 256 blocks of `A_bc` 256^2 and of the force operator 192 x 256,
@@ -299,8 +304,9 @@ def test_block_df_tile_compiles(one_chip, blocks, rows, cols, shared, vmapped):
     members as the ensemble calls it, and the 32-node bucket of
     `oocyte_1024.run` (1,024 blocks of 128^2, 32 a grid step; the force
     operator 96 x 128, `P_down` 114 rows split to 120, `D1` 32 x 32 split
-    to 32 x 128): Mosaic takes the strips, the 128 x 128 transposes and the
-    sublane rolls."""
+    to 32 x 128), and a chip's 750 of those blocks in `oocyte_mesh4.run`
+    (30 a grid step): Mosaic takes the strips, the 128 x 128 transposes and
+    the sublane rolls."""
     from skellysim_tpu.ops import block_df
 
     def st(shape, dtype):
@@ -369,12 +375,13 @@ def test_gmres_live_row_walk_compiles(topo, n_dev):
 
 
 @pytest.mark.parametrize("rows,cols", [(24000, 24000), (6000, 24000),
-                                       (19293, 19293)])
+                                       (19293, 19293), (4824, 19296)])
 def test_streamed_shell_tile_compiles(one_chip, rows, cols):
     """The streamed double-float tile (`ops.block_df.matvec_df_streamed`)
     at `ellipsoid_256.run`'s whole 24,000^2 shell operator, at a chip's
-    6,000 rows of it in `ellipsoid_mesh4.run`, and at `oocyte_1024.run`'s
-    19,293^2 (odd, no multiple of 8): ragged blocks both ways."""
+    6,000 rows of it in `ellipsoid_mesh4.run`, at `oocyte_1024.run`'s
+    19,293^2 (odd, no multiple of 8): ragged blocks both ways, and at a
+    chip's 4,824 of the 19,296 padded rows in `oocyte_mesh4.run`."""
     from skellysim_tpu.ops import block_df
 
     def st(shape, dtype):

@@ -307,3 +307,32 @@ def test_oocyte_cut_lays_the_first_fibers_of_the_shipped_scene():
     assert len(shipped.fibers) == 3000
     np.testing.assert_array_equal([f.x for f in cut.fibers],
                                   [f.x for f in shipped.fibers[:1024]])
+
+
+# ------------------------------------------------------------ `oocyte_mesh4`
+
+def test_oocyte_mesh4_is_the_shipped_example_on_a_mesh_of_four(tmp_path):
+    """`examples/oocyte/gen_config.py`'s `build_config(3000, 4)` — the
+    shipped scene whole, with the chip count — with what a configuration
+    here adds to every scene; every key as `oocyte_1024`'s but the fiber
+    count and ``params.mesh_devices``, and the same periphery fields and
+    ``eta``, so the same precompute (a hit after that cell's run)."""
+    import scene
+
+    cfg = _scene_tests._configuration("oocyte_mesh4")
+    twin = _scene_tests._configuration("oocyte_1024")
+    assert cfg["reduced"] == [] and cfg["published"] == {"n_fibers": 3000}
+    assert cfg["params"] == dict(twin["params"], mesh_devices=4)
+    assert cfg["fibers"] == dict(twin["fibers"], n_fibers=3000)
+    assert cfg["periphery"] == twin["periphery"] and cfg["bodies"] == []
+    assert cfg["reference"] == "revolution_shell_step_mesh"
+    assert set(cfg["limits"]) == set(twin["limits"])
+    assert cfg["guarantees"]["gmres_tol"] == cfg["limits"]["ref_residual"]
+    assert scene.precompute_key(cfg) == OOCYTE_1024_PRECOMPUTE_KEY
+    theirs = _oocyte_example().build_config(3000, 4)
+    theirs.params.adaptive_timestep_flag = False
+    theirs.params.seed = 11
+    theirs.params.t_final = cfg["params"]["t_final"]
+    assert (_scene_tests._saved(scene.build_config(cfg, 11),
+                                tmp_path / "ours.toml")
+            == _scene_tests._saved(theirs, tmp_path / "theirs.toml"))

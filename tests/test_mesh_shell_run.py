@@ -280,7 +280,8 @@ def test_no_operator_leaf_is_whole_on_any_one_device(cache, tmp_path):
 
 
 def test_a_shell_the_mesh_does_not_divide_is_refused_in_words(cache):
-    # 300 nodes over 8 devices: whole rows a device (900 / 8 no, either)
+    # 300 nodes over 8 devices: GSPMD's whole rows a device (900 / 8 no);
+    # the mesh step pads such a shell (tests/test_spmd_padded_shell.py)
     state = jax.eval_shape(lambda: peri.make_state(
         np.zeros((N_SHELL, 3)), np.zeros((N_SHELL, 3)), np.zeros(N_SHELL),
         np.zeros((ROWS, ROWS)), np.zeros((ROWS, ROWS))))
@@ -288,9 +289,9 @@ def test_a_shell_the_mesh_does_not_divide_is_refused_in_words(cache):
 
     sim = SimState(time=jnp.zeros(()), dt=jnp.zeros(()), fibers=None,
                    points=None, background=None, shell=state, bodies=None)
-    with pytest.raises(ValueError, match=r"shell n_nodes \(300\).*multiple "
-                                         r"of 8"):
-        shard_state(sim, make_mesh(8), step="spmd")
+    with pytest.raises(ValueError, match=r"3\*n_nodes = 900\).*multiple "
+                                         r"of 8.*step='spmd'"):
+        shard_state(sim, make_mesh(8), step="gspmd")
     with pytest.raises(ValueError, match="step 'pjit'"):
         shell_specs(state, "pjit")
 

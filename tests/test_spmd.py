@@ -137,13 +137,14 @@ def test_spmd_mixed_refinement_inside_mesh(coupled_parts):
 
 @pytest.mark.slow
 def test_spmd_replicated_shell_fallback():
-    """A shell that cannot split node-aligned raises; the explicit
-    replicated opt-in still matches the single program."""
+    """A shell that cannot split node-aligned is padded by the mesh step
+    (an unpadded one is refused in words); the explicit replicated opt-in
+    still matches the single program."""
     parts = make_coupled_parts(100, 50, jnp.float64)  # 100 % 8 != 0
     mesh = make_mesh(N_DEV)
     sys_sp = System(Params(**PARAMS), shell_shape=SHAPE)
     state = _coupled_state(sys_sp, parts)
-    with pytest.raises(ValueError, match="multiple of 8"):
+    with pytest.raises(ValueError, match="padded to 312 rows"):
         spmd_shell_mode(state, mesh)
 
     sys_ref = System(Params(**PARAMS), shell_shape=SHAPE)
@@ -171,16 +172,24 @@ def test_spmd_indivisible_fibers_raise():
 
 
 def test_spmd_indivisible_shell_raises():
-    """Node-misaligned shells must fail loudly (never silently replicate
-    the O(n^2) operators); the explicit opt-in reports 'replicated'."""
+    """A node-misaligned shell is never silently replicated: the mesh step
+    takes it PADDED (its operators at 3 x 104 rows, as `shard_state`
+    places them, the O(n) vectors replicated at 100 nodes), refuses in words
+    a state whose operators are not padded, and replicates it only on the
+    explicit opt-in."""
     parts = make_coupled_parts(100, 50, jnp.float64)  # 100 % 8 != 0
     mesh = make_mesh(N_DEV)
     sys_sp = System(Params(**PARAMS), shell_shape=SHAPE)
     state = _coupled_state(sys_sp, parts)
-    with pytest.raises(ValueError, match="multiple of 8"):
+    with pytest.raises(ValueError, match="padded to 312 rows"):
         spmd_shell_mode(state, mesh)
     assert spmd_shell_mode(state, mesh,
                            allow_replicated_shell=True) == "replicated"
+    placed = shard_state(state, mesh, step="spmd")
+    assert spmd_shell_mode(placed, mesh) == "padded"
+    assert placed.shell.M_inv.shape == (312, 312)
+    assert placed.shell.nodes.shape == (100, 3)
+    assert placed.shell.density.sharding.is_fully_replicated
 
 
 # ------------------------------------------------- lowered-program contracts
