@@ -43,6 +43,8 @@ ANNOUNCEMENTS = {
     "fiber_ops": ("fiber operators",
                   ("apply", "dtype", "fibers", "fallback", "nodes", "block",
                    "blocks_per_step")),
+    "prep_flow": ("prep flow (explicit wall force)",
+                  ("wall_force", "reason", "shape", "evaluator", "impl")),
     "periphery": ("periphery",
                   ("shape", "nodes", "nodes_padded", "operator",
                    "operator_dtype", "operator_bytes", "m_inv", "m_inv_dtype",

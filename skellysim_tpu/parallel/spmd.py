@@ -364,12 +364,17 @@ def build_spmd_step(system, mesh: Mesh, state: SimState, *,
                            fc.generate_constant_force(g, c),
                            jnp.zeros_like(g.x))
                  for g, c in zip(buckets, caches)]
-        fl, fp = fc.flow_multi_local(buckets, caches, external, r_loc, r_rep,
-                                     p.eta, axis_name=axis, n_dev=n_dev,
-                                     subtract_self=True, impl=prep_impl,
-                                     pair=prep_pair, pair_anchors=anchors)
-        v_loc = v_loc + fl
-        v_rep_part = fp
+        # a zero wall force (no shell, no force law): no ring, no psum
+        if system._announce_prep_flow(
+                st, "ring" if prep_pair is None else prep_pair.evaluator,
+                prep_impl):
+            fl, fp = fc.flow_multi_local(buckets, caches, external, r_loc,
+                                         r_rep, p.eta, axis_name=axis,
+                                         n_dev=n_dev, subtract_self=True,
+                                         impl=prep_impl, pair=prep_pair,
+                                         pair_anchors=anchors)
+            v_loc = v_loc + fl
+            v_rep_part = fp
 
         if b_list:
             for g, bc in zip(b_list, body_caches):

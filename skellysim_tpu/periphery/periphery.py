@@ -598,42 +598,67 @@ def check_collision(shape: PeripheryShape, points, threshold):
     return jnp.asarray(False)
 
 
+def _sphere_steric(shape, points, f_0, l_0, mask):
+    """f = f_0 * dr/|dr| * exp(-(R - r)/l_0) for r < R
+    (`periphery.cpp:140-162`)."""
+    r_mag = jnp.linalg.norm(points, axis=-1)
+    safe_r = jnp.where(r_mag > 0, r_mag, 1.0)
+    u_hat = points / safe_r[:, None]
+    dr = points - u_hat * shape.radius
+    d = jnp.linalg.norm(dr, axis=-1)
+    safe_d = jnp.where(d > 0, d, 1.0)
+    f = f_0 * dr / safe_d[:, None] * jnp.exp(-(shape.radius - r_mag) / l_0)[:, None]
+    inside = (r_mag < shape.radius) & mask
+    return jnp.where(inside[:, None], f, 0.0)
+
+
+def _ellipsoid_steric(shape, points, f_0, l_0, mask):
+    """The sphere's law toward the ellipsoid's cortex point
+    (`periphery.cpp:232-263`)."""
+    a, b, c = shape.abc
+    abc = jnp.asarray(shape.abc, dtype=points.dtype)
+    r_scaled = points / abc
+    r_scaled_mag = jnp.linalg.norm(r_scaled, axis=-1)
+    r_mag = jnp.linalg.norm(points, axis=-1)
+    phi = jnp.arctan2(r_scaled[:, 1], r_scaled[:, 0] + 1e-12)
+    theta = jnp.arccos(jnp.clip(r_scaled[:, 2] / (1e-12 + r_scaled_mag), -1, 1))
+    sin_t = jnp.sin(theta)
+    r_cortex = jnp.stack([a * sin_t * jnp.cos(phi),
+                          b * sin_t * jnp.sin(phi),
+                          c * jnp.cos(theta)], axis=-1)
+    r_cortex_mag = jnp.linalg.norm(r_cortex, axis=-1)
+    dr = points - r_cortex
+    d = jnp.linalg.norm(dr, axis=-1)
+    safe_d = jnp.where(d > 0, d, 1.0)
+    f = f_0 * dr / safe_d[:, None] * jnp.exp(-(r_cortex_mag - r_mag) / l_0)[:, None]
+    inside = (r_mag < r_cortex_mag) & mask
+    return jnp.where(inside[:, None], f, 0.0)
+
+
+#: the shape kinds with a steric wall-force law, and the law; every other
+#: kind (``generic``, ``revolution``) exerts none, upstream as here
+_STERIC_LAWS = {"sphere": _sphere_steric, "ellipsoid": _ellipsoid_steric}
+
+
+def exerts_steric_force(shape: PeripheryShape | None) -> bool:
+    """Whether a wall of ``shape`` pushes on fiber nodes at all: a
+    trace-time answer from the static kind. Where it is False,
+    `fiber_steric_force` is zero by construction, and the step skips the
+    explicit flow of that force (`System.wall_force`)."""
+    return shape is not None and shape.kind in _STERIC_LAWS
+
+
 def fiber_steric_force(shape: PeripheryShape, points, f_0, l_0, skip_first):
     """Exponential repulsion wall force on fiber nodes [n, 3] -> [n, 3].
 
     sphere: f = f_0 * dr/|dr| * exp(-(R - r)/l_0) for r < R
     (`periphery.cpp:140-162`); ellipsoid analogue (`periphery.cpp:232-263`);
-    generic: zero (stub parity). ``skip_first`` masks the clamped minus-end node.
+    every kind without a law (`exerts_steric_force`): zero (stub parity).
+    ``skip_first`` masks the clamped minus-end node.
     """
+    law = _STERIC_LAWS.get(shape.kind)
+    if law is None:
+        return jnp.zeros_like(points)
     n = points.shape[0]
     mask = jnp.arange(n, dtype=jnp.int32) >= jnp.where(skip_first, 1, 0)
-    if shape.kind == "sphere":
-        r_mag = jnp.linalg.norm(points, axis=-1)
-        safe_r = jnp.where(r_mag > 0, r_mag, 1.0)
-        u_hat = points / safe_r[:, None]
-        dr = points - u_hat * shape.radius
-        d = jnp.linalg.norm(dr, axis=-1)
-        safe_d = jnp.where(d > 0, d, 1.0)
-        f = f_0 * dr / safe_d[:, None] * jnp.exp(-(shape.radius - r_mag) / l_0)[:, None]
-        inside = (r_mag < shape.radius) & mask
-        return jnp.where(inside[:, None], f, 0.0)
-    if shape.kind == "ellipsoid":
-        a, b, c = shape.abc
-        abc = jnp.asarray(shape.abc, dtype=points.dtype)
-        r_scaled = points / abc
-        r_scaled_mag = jnp.linalg.norm(r_scaled, axis=-1)
-        r_mag = jnp.linalg.norm(points, axis=-1)
-        phi = jnp.arctan2(r_scaled[:, 1], r_scaled[:, 0] + 1e-12)
-        theta = jnp.arccos(jnp.clip(r_scaled[:, 2] / (1e-12 + r_scaled_mag), -1, 1))
-        sin_t = jnp.sin(theta)
-        r_cortex = jnp.stack([a * sin_t * jnp.cos(phi),
-                              b * sin_t * jnp.sin(phi),
-                              c * jnp.cos(theta)], axis=-1)
-        r_cortex_mag = jnp.linalg.norm(r_cortex, axis=-1)
-        dr = points - r_cortex
-        d = jnp.linalg.norm(dr, axis=-1)
-        safe_d = jnp.where(d > 0, d, 1.0)
-        f = f_0 * dr / safe_d[:, None] * jnp.exp(-(r_cortex_mag - r_mag) / l_0)[:, None]
-        inside = (r_mag < r_cortex_mag) & mask
-        return jnp.where(inside[:, None], f, 0.0)
-    return jnp.zeros_like(points)
+    return law(shape, points, f_0, l_0, mask)

@@ -571,6 +571,13 @@ class System:
                             evaluator="ring", mesh=self.mesh, impl=impl)
         return vel[:T]
 
+    def _flow_evaluator(self, pair) -> str:
+        """The evaluator `_fiber_flow` takes with ``pair``: the planned
+        spec's, else ``ring`` or ``direct``."""
+        if pair is not None and pair.is_fast:
+            return pair.evaluator
+        return "ring" if self._ring_active() else "direct"
+
     def _shell_flow(self, state: SimState, r_trg, density,
                     impl: str | None = None, pair=None, pair_anchors=None):
         """Shell -> target flow through the pair-evaluator seam
@@ -714,6 +721,40 @@ class System:
 
     # ------------------------------------------------- fiber-periphery coupling
 
+    def wall_force(self, state: SimState) -> tuple[bool, str]:
+        """``(exerts, reason)``: whether the shell of ``state`` pushes on
+        the fibers at all — ``no_shell``, ``no_force_law`` (a shape kind
+        without a steric law, `periphery.exerts_steric_force`: ``generic``,
+        ``revolution``) or ``force_law``. A trace-time answer from the
+        static `shell_shape`; where it is False the wall force is zero by
+        construction, and both `prep`s skip its explicit flow (one pair
+        sum, on a mesh one ring, that would add exact zeros)."""
+        if state.shell is None:
+            return False, "no_shell"
+        if not peri.exerts_steric_force(self.shell_shape):
+            return False, "no_force_law"
+        return True, "force_law"
+
+    def _announce_prep_flow(self, state: SimState, evaluator: str,
+                            impl: str) -> bool:
+        """Trace-time (once per build, like `_announce_block_precond`):
+        whether `prep` evaluates the explicit flow of the wall force
+        (`wall_force`), why, and the evaluator and tile that flow takes
+        or would take, in the log and as a ``prep_flow`` event. Returns
+        whether to evaluate it."""
+        exerts, reason = self.wall_force(state)
+        fields = dict(
+            wall_force="evaluated" if exerts else "skipped", reason=reason,
+            shape=self.shell_shape.kind if state.shell is not None else "-",
+            evaluator=evaluator,
+            impl=(kernels.resolve_impl(impl, state.time.dtype)
+                  if impl == "auto" else impl))
+        logger.info("prep_flow wall_force=%(wall_force)s reason=%(reason)s "
+                    "shape=%(shape)s evaluator=%(evaluator)s impl=%(impl)s",
+                    fields)
+        obs_tracer.emit("prep_flow", **fields)
+        return exerts
+
     def _periphery_force_fibers(self, state: SimState):
         """Steric wall force on fiber nodes, one [nf, n, 3] array per bucket
         (`periphery_force`).
@@ -806,10 +847,12 @@ class System:
                                jnp.zeros_like(g.x))
                      for g, c in zip(buckets, caches)]
 
-            v_all = v_all + self._fiber_flow(state, caches, r_all, external,
-                                             impl=impl_flow,
-                                             pair=prep_pair,
-                                             pair_anchors=prep_anchors)
+            if self._announce_prep_flow(
+                    state, self._flow_evaluator(prep_pair), impl_flow):
+                v_all = v_all + self._fiber_flow(state, caches, r_all,
+                                                 external, impl=impl_flow,
+                                                 pair=prep_pair,
+                                                 pair_anchors=prep_anchors)
 
         b_list = body_buckets(state.bodies)
         if b_list:
@@ -1304,7 +1347,7 @@ class System:
                 off += size
             f_on_fibers = [fc.apply_fiber_force(g, c, xf)
                            for g, c, xf in zip(buckets, caches, x_fibs)]
-            if p.periphery_interaction_flag and shell is not None:
+            if p.periphery_interaction_flag and self.wall_force(state)[0]:
                 steric = self._periphery_force_fibers(state)
                 f_on_fibers = [f + s for f, s in zip(f_on_fibers, steric)]
             # through the pair-evaluator seam so listener-mode evaluator
